@@ -13,7 +13,7 @@ import sys
 from pathlib import Path
 
 DEFAULT_PATHS = ["incubator_mxnet_tpu", "tools", "examples", "ci",
-                 "bench.py", "__graft_entry__.py"]
+                 "bench.py", "chip_smoke.py", "__graft_entry__.py"]
 MAX_LINE = 100
 
 # Framework modules that write checkpoint/state files.  In these,

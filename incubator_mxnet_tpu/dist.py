@@ -160,16 +160,10 @@ def shutdown():
     when never initialized; after it, :func:`init` works again with
     fresh env/arguments."""
     global _initialized
-    import jax
-    try:
-        from jax._src.distributed import global_state
-    except ImportError:
-        try:
-            jax.distributed.shutdown()
-        except Exception:
-            pass
-        _initialized = False
-        return
+    # jax.distributed.shutdown() stops at the first part whose own
+    # shutdown raises (a broken world's client does) and leaves it
+    # set, and initialize() then refuses; no public call clears it
+    from jax._src.distributed import global_state
     try:
         global_state.shutdown()
     except Exception:

@@ -49,11 +49,8 @@ def wait_all():
 
     Failures must surface: a dead backend raising here is the signal
     the caller asked for — swallowing it would turn "wait for
-    completion" into a silent no-op.  Only the absence of
-    ``effects_barrier`` on older jax is tolerated."""
-    barrier = getattr(jax, "effects_barrier", None)
-    if barrier is not None:
-        barrier()
+    completion" into a silent no-op."""
+    jax.effects_barrier()
     # touching a fresh computation forces the queue to drain per-device;
     # local_devices only — a process cannot (and need not) wait on
     # devices addressable only by other hosts

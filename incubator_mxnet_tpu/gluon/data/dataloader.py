@@ -100,16 +100,10 @@ def _accel_backend_initialized():
     process.  Must never initialize one (probing via
     jax.default_backend() would itself claim the device and spawn the
     runtime threads whose post-fork use the flag exists to prevent);
-    an uninitialized jax is fork-safe by definition.  If the probe
-    API is gone in a future jax, fail CLOSED (assume an accelerator)
-    rather than risk a silent post-fork deadlock."""
-    try:
-        from jax._src import xla_bridge as _xb
-        if not _xb.backends_are_initialized():
-            return False
-        return any(p != "cpu" for p in _xb._backends)
-    except Exception:
-        return True
+    an uninitialized jax is fork-safe by definition.  No public call
+    answers this without initializing a backend."""
+    from jax._src import xla_bridge as _xb
+    return any(p != "cpu" for p in _xb._backends)
 
 
 def _dtype_from_name(name):

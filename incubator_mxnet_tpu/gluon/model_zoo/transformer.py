@@ -91,6 +91,35 @@ def _rope_rows(x, pos, base=10000.0):
 _ULYSSES_WARNED = False
 
 
+def _flash_on_mesh(q, k, v, mesh, n_heads, window):
+    """The flash kernel under a multi-device mesh.  GSPMD cannot
+    partition a Mosaic kernel, so ``shard_map`` hands each device its
+    own (batch*head) rows: the batch over 'dp', the heads over 'tp'
+    (attention never mixes either).  q/k/v: (B*H, L, Dh) jax arrays,
+    batch-major."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from ...ops.flash import flash_attention
+    bh, l, dh = q.shape
+    spec = P(*(a if a in mesh.axis_names else None
+               for a in ("dp", "tp")))
+
+    def local(q, k, v):                        # (b, h, L, Dh) shards
+        lb, lh = q.shape[:2]
+        out = flash_attention(
+            *(t.reshape(lb * lh, l, dh) for t in (q, k, v)),
+            causal=True, window=window)
+        return out.reshape(lb, lh, l, dh)
+
+    split = (bh // n_heads, n_heads, l, dh)
+    # check_vma off: the kernel's out_shape says nothing of mesh axes
+    out = jax.shard_map(local, mesh=mesh, in_specs=(spec,) * 3,
+                        out_specs=spec, check_vma=False)(
+        *(t.reshape(split) for t in (q, k, v)))
+    return out.reshape(bh, l, dh)
+
+
 class CausalSelfAttention(Block):
     """Multi-head causal self-attention over registry ops.
 
@@ -142,22 +171,29 @@ class CausalSelfAttention(Block):
                              flatten=False, use_bias=True)
             self.proj = Dense(d_model, flatten=False, use_bias=True)
 
-    def _ring_mesh(self, seq_len):
-        """The mesh to ring over, or None to use exact local
-        attention.  Ring requires: the flag, an ambient mesh with
-        sp>1, a divisible sequence, and NOT an eager tape-recording
-        pass — the raw-jax ring call is invisible to the imperative
-        autograd tape, so eager record()/backward() must take the
-        registry-op path (identical values, correct gradients); the
-        compiled ShardedTrainStep path differentiates through ring
-        via jax.grad and keeps it."""
-        if not self._seq_parallel:
-            return None
+    @staticmethod
+    def _compiled_mesh():
+        """The ambient multi-device mesh of a compiled step, or None.
+        What runs under it here is raw jax (ring attention, the
+        shard_map around the flash kernel), invisible to the
+        imperative autograd tape: an eager record()/backward() pass
+        must take the registry-op path (identical values, correct
+        gradients), while ShardedTrainStep differentiates through
+        the raw call via jax.grad."""
         from ... import autograd
         if autograd.is_recording():
             return None
         from ...parallel.mesh import current_mesh
         mesh = current_mesh()
+        return mesh if mesh is not None and mesh.size > 1 else None
+
+    def _ring_mesh(self, seq_len):
+        """The mesh to ring over, or None to use exact local
+        attention.  Ring requires: the flag, a compiled step's mesh
+        with sp>1, and a divisible sequence."""
+        if not self._seq_parallel:
+            return None
+        mesh = self._compiled_mesh()
         if (mesh is None or mesh.shape.get("sp", 1) <= 1
                 or seq_len % mesh.shape["sp"] != 0):
             return None
@@ -232,8 +268,15 @@ class CausalSelfAttention(Block):
             # Pallas online-softmax kernel (ops/flash.py): no L x L
             # score tensor in HBM; registry op, so the tape and the
             # compiled paths both differentiate it
-            out = nd._internal._flash_attention(
-                q, k, v, causal=True, window=self._window)
+            import jax
+            mesh = self._compiled_mesh()
+            if mesh is not None and isinstance(q._data,
+                                               jax.core.Tracer):
+                out = nd.NDArray(_flash_on_mesh(
+                    q._data, k._data, v._data, mesh, h, self._window))
+            else:
+                out = nd._internal._flash_attention(
+                    q, k, v, causal=True, window=self._window)
         else:
             scores = nd.batch_dot(q, k, transpose_b=True) \
                 / math.sqrt(dh)

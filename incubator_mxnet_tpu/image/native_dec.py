@@ -52,9 +52,14 @@ def _native_lib():
     so = os.path.join(here, "lib", "libmxtpu_imgdec.v3.so")
     src = os.path.join(os.path.dirname(here), "src", "imgdec",
                        "imgdec.cc")
-    if not os.path.exists(so):
-        if not (os.path.exists(src) and _build(src, so)):
+    if os.path.exists(src) and (
+            not os.path.exists(so)
+            or os.path.getmtime(so) < os.path.getmtime(src)):
+        # missing, or older than its source
+        if not _build(src, so):
             return None
+    if not os.path.exists(so):
+        return None
     try:
         lib = ctypes.CDLL(so)
         lib.imgdec_last_error.restype = ctypes.c_char_p

@@ -1,4 +1,4 @@
-"""Remaining contrib / legacy op families (VERDICT r2 task 9).
+"""Remaining contrib / legacy op families.
 
 TPU-native implementations of the reference kernels:
   _contrib_fft / _contrib_ifft      (src/operator/contrib/fft.cc,

@@ -21,12 +21,13 @@ FlashAttention recipe too — dq/dk/dv kernels rebuild each P tile from
 the forward's log-sum-exp residual (delta = rowsum(g*o)), so no L x L
 tensor exists in HBM on either direction.
 
-On non-TPU backends the kernel runs in Pallas interpret mode (tests
-exercise it on CPU); numerics match the reference implementation to
-float32 tolerance either way.
+Where a call is placed anywhere but on a TPU the kernel runs in
+Pallas interpret mode (tests exercise it on CPU); numerics match the
+reference implementation to float32 tolerance either way.
 """
 import functools
 import math
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -173,6 +174,8 @@ def _flash_fwd(q, k, v, causal, scale, interpret, window=0):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    from ..rtc import pallas_call
+
     bh, lq, d = q.shape
     lk = k.shape[1]
     bq = min(128, lq)
@@ -191,7 +194,7 @@ def _flash_fwd(q, k, v, causal, scale, interpret, window=0):
     kernel = functools.partial(_fwd_kernel, bq=bq, bk=bk, nk=nk,
                                nj=nj, causal=causal, scale=scale,
                                window=window)
-    o, lse = pl.pallas_call(
+    o, lse = pallas_call(
         kernel,
         grid=(bh, lq // bq, nj),
         in_specs=[
@@ -313,6 +316,8 @@ def _flash_bwd(q, k, v, o, lse, g, causal, scale, interpret,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    from ..rtc import pallas_call
+
     bh, lq, d = q.shape
     lk = k.shape[1]
     bq = min(128, lq)
@@ -338,7 +343,7 @@ def _flash_bwd(q, k, v, o, lse, g, causal, scale, interpret,
 
         def qmap(b, jk, j):
             return (b, j, 0)
-    dq = pl.pallas_call(
+    dq = pallas_call(
         functools.partial(_dq_kernel, bq=bq, bk=bk, nk=nk,
                           nj=nj_k, causal=causal, scale=scale,
                           window=window),
@@ -357,7 +362,7 @@ def _flash_bwd(q, k, v, o, lse, g, causal, scale, interpret,
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
     )(q, k, v, g, lse, delta)
-    dk, dv = pl.pallas_call(
+    dk, dv = pallas_call(
         functools.partial(_dkv_kernel, bq=bq, bk=bk, nq=nq,
                           nj=nj_q, causal=causal, scale=scale,
                           window=window),
@@ -421,9 +426,10 @@ def flash_attention(q, k, v, causal=True, scale=None,
                     interpret=None, window=0):
     """Tiled online-softmax attention.  q/k/v: (BH, L, D).
 
-    ``interpret`` defaults to True off-TPU (Pallas interpreter) and
-    False on TPU (compiled Mosaic kernel).  Falls back to the XLA
-    reference implementation for shapes the tiling cannot cover.
+    ``interpret=None`` compiles the Mosaic kernel where the call is
+    placed on a TPU and interprets it elsewhere (``rtc.pallas_call``).
+    Shapes the tiling cannot cover get the XLA reference
+    implementation, with a warning.
 
     ``window > 0`` (requires ``causal``): sliding-window attention —
     query i sees keys (i - window, i].  Blocks entirely outside the
@@ -445,9 +451,11 @@ def flash_attention(q, k, v, causal=True, scale=None,
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     scale = float(scale)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     if not _supported(q, k):
+        warnings.warn(
+            f"flash_attention: q {q.shape} / k {k.shape} is not tiled "
+            "by 128 — XLA attention instead of the Pallas kernel (the "
+            "L x L scores go through HBM)", stacklevel=2)
         return _reference_attention(q, k, v, causal, scale,
                                     window=window)
-    return _flash(q, k, v, causal, scale, bool(interpret), window)
+    return _flash(q, k, v, causal, scale, interpret, window)

@@ -61,11 +61,8 @@ class TrainPerfClock:
 
     def _ensure_caps(self):
         if self._caps is None:
-            try:
-                import jax
-                self._caps = device_db.caps_for(jax.devices()[0])
-            except Exception:
-                self._caps = device_db.caps_for_kind("")
+            import jax
+            self._caps = device_db.caps_for(jax.devices()[0])
         return self._caps
 
     def tick(self, due=None):

@@ -45,7 +45,7 @@ class DeviceCaps:
         self.int8_2x = bool(int8_2x)
         self.nominal = bool(nominal)
         # per-chip HBM capacity; nominal_hbm marks values that are
-        # placeholders (CPU / unknown kinds) rather than datasheet
+        # placeholders (the CPU's) rather than datasheet
         self.nominal_hbm = bool(nominal) or hbm_gib is None
         self.hbm_bytes = float(
             (hbm_gib if hbm_gib is not None else 32) * (1 << 30))
@@ -67,7 +67,7 @@ class DeviceCaps:
     def capacity(self):
         """Usable per-device HBM in bytes: the ``MXTPU_HBM_BYTES``
         override when set (> 0), the generation's datasheet capacity
-        otherwise (nominal for CPU/unknown kinds)."""
+        otherwise (nominal for the CPU)."""
         override = float(get_env("MXTPU_HBM_BYTES"))
         return override if override > 0 else self.hbm_bytes
 
@@ -104,13 +104,20 @@ def _cpu_caps():
 
 
 def caps_for_kind(kind):
-    """Caps for a device-kind string; nominal CPU caps when no TPU
-    tag matches (so a roofline verdict always exists)."""
+    """Caps for a device-kind string: the matching ``DEVICE_DB`` row,
+    nominal CPU caps for ``"cpu"`` (or no kind at all).  An
+    accelerator that matches no row is an error, not a default — its
+    MFU would be computed against CPU peaks and the OOM gate would
+    plan against a made-up capacity."""
     k = (kind or "").lower()
     for caps in DEVICE_DB:
         if caps.kind in k:
             return caps
-    return _cpu_caps()
+    if k in ("", "cpu"):
+        return _cpu_caps()
+    raise ValueError(
+        f"device_kind {kind!r} matches no row of perf.DEVICE_DB: add "
+        "its peak FLOP/s, HBM bandwidth and capacity there")
 
 
 def caps_for(device):
@@ -119,14 +126,8 @@ def caps_for(device):
 
 
 def peak_flops(device, dtype="bfloat16"):
-    """Peak FLOP/s of a jax device for a compute dtype, or None for
-    unknown non-CPU kinds (kept for bench.py's legacy contract where
-    'no peak' means 'report throughput only')."""
-    kind = getattr(device, "device_kind", "").lower()
-    for caps in DEVICE_DB:
-        if caps.kind in kind:
-            return caps.peak(dtype)
-    return None
+    """Peak FLOP/s of a jax device for a compute dtype."""
+    return caps_for(device).peak(dtype)
 
 
 def hbm_capacity(device=None):

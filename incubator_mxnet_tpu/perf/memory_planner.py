@@ -284,6 +284,11 @@ def jaxpr_liveness(fn, *example_args):
             for v in eqn.params.values():
                 vs = v if isinstance(v, (tuple, list)) else (v,)
                 subs += [s for s in vs if hasattr(s, "jaxpr")]
+            if "branches" in eqn.params:
+                # one branch of a cond runs, and its branches share
+                # their buffers (rtc.pallas_call's compiled/interpreted
+                # pair has the same outputs): count one, not the sum
+                subs = subs[:1]
             for s in subs:
                 flatten(s.jaxpr)
             # a call eqn's outputs alias its sub-jaxpr's outputs:
@@ -306,7 +311,8 @@ def jaxpr_liveness(fn, *example_args):
             shape = getattr(aval, "shape", None)
             if shape is None:
                 continue
-            nb = _prod(shape) * np.dtype(aval.dtype).itemsize
+            # .itemsize, not np.dtype(): a PRNG key's dtype is jax's own
+            nb = _prod(shape) * aval.dtype.itemsize
             t_bytes[v] = nb
             t_prod[v] = pos
             last_use[v] = pos
@@ -318,14 +324,14 @@ def jaxpr_liveness(fn, *example_args):
         aval = getattr(v, "aval", None)
         if hasattr(aval, "shape"):
             inputs_bytes += _prod(aval.shape) \
-                * np.dtype(aval.dtype).itemsize
+                * aval.dtype.itemsize
     outputs_bytes = 0.0
     end = len(eqn_seq)
     for v in closed.jaxpr.outvars:
         aval = getattr(v, "aval", None)
         if hasattr(aval, "shape"):
             outputs_bytes += _prod(aval.shape) \
-                * np.dtype(aval.dtype).itemsize
+                * aval.dtype.itemsize
         if v in t_bytes:
             last_use[v] = end
 

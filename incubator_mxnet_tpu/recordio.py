@@ -40,7 +40,11 @@ def _native_lib():
     so = os.path.join(here, "lib", "librecordio.so")
     src = os.path.join(os.path.dirname(here), "src", "recordio",
                        "recordio.cc")
-    if not os.path.exists(so) and os.path.exists(src):
+    # build when missing, and again when the source is newer: the
+    # library is ignored by git, so a checkout never brings one along
+    if os.path.exists(src) and (
+            not os.path.exists(so)
+            or os.path.getmtime(so) < os.path.getmtime(src)):
         try:
             os.makedirs(os.path.dirname(so), exist_ok=True)
             subprocess.run(

@@ -12,9 +12,9 @@ jit-compiled executables like every built-in op.
 Two layers:
 
 ``compile_kernel``
-    pallas_call wrapper with interpret-mode auto-detection (the
-    kernel runs through the Pallas interpreter off-TPU, so custom
-    kernels are testable on CPU and in CI).
+    pallas_call wrapper: the kernel is Mosaic-compiled where the call
+    is lowered for a TPU and runs through the Pallas interpreter
+    anywhere else, so custom kernels are testable on CPU and in CI.
 
 ``register``
     put any jit-compatible function — a compiled Pallas kernel or
@@ -45,15 +45,34 @@ import jax
 
 from .ops.registry import OPS, OpDef
 
-__all__ = ["compile_kernel", "register", "on_tpu"]
+__all__ = ["compile_kernel", "register", "pallas_call"]
 
 
-def on_tpu():
-    """True when the default jax backend is a real accelerator."""
-    try:
-        return jax.default_backend() not in ("cpu",)
-    except Exception:
-        return False
+def pallas_call(kernel, *, interpret=None, **pallas_kwargs):
+    """``pl.pallas_call`` that compiles the kernel for the TPU and
+    interprets it everywhere else.
+
+    With ``interpret=None`` the choice is made when the call is
+    lowered, from the platform it is lowered for — where the operands
+    live, or what the enclosing ``jit`` targets — never from the
+    process's default backend: on a host with a TPU, an eager forward
+    over CPU-placed operands must still get the interpreter.  The
+    ``jit`` gives an eager call that lowering context."""
+    from jax.experimental import pallas as pl
+
+    if interpret is not None:
+        return pl.pallas_call(kernel, interpret=bool(interpret),
+                              **pallas_kwargs)
+    compiled = pl.pallas_call(kernel, interpret=False, **pallas_kwargs)
+    interpreted = pl.pallas_call(kernel, interpret=True,
+                                 **pallas_kwargs)
+
+    @jax.jit
+    def call(*arrays):
+        return jax.lax.platform_dependent(
+            *arrays, tpu=compiled, default=interpreted)
+
+    return call
 
 
 def compile_kernel(kernel, out_shape, *, interpret=None,
@@ -68,19 +87,16 @@ def compile_kernel(kernel, out_shape, *, interpret=None,
     out_shape : ``jax.ShapeDtypeStruct`` (or list of them), or a
         callable ``(*arrays, **params) -> out_shape`` evaluated per
         call — shape polymorphism the CUDA-RTC analog never had.
-    interpret : force Pallas interpret mode.  Default ``None`` =
-        auto: compiled on TPU, interpreted elsewhere (CPU testing).
+    interpret : force Pallas interpret mode on or off.  Default
+        ``None``: compiled where the call is placed on a TPU,
+        interpreted elsewhere (see :func:`pallas_call`).
     grid, in_specs, out_specs, **pallas_kwargs :
         forwarded to ``pallas_call`` (same semantics; may each be a
         callable of ``(*arrays, **params)`` for shape-dependent
         tiling).
     """
-    from jax.experimental import pallas as pl
-
     def call(*arrays, **params):
         ipret = params.pop("_interpret", interpret)
-        if ipret is None:
-            ipret = not on_tpu()
 
         def resolve(v):
             return v(*arrays, **params) if callable(v) else v
@@ -92,7 +108,7 @@ def compile_kernel(kernel, out_shape, *, interpret=None,
                 kw[k] = resolve(v)
         bound = functools.partial(kernel, **params) if params \
             else kernel
-        return pl.pallas_call(
+        return pallas_call(
             bound, out_shape=resolve(out_shape), interpret=ipret,
             **kw)(*arrays)
 

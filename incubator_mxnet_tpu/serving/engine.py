@@ -610,13 +610,11 @@ class ServingEngine:
     # ------------------------------------------------ perf observatory
     def _serve_dtype(self):
         """Weight-stream dtype for roofline math: int8 when the
-        weights are quantized, else the device's native matmul
-        width (bf16 on TPU, fp32 elsewhere)."""
+        weights are quantized, else the dtype the parameters are held
+        and multiplied in."""
         if self.quantized:
             return "int8"
-        import jax
-        return ("bfloat16" if jax.devices()[0].platform == "tpu"
-                else "float32")
+        return str(self._wts["head"].dtype)
 
     def _caps(self):
         if self._perf_caps is None:

@@ -190,7 +190,7 @@ def test_c_train_resume_from_params(tmp_path):
 
 
 def test_c_train_regression_head_reports_mse():
-    """Loss semantics follow the head op (VERDICT r4 next-step 10):
+    """Loss semantics follow the head op:
     a LinearRegressionOutput head must report mean squared error —
     not the mean of the predictions — and it must decrease."""
     lib = _bind(ctypes.CDLL(_build_lib()))

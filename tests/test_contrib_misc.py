@@ -1,4 +1,4 @@
-"""Contrib op tail (VERDICT r2 task 9): fft/ifft, count_sketch,
+"""Contrib op tail: fft/ifft, count_sketch,
 quantize/dequantize, Correlation, DeformablePSROIPooling, MakeLoss,
 IdentityAttachKLSparseReg, cast_storage/reshape_like/_sparse_retain/
 _square_sum.  Oracles are independent numpy implementations of the

@@ -1,5 +1,5 @@
 """tools/launch.py spawning multi-process kvstore workers
-(VERDICT r2 task 6; ref: tools/launch.py:64 +
+(ref: tools/launch.py:64 +
 tests/nightly/dist_sync_kvstore.py run as local processes)."""
 import os
 import subprocess
@@ -279,7 +279,7 @@ def _write_shim(tmp_path):
 
 def test_launch_ssh_two_host_kvstore(tmp_path):
     """--launcher ssh spawns real per-host remote-shell sessions with
-    env propagated inline (VERDICT r4 next-step 7).  The transport is
+    env propagated inline.  The transport is
     swapped for a local shim (this image has no ssh client); with a
     real ssh binary the identical code path runs unchanged."""
     import socket

@@ -1,5 +1,5 @@
-"""Driver-config example workloads with convergence gates
-(VERDICT r2 task 7): config 2 (image classification, mesh path),
+"""Driver-config example workloads with convergence gates:
+config 2 (image classification, mesh path),
 config 3 (bucketed LSTM perplexity), config 4 (SSD detection mAP).
 
 Each example runs in --quick mode, which asserts its own gate
@@ -40,8 +40,8 @@ def test_ssd_train_quick():
 
 
 def test_ssd_anchor_scale_8732():
-    """Detection kernels at the reference's real SSD300 anchor count
-    (VERDICT r2 weak #6: 'never run at realistic scale')."""
+    """Detection kernels at the reference's real SSD300 anchor
+    count."""
     import ssd_train as ex
     import incubator_mxnet_tpu as mx
     from incubator_mxnet_tpu import nd

@@ -1,5 +1,5 @@
 """HybridBlock.export -> symbol JSON + params -> Predictor / Module
-round-trip (VERDICT r2 task 8; ref: python/mxnet/gluon/block.py
+round-trip (ref: python/mxnet/gluon/block.py
 HybridBlock.export, include/mxnet/c_predict_api.h)."""
 import numpy as np
 

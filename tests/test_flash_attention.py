@@ -65,11 +65,13 @@ def test_gradients_match_reference():
 
 def test_unsupported_shape_falls_back():
     # L=200 is untileable (200 % 128 != 0): must take the XLA
-    # reference fallback, preserving causal flag and scale
+    # reference fallback, preserving causal flag and scale — and say
+    # so, since it is not the kernel the caller asked for
     from incubator_mxnet_tpu.ops import flash as flash_mod
     q, k, v = _rand(1, 200, 16)
     assert not flash_mod._supported(q, k)
-    out = flash_attention(q, k, v, causal=True, interpret=True)
+    with pytest.warns(UserWarning, match="not tiled by 128"):
+        out = flash_attention(q, k, v, causal=True, interpret=True)
     ref = _reference_attention(q, k, v, True, 0.25)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
@@ -122,6 +124,70 @@ def test_model_uses_flash(monkeypatch):
     got = net(toks).asnumpy()
     assert calls, "flash path never engaged despite MXTPU_FLASH=1"
     np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-4)
+
+
+def test_eager_forward_on_cpu_operands_interprets_on_a_tpu_host(
+        monkeypatch):
+    """On a host with a TPU the default backend is ``tpu`` while a
+    shape-settling eager forward (parallel.functionalize) may run on
+    CPU-placed operands.  The kernel's compiled-or-interpreted choice
+    must follow where the call is lowered, not the default backend:
+    steered here by saying the default backend is a TPU (at the
+    parent commit this raised "Only interpret mode is supported on
+    CPU backend")."""
+    from incubator_mxnet_tpu.gluon.model_zoo.transformer import \
+        TransformerLM
+    mx.random.seed(0)
+    net = TransformerLM(37, d_model=32, n_layers=1, n_heads=2,
+                        max_len=128)
+    net.initialize(mx.initializer.Xavier())
+    toks = mx.nd.array(np.random.RandomState(0)
+                       .randint(0, 37, (2, 128)).astype("int32"))
+    ref = net(toks).asnumpy()               # XLA attention
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert net.blocks[0].attn._use_flash()
+    assert toks._data.devices() == {jax.devices("cpu")[0]}
+    got = net(toks).asnumpy()               # the kernel, interpreted
+    np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-4)
+    # ... and shape settling through functionalize does the same
+    from incubator_mxnet_tpu import parallel
+    pure = parallel.functionalize(net, toks)
+    outs, _ = pure.apply(pure.params(), pure.states(), [toks._data],
+                         jax.random.PRNGKey(0), training=False)
+    np.testing.assert_allclose(np.asarray(outs[0]), ref, rtol=1e-4,
+                               atol=1e-4)
+
+
+def test_flash_under_a_dp_mesh_matches_one_device(monkeypatch):
+    """Under a multi-device mesh the compiled step runs the kernel
+    through shard_map (GSPMD cannot partition a Mosaic kernel; the
+    v5e compile is in test_tpu_compile.py): dp=4 must train like one
+    device."""
+    from incubator_mxnet_tpu import parallel
+    from incubator_mxnet_tpu.gluon.model_zoo import transformer as tr
+    monkeypatch.setenv("MXTPU_FLASH", "1")
+    calls = []
+    real = tr._flash_on_mesh
+    monkeypatch.setattr(
+        tr, "_flash_on_mesh",
+        lambda *a: calls.append(a[3].shape["dp"]) or real(*a))
+    mx.random.seed(0)
+    net = tr.TransformerLM(37, d_model=32, n_layers=1, n_heads=2,
+                           max_len=128)
+    net.initialize(mx.initializer.Xavier())
+    rs = np.random.RandomState(0)
+    toks = rs.randint(0, 37, (4, 128)).astype(np.int32)
+    labels = rs.randint(0, 37, (4, 128)).astype(np.int32)
+    losses = {}
+    for dp in (4, 1):
+        step = parallel.ShardedTrainStep(
+            net, optimizer="sgd",
+            optimizer_params=dict(learning_rate=0.1),
+            example_args=[mx.nd.array(toks[:1])],
+            mesh=parallel.make_mesh(devices=jax.devices()[:dp]))
+        losses[dp] = [float(step(toks, labels)) for _ in range(3)]
+    assert calls and set(calls) == {4}, calls   # dp=1: the plain op
+    np.testing.assert_allclose(losses[4], losses[1], rtol=1e-5)
 
 
 def test_flash_backward_matches_reference_vjp():

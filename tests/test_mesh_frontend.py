@@ -1,5 +1,5 @@
 """Mesh-aware frontends: Module.fit and Gluon Trainer on the 8-device
-virtual CPU mesh (VERDICT r2 tasks 2/3).
+virtual CPU mesh.
 
 Oracle = the single-device eager paths of the same frontends: the
 compiled kvstore='tpu' step must reproduce them numerically (the

@@ -1,4 +1,4 @@
-"""Registry-wide operator sweep (VERDICT r2 task 5): every
+"""Registry-wide operator sweep: every
 differentiable op gets a numeric-gradient check through the symbolic
 executor (the reference's per-op check_numeric_gradient discipline,
 ref: python/mxnet/test_utils.py:789 used across

@@ -107,16 +107,25 @@ def test_device_db_peaks_and_dtype_conventions():
     assert not v4.nominal
     v5e = device_db.caps_for_kind("TPU v5e chip")
     assert v5e.peak("int8") == 2 * v5e.peak("bfloat16")
-    # unknown kinds: caps_for_kind degrades to nominal CPU numbers
-    # (so a roofline verdict always exists) while peak_flops keeps
-    # bench.py's legacy contract and returns None
-    cpu = device_db.caps_for_kind("some future accelerator")
+    lite = device_db.caps_for_kind("TPU v5 lite")   # a v5e chip's kind
+    assert (lite.peak("bfloat16"), lite.hbm_bytes) == \
+        (197e12, 16 * (1 << 30))
+    cpu = device_db.caps_for_kind("cpu")
     assert cpu.nominal
     assert cpu.peak("float32") == cpu.peak("bfloat16")
+    import jax
+    assert device_db.caps_for(jax.devices()[0]).nominal
 
+    # an accelerator no row knows is an error, never CPU numbers: MFU
+    # and the OOM gate's capacity would both be made up
     class FakeDev:
         device_kind = "some future accelerator"
-    assert device_db.peak_flops(FakeDev()) is None
+    for ask in (lambda: device_db.caps_for_kind(FakeDev.device_kind),
+                lambda: device_db.caps_for(FakeDev()),
+                lambda: device_db.peak_flops(FakeDev()),
+                lambda: device_db.hbm_capacity(FakeDev())):
+        with pytest.raises(ValueError, match="DEVICE_DB"):
+            ask()
 
     class V5p:
         device_kind = "TPU v5p"

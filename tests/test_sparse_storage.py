@@ -1,4 +1,4 @@
-"""Real sparse storage (VERDICT r2 task 4): no O(full-shape) buffer
+"""Real sparse storage: no O(full-shape) buffer
 on construction or through the sparse kernel/kvstore paths, plus the
 LibSVM linear-classification convergence gate (driver config 5,
 ref: example/sparse/linear_classification.py).

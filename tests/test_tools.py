@@ -90,7 +90,7 @@ def test_pipeline_bench_mode(tmp_path):
     train step, end-to-end on the CPU backend with the tiny net."""
     import json
     env = dict(os.environ)
-    env.update(MXTPU_BENCH_PLATFORM="cpu", MXTPU_BENCH_MODEL="pipeline",
+    env.update(JAX_PLATFORMS="cpu", MXTPU_BENCH_MODEL="pipeline",
                MXTPU_BENCH_PIPE_IMGS="64", MXTPU_BENCH_PIPE_NET="tiny",
                MXTPU_BENCH_BATCH="16")
     env.pop("XLA_FLAGS", None)
@@ -103,3 +103,46 @@ def test_pipeline_bench_mode(tmp_path):
     assert rec["metric"].startswith("tiny_e2e_pipeline")
     assert rec["value"] > 0 and rec["feed_only_img_s"] > 0
     assert rec["naked_step_img_s"] > 0 and rec["e2e_over_step"] > 0
+    # a run asked onto the CPU says so, and claims no device metric
+    assert rec["platform"] == "cpu" and "mfu" not in rec
+
+
+def _chip_smoke(*args, **env_changes):
+    repo = os.path.dirname(TOOLS)
+    env = dict(os.environ, JAX_PLATFORMS="cpu", **env_changes)
+    env.pop("XLA_FLAGS", None)
+    return subprocess.run(
+        [sys.executable, os.path.join(repo, "chip_smoke.py"), *args],
+        capture_output=True, text=True, timeout=420, env=env, cwd=repo)
+
+
+def test_chip_smoke_needs_a_tpu():
+    """Without a TPU the smoke exits non-zero at once with one line
+    and no result: nothing here may read as a chip run."""
+    r = _chip_smoke()
+    assert r.returncode != 0
+    assert r.stdout == "" and "needs a TPU" in r.stderr
+
+
+def test_chip_smoke_rehearsal_on_cpu_is_never_a_result():
+    """The first rehearsal of the on-chip-measurement guide, kept as a
+    test: every phase end to end at a tiny size on the CPU, kernels
+    interpreted.  It runs to its end, and still exits non-zero and
+    prints neither ``"ok": true`` nor a TPU platform."""
+    import json
+    r = _chip_smoke(
+        "--rehearse", "--mlp-samples", "1024", "--resnet-batch", "2",
+        "--resnet-hw", "32", "--resnet-steps", "3", "--lm-vocab", "256",
+        "--lm-d-model", "64", "--lm-layers", "2", "--lm-heads", "2",
+        "--lm-batch", "2", "--lm-seq", "128", "--lm-steps", "3",
+        "--kernel-shape", "4,256,32", "--kernel-window", "128",
+        "--serve-prompts", "5,17,40", "--serve-new", "8",
+        MXTPU_FLASH="1")
+    assert r.returncode == 2, r.stderr[-3000:]
+    assert "rehearsal on cpu passed; not a result" in r.stderr
+    lines = [json.loads(ln) for ln in r.stdout.strip().splitlines()]
+    assert [ln["phase"] for ln in lines] == [
+        "device", "eager+module", "resnet50", "transformer_lm",
+        "flash_kernel", "serve", "done"]
+    assert not any("ok" in ln for ln in lines)
+    assert '"tpu"' not in r.stdout

@@ -1,0 +1,167 @@
+"""Ask the TPU v5e's compiler, without a chip (on-chip-measurement guide
+section 2): the Pallas kernels of the main path at the widths
+``chip_smoke.py`` runs them, Mosaic-compiled for a *described*
+``v5e:2x2`` — what interpret mode cannot show (tiling rules, VMEM
+limits, partitioning).  A compile that passes is not a chip run.
+
+Every such test lives in this one file: the topology is described in
+a module-scoped fixture, so only the xdist worker that is handed this
+file loads the TPU library, and every worker collects the same tests.
+"""
+import importlib.util
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+import incubator_mxnet_tpu as mx
+from incubator_mxnet_tpu import rtc
+from incubator_mxnet_tpu import symbol as symmod
+from incubator_mxnet_tpu.gluon.model_zoo.transformer import \
+    _flash_on_mesh
+from incubator_mxnet_tpu.ops.flash import flash_attention
+from incubator_mxnet_tpu.perf import memory_planner as mp
+
+from test_memory_planner import GRAPH_INPUTS, _load_bench, \
+    _train_compiled
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # noqa: BLE001 — whatever says "no"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent
+    # cache but cannot be read back without one: keep it out
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _attention_loss(window):
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, window=window)
+        return jnp.sum(out.astype(jnp.float32))
+    return loss
+
+
+# (BH, L, d), window, dtype: the smoke's kernel comparison and LM step
+# (128, 1024, 64), a long context, the banded grid, wide heads, f32
+FLASH_CASES = [
+    pytest.param((128, 1024, 64), 0, "bfloat16", id="1024x64"),
+    pytest.param((128, 4096, 64), 0, "bfloat16", id="4096x64"),
+    pytest.param((128, 1024, 64), 256, "bfloat16", id="1024x64-w256"),
+    pytest.param((32, 1024, 128), 0, "bfloat16", id="1024x128"),
+    pytest.param((128, 1024, 64), 0, "float32", id="1024x64-f32"),
+]
+
+
+@pytest.mark.parametrize("shape,window,dtype", FLASH_CASES)
+def test_flash_forward_compiles_for_v5e(one_chip, shape, window,
+                                        dtype):
+    x = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+    compiled = jax.jit(_attention_loss(window)).lower(x, x, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("shape,window,dtype", FLASH_CASES)
+def test_flash_backward_compiles_for_v5e(one_chip, shape, window,
+                                         dtype):
+    x = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+    compiled = jax.jit(jax.grad(_attention_loss(window),
+                                argnums=(0, 1, 2))).lower(
+        x, x, x).compile()
+    # forward (for the residuals), dq and dk/dv kernels
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_flash_under_dp_mesh_compiles_for_v5e(topo):
+    """GSPMD cannot partition a Mosaic kernel ("wrap the call in a
+    shard_map"): under a multi-device mesh the transformer hands each
+    device its rows through ``_flash_on_mesh``.  The dp=4 step of
+    ``chip_smoke.py --chips 4`` stands on this."""
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1, 1, 1, 1),
+                mx.parallel.AXES)
+    heads, shape = 16, (8 * 16, 1024, 64)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("dp")))
+
+    def loss(q, k, v):
+        return jnp.sum(_flash_on_mesh(q, k, v, mesh, heads, 0)
+                       .astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        jax.jit(_attention_loss(0)).lower(x, x, x).compile()
+
+
+def test_rtc_example_kernel_compiles_for_v5e(one_chip):
+    """examples/custom_pallas_kernel.py's kernel through
+    ``rtc.compile_kernel``: the compiled-or-interpreted choice follows
+    the platform the call is lowered for, not the default backend
+    (which is the CPU here)."""
+    spec = importlib.util.spec_from_file_location(
+        "custom_pallas_kernel_example",
+        os.path.join(REPO, "examples", "custom_pallas_kernel.py"))
+    example = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(example)    # registers scale_shift_relu
+    try:
+        x = jax.ShapeDtypeStruct((512, 1024), jnp.float32,
+                                 sharding=one_chip)
+        compiled = jax.jit(
+            lambda a: example.fused(a, alpha=2.0, beta=0.5)).lower(
+            x).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+    finally:
+        rtc.unregister("scale_shift_relu")
+
+
+@pytest.mark.parametrize("graph,accum", [
+    ("mlp", 1), ("mlp", 2),
+    ("resnet_block", 1), ("resnet_block", 2),
+    ("transformer_step", 1),
+])
+def test_planner_never_under_the_v5e_compiler(one_chip, graph, accum):
+    """The planner plans for the TPU, so the TPU's compiler is what it
+    answers to (tests/test_memory_planner.py holds it to XLA:CPU,
+    whose accounting moves with the installed XLA).  What the OOM gate
+    needs is one-sided: a plan below the compiler's live bytes would
+    wave through a step that cannot fit.  The other side is not
+    banded.  At these toy sizes the v5e compiler reports no temporary
+    HBM at all, and at the smoke's real sizes the plan is about twice
+    its number (ResNet-50 B=32: 3.94 vs 1.48 GiB; the 150M LM step:
+    13.47 vs 6.76 GiB — PERF.md, ISSUE 21; compiles, not chip runs)."""
+    bench = _load_bench()
+    s, shapes = getattr(bench, f"_graph_{graph}")(symmod)
+    inputs = GRAPH_INPUTS[graph]
+    compiled = _train_compiled(s, shapes, inputs, grad_accum=accum,
+                               sharding=one_chip)
+    tpu = mp.xla_live_bytes(compiled.memory_analysis())
+    assert tpu, "the v5e compiler reports a memory analysis"
+    plan = mp.plan_memory(s, shapes, input_names=inputs,
+                          grad_accum=accum, donate=True)
+    assert plan.total() >= tpu, (
+        f"{graph} accum={accum}: planner {plan.total():.0f} under the "
+        f"v5e compiler's {tpu:.0f} — {plan.describe()}")

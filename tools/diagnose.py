@@ -5,8 +5,7 @@ reports carry a reproducible context; same role here, for the JAX
 stack this framework runs on).
 
 Prints one JSON document; everything best-effort (a broken install
-is exactly when this must still run).  TPU-tunnel specifics live in
-the sibling `tools/tpu_doctor.py`; this one never touches a device
+is exactly when this must still run).  It never touches a device
 unless --probe is passed (a dead accelerator must not hang the
 report).
 
