@@ -95,7 +95,8 @@ HOT_SYNC_FUNCS = {"step", "update", "__call__", "begin_step",
                   "guarded_step_begin", "read_window_bad",
                   "accumulate_window", "all_finite",
                   # serving scheduler loop + decode step
-                  "_admit", "_grow", "_decode_once", "_append_token",
+                  "_admit", "_admit_one", "_grow", "_decode_once",
+                  "_append_token",
                   "_retire", "_preempt", "_fail", "stream", "run",
                   # serving survival layer: the reap sweep and every
                   # terminal path run inside the engine iteration,

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .. import telemetry
 from .functional import PureBlock, functionalize
 from .mesh import (current_mesh, make_mesh, shard_batch,
                    use_mesh)
@@ -164,6 +165,7 @@ class ShardedTrainStep:
         # perf observatory: armed by cost_analysis()/arm_perf(); a
         # ticking clock publishes train_mfu/train_mbu from wall time
         self._perf_clock = None
+        self._calls = 0     # host-side count of __call__ (span field)
         # memory planner (docs/memory.md): the preflight gate's
         # accepted plan + the cached forward-liveness walk (both
         # bind-time artifacts — nothing here runs on the step path)
@@ -350,7 +352,7 @@ class ShardedTrainStep:
         ladder re-raises the typed OomError.  MXTPU_MEM_POLICY=off
         opts out of automatic degrading entirely — the OomError
         stays loud."""
-        from .. import telemetry, tracing
+        from .. import tracing
         from ..perf.memory_planner import next_divisor
         from ..utils.env import get_env
         if str(get_env("MXTPU_MEM_POLICY")).lower() == "off":
@@ -378,6 +380,11 @@ class ShardedTrainStep:
     # ---------------------------------------------------------------- run
     def __call__(self, x, y, rng=None):
         """Run one training step on a *global* batch; returns loss."""
+        with telemetry.span("train_step", step=self._calls):
+            self._calls += 1
+            return self._call(x, y, rng)
+
+    def _call(self, x, y, rng):
         from ..dist import elastic_probe
         elastic_probe()     # elastic:rank<N> injection (docs/elastic.md)
         x, y = _raw(x), _raw(y)
@@ -388,19 +395,23 @@ class ShardedTrainStep:
         for attempt in (0, 1):
             try:
                 if self._step is None:
-                    self._preflight(x, y)
-                    self._step = self._build(x, y)
+                    with telemetry.span("train_preflight"):
+                        self._preflight(x, y)
+                    with telemetry.span("train_build"):
+                        self._step = self._build(x, y)
                 # mem:oom injection point (docs/resilience.md); a
                 # no-op single bool check without MXTPU_FAULT_SPEC
                 check_oom("sharded_train_step")
-                xs = jax.device_put(x, self._input_sharding(x.ndim))
-                ys = jax.device_put(
-                    y, self._input_sharding(y.ndim, True))
+                with telemetry.span("train_put"):
+                    xs = jax.device_put(x, self._input_sharding(x.ndim))
+                    ys = jax.device_put(
+                        y, self._input_sharding(y.ndim, True))
                 # run (and, on the first call, trace) with this
                 # step's mesh ambient, so mesh-aware blocks (e.g.
                 # ring attention) resolve the step's mesh even when
                 # called outside use_mesh()
-                with use_mesh(self.mesh):
+                with telemetry.span("train_dispatch"), \
+                        use_mesh(self.mesh):
                     (self.params, self.states, self.opt_state,
                      self.step_count, loss) = self._step(
                         self.params, self.states, self.opt_state,

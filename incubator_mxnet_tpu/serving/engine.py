@@ -265,6 +265,8 @@ class ServingEngine:
         self._m_tokens = telemetry.counter("serving_tokens_total")
         self._m_prefill = telemetry.counter(
             "serving_prefill_tokens_total")
+        self._m_prefill_padded = telemetry.counter(
+            "serving_prefill_padded_tokens_total")
         self._m_hits = telemetry.counter(
             "serving_prefix_cache_hits_total")
         self._m_misses = telemetry.counter(
@@ -374,6 +376,10 @@ class ServingEngine:
             self.trace_counts[name] = \
                 self.trace_counts.get(name, 0) + 1
             return fn(*args)
+
+        # the program's name on a device trace's "XLA Modules" line
+        # (jit_serve_decode, jit_serve_prefill_<bucket>)
+        traced.__name__ = traced.__qualname__ = f"serve_{name}"
 
         # donate the KV pools (args 1, 2 in both the prefill and the
         # step signature): the compiled call updates the cache IN
@@ -592,19 +598,25 @@ class ServingEngine:
         grow -> decode -> retire.  Returns the ``(request,
         token_id)`` events emitted this iteration."""
         events = []
-        self._reap()
-        self._admit(events)
-        if self._sched.any_running():
-            self._grow()
-        if self._sched.any_running():
-            self._decode_once(events)
-        self._m_occ.set(self._sched.n_running() / self.max_batch)
-        self._m_util.set(self.pool.utilization())
-        self._m_qdepth.set(len(self._sched.waiting))
-        self._m_qtokens.set(self._sched.queued_tokens)
-        self._perf_iters += 1
-        if self._perf_iters >= self._perf_interval:
-            self._publish_perf()
+        with telemetry.span("serve_step",
+                            running=self._sched.n_running(),
+                            waiting=len(self._sched.waiting)) as sp:
+            with telemetry.span("serve_reap"):
+                self._reap()
+            self._admit(events)
+            if self._sched.any_running():
+                with telemetry.span("serve_grow"):
+                    self._grow()
+            if self._sched.any_running():
+                self._decode_once(events)
+            self._m_occ.set(self._sched.n_running() / self.max_batch)
+            self._m_util.set(self.pool.utilization())
+            self._m_qdepth.set(len(self._sched.waiting))
+            self._m_qtokens.set(self._sched.queued_tokens)
+            self._perf_iters += 1
+            if self._perf_iters >= self._perf_interval:
+                self._publish_perf()
+            sp.set(emitted=len(events))
         return events
 
     # ------------------------------------------------ perf observatory
@@ -1103,8 +1115,6 @@ class ServingEngine:
         """Fill free slots from the waiting queue; one suffix
         prefill per admission (prefix-cache hits skip the shared
         blocks)."""
-        import jax
-        import jax.numpy as jnp
         if self._draining:
             return      # drain(): queued requests belong to snapshot()
         while self._sched.has_waiting():
@@ -1117,93 +1127,108 @@ class ServingEngine:
             # it would be in neither.  Visible until placed,
             # requeued, or terminal (terminals filter on req.done).
             self._in_transit = self._sched.waiting[0]
-            req = self._sched.pop_waiting()
-            try:
-                resilience.inject("serve", "request")
-            except resilience.TransientError as exc:
-                self._fail(req, exc)
-                continue
-            try:
-                # re-check at admission: a snapshot restored into a
-                # smaller pool/context must fail THAT request loudly,
-                # not hang the schedule (submit() already vets fresh
-                # submissions; preemption cannot grow the bound)
-                self._check_servable(len(req.prompt),
-                                     req.max_new_tokens)
-            except RequestTooLargeError as exc:
-                self._fail(req, exc)
-                continue
-            toks = req.tokens
-            matched, n_cached = self.cache.match(toks)
-            need = -(-len(toks) // self.block_size) - len(matched)
-            try:
-                fresh = self._alloc(need)
-            except BlockPoolExhausted:
-                if matched:
-                    self.pool.free(matched)     # release the match
-                self._sched.push_front(req)
-                self._in_transit = None
-                if not self._sched.any_running():
-                    raise SchedulingError(
-                        f"request {req.id} needs {need} fresh "
-                        "blocks but the pool cannot ever provide "
-                        "them — raise MXTPU_SERVE_NUM_BLOCKS")
-                return                          # wait for frees
-            req.admit_ts = time.monotonic()
-            # per-segment wait: a preempted request's requeue
-            # restarted the clock, so re-admission must not count
-            # its earlier prefill/decode time as queue wait
-            wait = req.admit_ts - req.enqueue_ts
-            req.queue_wait_s += wait
-            self._h_wait.observe(wait)
-            self._m_hits.inc(n_cached)
-            self._m_misses.inc(len(toks) - n_cached)
-            req.block_ids = matched + fresh
-            self._sched.place(req, slot)
-            self._in_transit = None
-            tracing.trace_event(
-                "serve_admit", rid=req.id, engine=self.engine_id,
-                slot=slot,
-                blocks=len(req.block_ids), cached_tokens=n_cached,
-                queue_wait_s=round(wait, 6),
-                preemptions=req.preemptions)
-            self._prof_async("e", "queue_wait", req)
-            self._prof_async("b", "prefill", req)
+            with telemetry.span("serve_admit",
+                                rid=self._in_transit.id,
+                                slot=slot) as sp_admit:
+                if not self._admit_one(slot, events, sp_admit):
+                    return
 
-            suffix = toks[n_cached:]
-            bucket, fn = self._get_prefill_fn(len(suffix))
-            suf = np.zeros(bucket, np.int32)
-            suf[:len(suffix)] = suffix
-            row = np.zeros(self.max_blocks, np.int32)
-            row[:len(req.block_ids)] = req.block_ids
-            t_pre = time.monotonic()
-            with telemetry.span("serve_prefill"):
-                self._kpools, self._vpools, nxt, logits = fn(
-                    self._wts, self._kpools, self._vpools,
-                    jnp.asarray(row), np.int32(n_cached),
-                    jnp.asarray(suf), np.int32(len(suffix)))
-                # completion barrier, not a transfer: dispatching the
-                # next call while its DONATED pool buffers are still
-                # pending hits a pathological slow path (~7x) in the
-                # runtime's donation bookkeeping
-                jax.block_until_ready(self._kpools)
-            dt_pre = time.monotonic() - t_pre
-            req.prefill_s += dt_pre
-            tracing.trace_event(
-                "serve_prefill", rid=req.id, engine=self.engine_id,
-                slot=slot,
-                suffix_tokens=len(suffix), bucket=bucket,
-                seconds=round(dt_pre, 6))
-            self._prof_async("e", "prefill", req)
-            self._prof_async("b", "decode", req)
-            self._m_prefill.inc(len(suffix))
-            if self.keep_logits:
-                req.logits = logits
-            # register this stream's full blocks for future sharing
-            self.cache.insert(toks, req.block_ids)
-            req.n_past = len(toks)
+    def _admit_one(self, slot, events, sp_admit):
+        """One admission, from the pop to the first token appended.
+        False where the queue's head has to wait for frees."""
+        import jax
+        import jax.numpy as jnp
+        req = self._sched.pop_waiting()
+        try:
+            resilience.inject("serve", "request")
+        except resilience.TransientError as exc:
+            self._fail(req, exc)
+            return True
+        try:
+            # re-check at admission: a snapshot restored into a
+            # smaller pool/context must fail THAT request loudly,
+            # not hang the schedule (submit() already vets fresh
+            # submissions; preemption cannot grow the bound)
+            self._check_servable(len(req.prompt),
+                                 req.max_new_tokens)
+        except RequestTooLargeError as exc:
+            self._fail(req, exc)
+            return True
+        toks = req.tokens
+        matched, n_cached = self.cache.match(toks)
+        need = -(-len(toks) // self.block_size) - len(matched)
+        try:
+            fresh = self._alloc(need)
+        except BlockPoolExhausted:
+            if matched:
+                self.pool.free(matched)     # release the match
+            self._sched.push_front(req)
+            self._in_transit = None
+            if not self._sched.any_running():
+                raise SchedulingError(
+                    f"request {req.id} needs {need} fresh "
+                    "blocks but the pool cannot ever provide "
+                    "them — raise MXTPU_SERVE_NUM_BLOCKS")
+            return False                    # wait for frees
+        req.admit_ts = time.monotonic()
+        # per-segment wait: a preempted request's requeue
+        # restarted the clock, so re-admission must not count
+        # its earlier prefill/decode time as queue wait
+        wait = req.admit_ts - req.enqueue_ts
+        req.queue_wait_s += wait
+        self._h_wait.observe(wait)
+        self._m_hits.inc(n_cached)
+        self._m_misses.inc(len(toks) - n_cached)
+        req.block_ids = matched + fresh
+        self._sched.place(req, slot)
+        self._in_transit = None
+        sp_admit.set(cached_tokens=n_cached)
+        tracing.trace_event(
+            "serve_admit", rid=req.id, engine=self.engine_id,
+            slot=slot,
+            blocks=len(req.block_ids), cached_tokens=n_cached,
+            queue_wait_s=round(wait, 6),
+            preemptions=req.preemptions)
+        self._prof_async("e", "queue_wait", req)
+        self._prof_async("b", "prefill", req)
+
+        suffix = toks[n_cached:]
+        bucket, fn = self._get_prefill_fn(len(suffix))
+        suf = np.zeros(bucket, np.int32)
+        suf[:len(suffix)] = suffix
+        row = np.zeros(self.max_blocks, np.int32)
+        row[:len(req.block_ids)] = req.block_ids
+        row, suf = jnp.asarray(row), jnp.asarray(suf)
+        with telemetry.span("serve_prefill", rid=req.id,
+                            tokens=len(suffix),
+                            bucket=bucket) as sp_pre:
+            self._kpools, self._vpools, nxt, logits = fn(
+                self._wts, self._kpools, self._vpools,
+                row, np.int32(n_cached), suf, np.int32(len(suffix)))
+            # completion barrier, not a transfer: dispatching the
+            # next call while its DONATED pool buffers are still
+            # pending hits a pathological slow path (~7x) in the
+            # runtime's donation bookkeeping
+            jax.block_until_ready(self._kpools)
+        req.prefill_s += sp_pre.elapsed
+        tracing.trace_event(
+            "serve_prefill", rid=req.id, engine=self.engine_id,
+            slot=slot,
+            suffix_tokens=len(suffix), bucket=bucket,
+            seconds=round(sp_pre.elapsed, 6))
+        self._prof_async("e", "prefill", req)
+        self._prof_async("b", "decode", req)
+        self._m_prefill.inc(len(suffix))
+        self._m_prefill_padded.inc(bucket)
+        if self.keep_logits:
+            req.logits = logits
+        # register this stream's full blocks for future sharing
+        self.cache.insert(toks, req.block_ids)
+        req.n_past = len(toks)
+        with telemetry.span("serve_token_fetch"):
             tok = int(np.asarray(nxt))  # sync-ok: first-token read seeds the decode loop
-            self._append_token(req, tok, events)
+        self._append_token(req, tok, events)
+        return True
 
     def _grow(self):
         """Ensure every runner owns the block its next position
@@ -1280,54 +1305,65 @@ class ServingEngine:
         the process is the heartbeat monitor's."""
         import jax
         import jax.numpy as jnp
-        t_step = time.monotonic()
-        resilience.inject("serve", "step")
         B, MB = self.max_batch, self.max_blocks
-        tokens = np.zeros(B, np.int32)
-        npast = np.zeros(B, np.int32)
-        tables = np.zeros((B, MB), np.int32)
         slots = self._sched.slots
-        for i, req in enumerate(slots):
-            if req is None:
-                continue
-            tokens[i] = req.generated[-1]
-            npast[i] = req.n_past
-            tables[i, :len(req.block_ids)] = req.block_ids
+        running = self._sched.n_running()
+        with telemetry.span("serve_decode_prep",
+                            running=running) as sp_prep:
+            # inside the span: the watchdog below counts an injected
+            # hang as part of the step
+            resilience.inject("serve", "step")
+            tokens = np.zeros(B, np.int32)
+            npast = np.zeros(B, np.int32)
+            tables = np.zeros((B, MB), np.int32)
+            for i, req in enumerate(slots):
+                if req is None:
+                    continue
+                tokens[i] = req.generated[-1]
+                npast[i] = req.n_past
+                tables[i, :len(req.block_ids)] = req.block_ids
+            tables, npast, tokens = (jnp.asarray(tables),
+                                     jnp.asarray(npast),
+                                     jnp.asarray(tokens))
         fn = self._get_step_fn()
-        with telemetry.span("serve_decode"):
+        with telemetry.span("serve_decode", running=running) as sp_dec:
             self._kpools, self._vpools, nxt, logits = fn(
                 self._wts, self._kpools, self._vpools,
-                jnp.asarray(tables), jnp.asarray(npast),
-                jnp.asarray(tokens))
-            # completion barrier (see _admit): the token read below
-            # already serializes the loop; waiting on the donated
-            # pools too keeps the NEXT dispatch off the slow path
+                tables, npast, tokens)
+            # completion barrier (see _admit_one): the token read
+            # below already serializes the loop; waiting on the
+            # donated pools too keeps the NEXT dispatch off the slow
+            # path
             jax.block_until_ready(self._kpools)
-        dt_step = time.monotonic() - t_step
+        dt_step = sp_prep.elapsed + sp_dec.elapsed
         if self.step_timeout > 0 and dt_step > self.step_timeout:
             tracing.trace_event(
                 "serve_step_overrun", engine=self.engine_id,
                 seconds=round(dt_step, 6), budget=self.step_timeout,
-                running=self._sched.n_running())
+                running=running)
             get_logger().warning(
                 "serving: decode step took %.3fs against the %.3fs "
                 "budget (MXTPU_SERVE_STEP_TIMEOUT); flight-recorder "
                 "post-mortem follows when MXTPU_TRACE_DUMP is set",
                 dt_step, self.step_timeout)
             tracing.dump_on_fault("serve_step_overrun")
-        toks = np.asarray(nxt)  # sync-ok: the per-iteration token read
-        for i, req in enumerate(list(slots)):
-            if req is None:
-                continue
-            # perf ledger: analytic FLOPs for this token at its
-            # context length (host arithmetic; no device reads)
-            self._perf_flops += self.model.decode_flops_per_token(
-                req.n_past)
-            self._perf_tokens += 1
-            req.n_past += 1
-            if self.keep_logits:
-                req.logits = logits[i]
-            self._append_token(req, int(toks[i]), events)
+        with telemetry.span("serve_token_fetch"):
+            toks = np.asarray(nxt)  # sync-ok: the per-iteration token read
+        with telemetry.span("serve_emit") as sp_emit:
+            before = len(events)
+            for i, req in enumerate(list(slots)):
+                if req is None:
+                    continue
+                # perf ledger: analytic FLOPs for this token at its
+                # context length (host arithmetic; no device reads)
+                self._perf_flops += self.model.decode_flops_per_token(
+                    req.n_past)
+                self._perf_tokens += 1
+                req.n_past += 1
+                if self.keep_logits:
+                    req.logits = logits[i]
+                self._append_token(req, int(toks[i]), events)
+            sp_emit.set(emitted=len(events) - before)
 
     def _append_token(self, req, tok, events):
         """Record one emitted token; retire the request when its

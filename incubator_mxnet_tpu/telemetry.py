@@ -17,10 +17,15 @@ slowly-diverging job without attaching a debugger.  Three layers:
   the registry (``span_<name>_seconds`` histogram) AND into the
   chrome://tracing profiler stream when the profiler is running, so
   coarse step phases and fine per-op events land on one timeline.
+  While a ``jax.profiler`` session records, and only then, a span
+  also lies in that session's trace as ``mx.<name>`` (on the device
+  planes' clock) and leaves a ``span`` event with its id, its parent
+  and its fields in the flight recorder (tracing.py).
   Spans never touch device values: they cost two ``perf_counter``
-  reads and add NO device->host syncs (the step sentinel's transfer
-  budget — one scalar read per MXTPU_GUARD_INTERVAL — is preserved;
-  proven by the transfer-budget test in tests/test_telemetry.py).
+  reads and one flag read, and add NO device->host syncs (the step
+  sentinel's transfer budget — one scalar read per
+  MXTPU_GUARD_INTERVAL — is preserved; proven by the transfer-budget
+  test in tests/test_telemetry.py).
 - :class:`TelemetryEmitter` — a daemon thread flushing periodic JSONL
   snapshots (``MXTPU_TELEMETRY_FILE``, every
   ``MXTPU_TELEMETRY_INTERVAL`` seconds, rotated at
@@ -40,9 +45,11 @@ literal name passed to counter()/gauge()/histogram()/span() must be
 declared in the catalog table of docs/observability.md — enforced by
 ``ci/lint.py``.
 """
+import itertools
 import json
 import os
 import re
+import sys
 import threading
 import time
 from collections import deque
@@ -359,6 +366,9 @@ class _NullSpan:
     __slots__ = ()
     elapsed = 0.0
 
+    def set(self, **fields):
+        pass
+
     def __enter__(self):
         return self
 
@@ -368,6 +378,66 @@ class _NullSpan:
 
 NULL_SPAN = _NullSpan()
 
+_SPAN_IDS = itertools.count(1)
+
+
+class _ThreadState(threading.local):
+    """What spans and the compile listeners keep for each thread."""
+
+    def __init__(self):
+        self.stack = []         # ids of this thread's open armed spans
+        self.lowering = None    # (fun_name, seconds) of the last lowering
+        self.cache_hit = False  # the persistent cache answered since
+
+
+_OPEN = _ThreadState()
+# recording sessions of jax.profiler seen by a span so far, and
+# whether the last span that looked found one on
+_SESSION = {"n": 0, "on": False}
+_SPAN_LOCK = threading.Lock()
+_TRACE_ANNOTATION = None        # jax.profiler's, once jax is imported
+
+
+def _hook_jax():
+    """Once jax is imported (this module stays importable before it
+    is: no session can record without it), take its TraceAnnotation
+    and register the compile listeners, once for the process."""
+    global _TRACE_ANNOTATION
+    jax = sys.modules.get("jax")
+    if jax is None or not hasattr(jax, "profiler"):
+        return None
+    with _SPAN_LOCK:
+        if _TRACE_ANNOTATION is None:
+            from jax import monitoring
+            monitoring.register_event_listener(_on_jax_event)
+            monitoring.register_event_duration_secs_listener(
+                _on_jax_duration)
+            _TRACE_ANNOTATION = jax.profiler.TraceAnnotation
+    return _TRACE_ANNOTATION
+
+
+def _armed():
+    """The TraceAnnotation class while a ``jax.profiler`` session is
+    recording, else None.  One flag read.  ``session`` counts the
+    changes from off to on that spans have seen: two sessions with no
+    span between them count as one."""
+    cls = _TRACE_ANNOTATION or _hook_jax()
+    if cls is None or not cls.is_enabled():
+        _SESSION["on"] = False
+        return None
+    if not _SESSION["on"]:
+        with _SPAN_LOCK:
+            if not _SESSION["on"]:
+                _SESSION["n"] += 1
+                _SESSION["on"] = True
+    return cls
+
+
+def _record_span(sid, parent, name, t0, t1, fields):
+    from . import tracing
+    tracing.trace_event("span", id=sid, parent=parent, name=name,
+                        t0=t0, t1=t1, session=_SESSION["n"], **fields)
+
 
 class _Span:
     """Times one wall-clock section into the registry histogram
@@ -376,30 +446,62 @@ class _Span:
     per-op events share a timeline.  Host-side timing only — never
     reads a device value.  The last measured duration stays readable
     as ``.elapsed`` so a fit loop can feed the per-step timeline
-    splits to :class:`AnomalyWatch` without re-timing anything."""
+    splits to :class:`AnomalyWatch` without re-timing anything.
 
-    __slots__ = ("name", "_t0", "elapsed")
+    While a ``jax.profiler`` session records (the only arming there
+    is), the span also lies in the session's trace as the
+    ``TraceAnnotation`` ``mx.<name>``, on the clock the device planes
+    share, and leaves one ``span`` event in the flight recorder:
+    its id, the id of the span open on this thread when it started
+    (``parent``), ``t0``/``t1`` (``perf_counter``) and its fields —
+    small host values such as ``rid`` or ``bucket``, never a device
+    value."""
 
-    def __init__(self, name):
+    __slots__ = ("name", "fields", "_t0", "elapsed", "_ann", "_id",
+                 "_parent")
+
+    def __init__(self, name, fields):
         self.name = name
+        self.fields = fields
         self._t0 = None
+        self._ann = None
         self.elapsed = 0.0
 
+    def set(self, **fields):
+        """Fields known only inside the span (``emitted``, ``rid``)."""
+        self.fields.update(fields)
+
     def __enter__(self):
+        cls = _armed()
+        if cls is not None:
+            stack = _OPEN.stack
+            self._id = next(_SPAN_IDS)
+            self._parent = stack[-1] if stack else None
+            stack.append(self._id)
+            self._ann = cls("mx." + self.name, **self.fields)
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         if self._t0 is None:
             return False
-        t1 = time.perf_counter()
-        self.elapsed = t1 - self._t0
+        t0, t1 = self._t0, time.perf_counter()
+        self.elapsed = t1 - t0
+        self._t0 = None
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
+            stack = _OPEN.stack
+            if stack and stack[-1] == self._id:
+                stack.pop()
+            _record_span(self._id, self._parent, self.name, t0, t1,
+                         self.fields)
         _REGISTRY.histogram(
             f"span_{self.name}_seconds").observe(self.elapsed)
         prof = _profiler()
         if prof is not None and prof.running:
-            prof.add_event(self.name, self._t0, t1, category="span")
-        self._t0 = None
+            prof.add_event(self.name, t0, t1, category="span")
         return False
 
 
@@ -416,12 +518,52 @@ def _profiler():
 _PROF = None
 
 
-def span(name):
+def span(name, **fields):
     """``with telemetry.span("data_wait"): ...`` — see :class:`_Span`.
     Returns the shared no-op span when telemetry is disabled."""
     if not enabled():
         return NULL_SPAN
-    return _Span(name)
+    return _Span(name, fields)
+
+
+# compilations, wherever they happen (jax.monitoring; registered by
+# the first span made after jax is imported)
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+def _on_jax_event(event, **kwargs):
+    if event == _CACHE_HIT_EVENT:
+        _OPEN.cache_hit = True
+
+
+def _on_jax_duration(event, duration, fun_name=None, **kwargs):
+    """Counts every program jax hands to the backend
+    (``xla_compiles_total``; jax 0.9 reports the event also where the
+    persistent cache answers, then with the retrieval's time) and,
+    while a profiler session records, leaves a ``span`` event named
+    ``compile`` under the span open on this thread: which step
+    recompiled.  ``lowering_s`` is the lowering reported just before
+    under the same name; ``cached`` says the persistent cache
+    answered."""
+    if event == _LOWERING_EVENT:
+        _OPEN.lowering = (fun_name, duration)
+        return
+    if event != _COMPILE_EVENT or not enabled():
+        return
+    _REGISTRY.counter("xla_compiles_total").inc()
+    lowered, _OPEN.lowering = _OPEN.lowering, None
+    cached, _OPEN.cache_hit = _OPEN.cache_hit, False
+    if _armed() is None:
+        return
+    t1 = time.perf_counter()
+    stack = _OPEN.stack
+    fields = {"fun_name": fun_name, "cached": cached}
+    if lowered is not None and lowered[0] == fun_name:
+        fields["lowering_s"] = lowered[1]
+    _record_span(next(_SPAN_IDS), stack[-1] if stack else None,
+                 "compile", t1 - duration, t1, fields)
 
 
 # ---------------------------------------------------------------------------

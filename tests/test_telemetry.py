@@ -133,6 +133,131 @@ def test_disabled_mode_has_zero_side_effects(monkeypatch, tmp_path):
     assert not tel.snapshot()["counters"]
 
 
+# ------------------------------------------- spans in a profiler session
+@pytest.fixture
+def fresh_ring():
+    from incubator_mxnet_tpu import tracing
+    tracing.reset_for_tests()
+    yield tracing
+    tracing.reset_for_tests()
+
+
+def test_span_without_a_session_leaves_no_event(fresh_ring):
+    with tel.span("data_wait", rid=3) as sp:
+        with tel.span("host_sync"):
+            pass
+    assert fresh_ring.events("span") == []
+    assert sp.elapsed > 0
+    hists = tel.snapshot()["histograms"]
+    assert hists["span_data_wait_seconds"]["count"] == 1
+    assert hists["span_host_sync_seconds"]["count"] == 1
+
+
+def test_span_in_a_session_records_tree_fields_and_shared_clock(
+        fresh_ring, profiler_session, newest_spans):
+    with profiler_session() as rec:
+        with tel.span("data_wait", rid=7) as outer:
+            with tel.span("host_sync", tokens=5):
+                time.sleep(0.002)
+            with tel.span("optimizer") as last:
+                time.sleep(0.001)
+                last.set(emitted=2)
+    with tel.span("data_wait"):         # the session is over
+        pass
+    evs = {e["name"]: e for e in newest_spans()}
+    assert set(evs) == {"data_wait", "host_sync", "optimizer"}
+    top = evs["data_wait"]
+    assert top["parent"] is None and top["rid"] == 7
+    assert evs["host_sync"]["parent"] == top["id"]
+    assert evs["host_sync"]["tokens"] == 5
+    assert evs["optimizer"]["parent"] == top["id"]
+    assert evs["optimizer"]["emitted"] == 2     # set inside the span
+    for child in (evs["host_sync"], evs["optimizer"]):
+        assert top["t0"] <= child["t0"] <= child["t1"] <= top["t1"]
+    assert evs["host_sync"]["t1"] <= evs["optimizer"]["t0"]
+    assert abs((top["t1"] - top["t0"]) - outer.elapsed) < 1e-9
+    # the histograms count as they do without a session
+    assert tel.snapshot()["histograms"][
+        "span_data_wait_seconds"]["count"] == 2
+    # the same spans lie in the trace as mx.<name>, on one clock:
+    # durations and distances agree with perf_counter's to 0.2 ms
+    traced = {n: (s, d) for n, s, d in rec.host_events()}
+    assert set(traced) == {"mx." + n for n in evs}
+    s0 = traced["mx.data_wait"][0]
+    for name, e in evs.items():
+        start, dur = traced["mx." + name]
+        assert abs(dur / 1e9 - (e["t1"] - e["t0"])) < 2e-4, name
+        assert abs((start - s0) / 1e9 - (e["t0"] - top["t0"])) < 2e-4
+
+
+def test_sessions_are_counted_so_the_newest_can_be_taken_alone(
+        fresh_ring, profiler_session):
+    for _ in range(2):
+        with profiler_session():
+            with tel.span("data_wait"):
+                pass
+        with tel.span("data_wait"):     # a span sees the session end
+            pass
+    first, second = fresh_ring.events("span")
+    assert second["session"] == first["session"] + 1
+    assert second["id"] > first["id"]
+
+
+def test_a_retrace_inside_a_span_gives_a_compile_span_under_it(
+        fresh_ring, profiler_session, newest_spans):
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def retraced_here(x):
+        return x * 3 + 1
+
+    with tel.span("data_wait"):
+        pass                    # the compile listeners are in place
+    x4, x5 = jnp.ones(4), jnp.ones(5)       # made before the count
+    before = tel.counter("xla_compiles_total").value
+    with profiler_session():
+        with tel.span("forward_backward") as sp:
+            retraced_here(x4)
+            retraced_here(x4)               # no second compile
+            with tel.span("host_sync"):
+                retraced_here(x5)           # a new shape: a retrace
+    evs = newest_spans()
+    mine = [e for e in evs if e["name"] == "compile"
+            and "retraced_here" in e["fun_name"]]
+    by_name = {e["name"]: e for e in evs if e["name"] != "compile"}
+    assert [e["parent"] for e in mine] == [
+        by_name["forward_backward"]["id"], by_name["host_sync"]["id"]]
+    for e in mine:
+        assert e["cached"] is False and e["t1"] > e["t0"]
+        assert e["t0"] >= by_name["forward_backward"]["t0"]
+    compiles = [e for e in evs if e["name"] == "compile"]
+    assert tel.counter("xla_compiles_total").value - before \
+        == len(compiles) >= 2
+    assert sp.elapsed > 0
+    # without a session a compile is counted and leaves no event
+    retraced_here(jnp.ones(6))
+    assert tel.counter("xla_compiles_total").value - before \
+        > len(compiles)
+    assert len(newest_spans()) == len(evs)
+
+
+def test_disabled_mode_spans_stay_silent_in_a_session(
+        monkeypatch, fresh_ring, profiler_session):
+    import jax
+    import jax.numpy as jnp
+    monkeypatch.setenv("MXTPU_TELEMETRY", "0")
+    with profiler_session() as rec:
+        with tel.span("data_wait", rid=1) as sp:
+            sp.set(emitted=1)
+            jax.jit(lambda x: x - 2)(jnp.ones(3))
+    assert sp is tel.NULL_SPAN and sp.elapsed == 0.0
+    assert rec.host_events() == []
+    monkeypatch.delenv("MXTPU_TELEMETRY")
+    assert fresh_ring.events("span") == []
+    assert "xla_compiles_total" not in tel.snapshot()["counters"]
+
+
 # ------------------------------------------------------------- emitter
 def test_emitter_flush_writes_jsonl_and_atomic_prom(tmp_path):
     tel.counter("train_steps_total").inc(5)

@@ -537,6 +537,155 @@ def test_serving_disabled_telemetry_records_nothing(monkeypatch):
     assert len(eng.stats()["requests"]) == 1
 
 
+# ------------------------------------- program spans (profiler session)
+def _tree(evs):
+    """(by id, id -> children in order of start)."""
+    by_id = {e["id"]: e for e in evs}
+    kids = {}
+    for e in sorted(evs, key=lambda e: e["t0"]):
+        kids.setdefault(e["parent"], []).append(e)
+    return by_id, kids
+
+
+def _assert_children_lie_inside_in_order(evs):
+    by_id, kids = _tree(evs)
+    for parent, children in kids.items():
+        if parent is None:
+            continue
+        lo = by_id[parent]["t0"]
+        for c in children:
+            assert lo <= c["t0"] <= c["t1"] <= by_id[parent]["t1"], c
+            lo = c["t1"]            # siblings do not overlap
+
+
+def test_engine_step_spans_form_the_tree_with_request_ids(
+        profiler_session, newest_spans):
+    from incubator_mxnet_tpu.serving import ServingEngine
+    eng = ServingEngine(_tiny_lm(), max_batch=2, block_size=4,
+                        num_blocks=64, prefix_cache=False)
+    eng.submit([1, 2, 3], 2)            # warm prefill_4 and decode
+    eng.run()
+    tokens0 = tel.counter("serving_prefill_tokens_total").value
+    padded0 = tel.counter("serving_prefill_padded_tokens_total").value
+    assert tracing.events("span") == []     # no session: no event
+    req = eng.submit([5, 6, 7, 8, 9], 3)    # suffix 5 -> bucket 8
+    with profiler_session() as rec:
+        eng.step()                          # admission + one decode
+        eng.step()                          # decode only
+    eng.run()
+    evs = newest_spans()
+    by_id, kids = _tree(evs)
+    first, second = kids[None]
+    assert first["name"] == second["name"] == "serve_step"
+    assert first["waiting"] == 1 and first["running"] == 0
+    assert first["emitted"] == 2 and second["emitted"] == 1
+    assert [c["name"] for c in kids[first["id"]]] == [
+        "serve_reap", "serve_admit", "serve_grow",
+        "serve_decode_prep", "serve_decode", "serve_token_fetch",
+        "serve_emit"]
+    assert [c["name"] for c in kids[second["id"]]] == [
+        "serve_reap", "serve_grow", "serve_decode_prep",
+        "serve_decode", "serve_token_fetch", "serve_emit"]
+    admit = kids[first["id"]][1]
+    assert admit["rid"] == req.id and admit["cached_tokens"] == 0
+    assert admit["slot"] == req.last_slot
+    prefill, fetch = (c for c in kids[admit["id"]]
+                      if c["name"] != "compile")
+    assert (prefill["name"], fetch["name"]) == (
+        "serve_prefill", "serve_token_fetch")
+    assert (prefill["rid"], prefill["tokens"], prefill["bucket"]) \
+        == (req.id, 5, 8)
+    # the new bucket compiled inside the traced step: the compile is
+    # found under the prefill, by the name the program is counted under
+    named = [e for e in evs if e["name"] == "compile"
+             and "serve_prefill_8" in e["fun_name"]]
+    assert [e["parent"] for e in named] == [prefill["id"]]
+    _assert_children_lie_inside_in_order(
+        [e for e in evs if e["name"] != "compile"])
+    # a step's children cover it but for its own self time
+    for step in (first, second):
+        own = (step["t1"] - step["t0"]) - sum(
+            c["t1"] - c["t0"] for c in kids[step["id"]])
+        assert 0 <= own < 0.2
+    # every span lies in the trace under mx.<name>, equally long
+    traced = sorted((s, n, d) for n, s, d in rec.host_events())
+    spans = sorted((e["t0"], "mx." + e["name"], e["t1"] - e["t0"])
+                   for e in evs if e["name"] != "compile")
+    assert [n for _, n, _ in traced] == [n for _, n, _ in spans]
+    for (_, name, dur_ns), (_, _, dur_s) in zip(traced, spans):
+        assert abs(dur_ns / 1e9 - dur_s) < 2e-4, name
+    # the one stopwatch: the lifecycle event and the request carry
+    # the prefill span's own duration
+    event, = tracing.events("serve_prefill", rid=req.id)
+    assert event["seconds"] == round(prefill["t1"] - prefill["t0"], 6)
+    assert req.prefill_s == pytest.approx(
+        prefill["t1"] - prefill["t0"], abs=1e-9)
+    # padding is counted where the bucket is chosen
+    assert tel.counter("serving_prefill_tokens_total").value \
+        - tokens0 == 5
+    assert tel.counter("serving_prefill_padded_tokens_total").value \
+        - padded0 == 8
+
+
+def test_sharded_train_step_spans_first_call_and_later(
+        profiler_session, newest_spans):
+    import jax
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu import parallel
+    mx.random.seed(0)
+    net = nn.HybridSequential()
+    with net.name_scope():
+        net.add(nn.Dense(16, activation="relu"))
+        net.add(nn.Dense(4))
+    net.initialize(mx.initializer.Xavier())
+    rs = np.random.RandomState(0)
+    x = jnp.asarray(rs.rand(8, 12), jnp.float32)
+    y = jnp.asarray(rs.randint(0, 4, (8,)), jnp.int32)
+    step = parallel.ShardedTrainStep(
+        net, optimizer="sgd", optimizer_params=dict(learning_rate=0.1),
+        mesh=parallel.make_mesh(devices=jax.devices()[:1]),
+        example_args=[x])
+    with profiler_session() as rec:
+        step(x, y)
+        step(x, y)
+    evs = newest_spans()
+    by_id, kids = _tree(evs)
+    first, second = kids[None]
+    assert (first["name"], first["step"]) == ("train_step", 0)
+    assert (second["name"], second["step"]) == ("train_step", 1)
+
+    def names(parent):
+        return [c["name"] for c in kids[parent["id"]]]
+    assert names(first) == ["train_preflight", "train_build",
+                            "train_put", "train_dispatch"]
+    assert names(second) == ["train_put", "train_dispatch"]
+    dispatch = kids[first["id"]][-1]
+    assert [c["fun_name"] for c in kids[dispatch["id"]]
+            if c["name"] == "compile"] == ["jit(step)"]
+    _assert_children_lie_inside_in_order(
+        [e for e in evs if e["name"] != "compile"])
+    traced = [n for n, _, _ in rec.host_events()]
+    assert sorted(traced) == sorted(
+        "mx." + e["name"] for e in evs if e["name"] != "compile")
+
+
+def test_engine_programs_are_named_as_they_are_counted(
+        profiler_session, newest_spans):
+    from incubator_mxnet_tpu.serving import ServingEngine
+    eng = ServingEngine(_tiny_lm(), max_batch=1, block_size=4,
+                        num_blocks=32, prefix_cache=False)
+    with profiler_session():
+        eng.submit([1, 2, 3], 2)
+        eng.run()
+    by_id, _ = _tree(newest_spans())
+    under = {e["fun_name"]: by_id[e["parent"]]["name"]
+             for e in by_id.values() if e["name"] == "compile"
+             and "serve_" in e["fun_name"]}
+    assert under == {"jit(serve_prefill_4)": "serve_prefill",
+                     "jit(serve_decode)": "serve_decode"}
+    assert set(eng.trace_counts) == {"decode", "prefill_4"}
+
+
 # ----------------------------------------------- profiler async events
 def test_profiler_async_events_and_lanes(tmp_path):
     from incubator_mxnet_tpu.serving import ServingEngine
