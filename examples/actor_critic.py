@@ -9,8 +9,15 @@ pole falls unless the agent pushes the cart under it; episodes end
 on |theta| > 12 deg, |x| > 2.4, or 200 steps.  An untrained policy
 survives ~20 steps; a trained one balances for the full horizon.
 
---quick is the CI gate: mean episode length over the last 10
-episodes must be at least 3x the first-10 mean.
+Episodes end after 10 to 200 steps, and a device that compiles one
+program per shape would compile every operator of the update anew for
+each new length: so every episode is padded to --max-steps and its
+means are taken under a weight of 1/n on the n real steps, and the
+net is hybridized, which leaves one program for the environment step
+and one for the update.
+
+--quick is the CI gate (150 episodes): mean episode length over the
+last 10 episodes must be at least 3x the first-10 mean.
 """
 import argparse
 import json
@@ -76,7 +83,7 @@ def main(argv=None):
     from incubator_mxnet_tpu import autograd, gluon, nd
     from incubator_mxnet_tpu.gluon import nn
 
-    class ActorCritic(gluon.Block):
+    class ActorCritic(gluon.HybridBlock):
         def __init__(self, **kw):
             super().__init__(**kw)
             with self.name_scope():
@@ -84,7 +91,7 @@ def main(argv=None):
                 self.policy = nn.Dense(2)
                 self.value = nn.Dense(1)
 
-        def forward(self, x):
+        def hybrid_forward(self, F, x):
             h = self.trunk(x)
             return self.policy(h), self.value(h)
 
@@ -94,6 +101,7 @@ def main(argv=None):
 
     net = ActorCritic(prefix="ac_")
     net.initialize(mx.init.Xavier())
+    net.hybridize()     # one program for the step, one for the update
     trainer = gluon.Trainer(net.collect_params(), "adam",
                             {"learning_rate": args.lr})
 
@@ -122,18 +130,27 @@ def main(argv=None):
             ret[t] = acc
         ret = (ret - ret.mean()) / (ret.std() + 1e-6)
 
-        xs = nd.array(np.stack(states))
-        acts = np.array(actions)
-        rets = nd.array(ret)
-        onehot = nd.array(np.eye(2, dtype=np.float32)[acts])
+        # every episode padded to max_steps: the means over the
+        # episode's n steps become sums under weights of 1/n and 0
+        n = len(rewards)
+        xs = np.zeros((args.max_steps, 4), np.float32)
+        xs[:n] = states
+        onehot = np.zeros((args.max_steps, 2), np.float32)
+        onehot[np.arange(n), actions] = 1.0
+        rets = np.zeros(args.max_steps, np.float32)
+        rets[:n] = ret
+        weight = np.zeros(args.max_steps, np.float32)
+        weight[:n] = 1.0 / n
+        xs, onehot, rets, weight = (nd.array(a) for a in
+                                    (xs, onehot, rets, weight))
         with autograd.record():
             logits, values = net(xs)
             logp = mx.nd.log_softmax(logits)
             chosen = (logp * onehot).sum(axis=1)
             adv = rets - values.reshape(-1)
             # critic baseline enters the actor term detached
-            actor = -(chosen * adv.detach()).mean()
-            critic = (adv ** 2).mean()
+            actor = -(chosen * adv.detach() * weight).sum()
+            critic = (adv ** 2 * weight).sum()
             loss = actor + 0.5 * critic
         loss.backward()
         trainer.step(1)

@@ -2,7 +2,8 @@
 """DCGAN through the Gluon imperative path (ref role:
 example/gluon/dcgan.py — ConvTranspose generator vs conv
 discriminator, alternating adversarial updates with
-SigmoidBinaryCrossEntropyLoss and two Trainers).
+SigmoidBinaryCrossEntropyLoss and two Trainers).  Both nets are
+hybridized: the loop is imperative, a net's forward is one program.
 
 Data is synthetic (zero-egress): 16x16 single-channel images of a
 bright centered disk over a dark field, with per-sample radius and
@@ -73,7 +74,7 @@ def build_nets(latent):
     with g.name_scope():
         g.add(nn.Dense(4 * 4 * 32))
         g.add(nn.HybridLambda(
-            lambda F, x: F.reshape(x, (-1, 32, 4, 4)), "to4x4"))
+            lambda F, x: F.reshape(x, shape=(-1, 32, 4, 4)), "to4x4"))
         g.add(nn.BatchNorm())
         g.add(nn.Activation("relu"))
         # 4x4 -> 8x8 -> 16x16
@@ -110,6 +111,10 @@ def main(argv=None):
     gen, disc = build_nets(args.latent)
     gen.initialize(mx.init.Normal(0.02))
     disc.initialize(mx.init.Normal(0.02))
+    # one compiled program a net and batch shape instead of an eager
+    # dispatch an operator: the loop below stays imperative
+    gen.hybridize()
+    disc.hybridize()
 
     g_tr = gluon.Trainer(gen.collect_params(), "adam",
                          {"learning_rate": args.lr, "beta1": 0.5})

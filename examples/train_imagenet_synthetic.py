@@ -10,7 +10,8 @@ executable (parallel.ShardedTrainStep); batches are prefetched to
 device, so the step never waits on a host-to-device copy.
 
 Runs unchanged on CPU (virtual mesh) and TPU.  --quick is the CI
-gate: tiny shapes, asserts the loss dropped.
+gate: tiny shapes (resnet18_v1, 32x32, batch 32, 2 epochs of 4
+steps), asserts the loss dropped.
 """
 import argparse
 import json
@@ -57,7 +58,7 @@ def main(argv=None):
         args.batch_size = 32
         args.num_classes = 10
         args.num_epochs = 2
-        args.iters_per_epoch = 16
+        args.iters_per_epoch = 4
         args.lr = 0.05
 
     import jax

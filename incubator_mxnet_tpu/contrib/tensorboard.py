@@ -5,12 +5,14 @@ summary writer so training curves show up in TensorBoard).
 Writer resolution order:
 1. an explicit ``summary_writer`` object (anything with
    ``add_scalar(tag, value, step)``),
-2. ``torch.utils.tensorboard.SummaryWriter`` (torch-cpu ships in
-   this image) writing real TF event files,
+2. a ``SummaryWriter`` writing real TF event files: ``tensorboardX``'s
+   first, a light import, else ``torch.utils.tensorboard``'s (which
+   imports all of torch, and TensorFlow where that is installed),
 3. a JSONL fallback writing ``{"tag", "value", "step"}`` lines —
    zero-dependency, parseable by ``tools/parse_log.py`` style
    tooling.
 """
+import importlib
 import json
 import os
 import time
@@ -42,11 +44,12 @@ class _JsonlWriter:
 
 def make_writer(logdir):
     """Best available summary writer for ``logdir``."""
-    try:
-        from torch.utils.tensorboard import SummaryWriter
-        return SummaryWriter(logdir)
-    except Exception:
-        return _JsonlWriter(logdir)
+    for module in ("tensorboardX", "torch.utils.tensorboard"):
+        try:
+            return importlib.import_module(module).SummaryWriter(logdir)
+        except Exception:
+            continue
+    return _JsonlWriter(logdir)
 
 
 def log_telemetry(writer, snapshot=None, step=None):

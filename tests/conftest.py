@@ -20,10 +20,52 @@ import jax  # noqa: E402
 jax.config.update("jax_platforms", "cpu")
 
 import contextlib  # noqa: E402
+import faulthandler  # noqa: E402
 import glob  # noqa: E402
+import signal  # noqa: E402
+import sys  # noqa: E402
 import tempfile  # noqa: E402
+import threading  # noqa: E402
 
 import pytest  # noqa: E402
+
+# Seconds one test's call phase may take, and the most a test may wait
+# for a whole child run (tests/test_suite_limits.py holds every literal
+# ``timeout=`` under tests/ to it).  The driver's clock is for the whole
+# suite: one hang must cost this much of it, not all of it.
+TEST_LIMIT_S = 300
+
+
+def pytest_configure(config):
+    config.addinivalue_line(
+        "markers", "slow: over 60 s on a builder's machine whatever was "
+        "tried; the driver's command deselects it, CHANGES.md names each")
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_call(item):
+    """Fail a test whose call phase passes ``TEST_LIMIT_S``.  The
+    watchdog thread writes every thread's stack at the limit (it needs
+    no interpreter, so a hang inside native code shows too); a second
+    later SIGALRM raises in the main thread, which ends any wait the
+    interpreter can leave, and the run goes on."""
+    if threading.current_thread() is not threading.main_thread():
+        return (yield)
+
+    def expired(signum, frame):
+        pytest.fail(f"{item.nodeid} passed the suite's limit of "
+                    f"{TEST_LIMIT_S} s a test (tests/conftest.py)")
+
+    # file descriptor 2: the test's captured stderr, or the terminal
+    faulthandler.dump_traceback_later(TEST_LIMIT_S, file=sys.__stderr__)
+    previous = signal.signal(signal.SIGALRM, expired)
+    signal.setitimer(signal.ITIMER_REAL, TEST_LIMIT_S + 1)
+    try:
+        return (yield)
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
+        faulthandler.cancel_dump_traceback_later()
 
 
 class _Session:

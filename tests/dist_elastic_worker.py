@@ -6,6 +6,7 @@ detection).  Spawned by tests/test_dist_launch.py — not a pytest
 module."""
 import glob
 import os
+import signal
 import sys
 
 import jax
@@ -24,6 +25,11 @@ def main():
     ckdir = os.environ["MXTPU_ELASTIC_DIR"]
     attempt = int(os.environ.get("MXTPU_RESTART_ATTEMPT", "0"))
     kv = mx.kvstore.create("dist_sync")
+    # jax.distributed takes SIGTERM for its preemption notice; a rank
+    # left in a collective with a dead peer then sits out the
+    # launcher's 10 s of grace before the kill.  That wait is not what
+    # this worker is here to show: die at the launcher's first signal.
+    signal.signal(signal.SIGTERM, signal.SIG_DFL)
     r = kv.rank
     prefix = os.path.join(ckdir, "model")
 

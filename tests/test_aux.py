@@ -310,12 +310,11 @@ def test_tensorboard_log_metrics_callback(tmp_path):
     assert all(r["tag"] == "train-accuracy" for r in rows)
     assert all(r["value"] == 1.0 for r in rows)
 
-    # the real torch SummaryWriter path, when available
-    try:
-        from torch.utils.tensorboard import SummaryWriter  # noqa
-    except Exception:
-        return
+    # a real SummaryWriter, when one is installed (make_writer tries
+    # the one that imports fast first)
     cb2 = LogMetricsCallback(str(tmp_path / "tb2"), prefix="t")
+    if isinstance(cb2.writer, _JsonlWriter):
+        return
     cb2(Param(epoch=0, nbatch=1, eval_metric=metric))
     cb2.writer.flush()
     assert os.listdir(str(tmp_path / "tb2"))

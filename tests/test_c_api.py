@@ -305,7 +305,7 @@ def test_c_api_standalone_client(tmp_path):
     env["MXTPU_FORCE_CPU"] = "1"
     env.pop("XLA_FLAGS", None)
     r = subprocess.run([demo], capture_output=True, text=True,
-                       timeout=600, env=env)
+                       timeout=300, env=env)
     assert r.returncode == 0, r.stderr[-2000:]
     lines = r.stdout.strip().splitlines()
 
@@ -577,7 +577,7 @@ def test_c_symbol_compose_and_native_train(tmp_path):
     env["MXTPU_FORCE_CPU"] = "1"
     env.pop("XLA_FLAGS", None)
     r = subprocess.run([demo], capture_output=True, text=True,
-                       timeout=600, env=env)
+                       timeout=300, env=env)
     assert r.returncode == 0, (r.stdout + r.stderr)[-2000:]
     lines = r.stdout.strip().splitlines()
     args = [l.split()[1] for l in lines if l.startswith("ARG ")]

@@ -187,7 +187,7 @@ def test_c_predict_standalone_client(tmp_path):
     env.pop("XLA_FLAGS", None)
     r = subprocess.run(
         [demo, prefix + "-symbol.json", prefix + "-0000.params"],
-        capture_output=True, text=True, timeout=600, env=env)
+        capture_output=True, text=True, timeout=300, env=env)
     assert r.returncode == 0, r.stderr[-2000:]
     vals = np.array([float(v) for v in r.stdout.split()],
                     dtype=np.float32)

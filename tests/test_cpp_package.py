@@ -113,7 +113,7 @@ def test_cpp_package_compose_and_train(tmp_path):
     env["MXTPU_FORCE_CPU"] = "1"
     env.pop("XLA_FLAGS", None)
     r = subprocess.run([demo], capture_output=True, text=True,
-                       timeout=600, env=env)
+                       timeout=300, env=env)
     assert r.returncode == 0, r.stderr[-2000:]
     lines = r.stdout.strip().splitlines()
     first, last = map(float, lines[0].split()[1:])

@@ -229,7 +229,10 @@ def test_maybe_start_gating_portfile_and_idempotence(tmp_path,
     assert debugz.server() is None
 
 
-def test_rpc_call_once_and_heartbeat_age(tmp_path):
+def test_rpc_call_once_and_heartbeat_age(tmp_path, monkeypatch):
+    # as in a process that has not beaten yet: another file's
+    # heartbeat in the same worker leaves its last beat behind
+    monkeypatch.setitem(rz._HB_STATE, "last_beat", None)
     assert rz.heartbeat_age() is None               # no beat yet
     rz.start_heartbeat(path=str(tmp_path / "hb"), interval=0.05)
     try:

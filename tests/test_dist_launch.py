@@ -92,7 +92,7 @@ def test_elastic_resume_from_checkpoint(tmp_path):
         [sys.executable, os.path.join(repo, "tools", "launch.py"),
          "-n", "2", "--max-restarts", "2", "--", sys.executable,
          os.path.join(repo, "tests", "dist_elastic_worker.py")],
-        capture_output=True, text=True, timeout=600, env=env,
+        capture_output=True, text=True, timeout=300, env=env,
         cwd=repo)
     out = r.stdout + r.stderr
     assert r.returncode == 0, out[-3000:]
@@ -222,7 +222,7 @@ def test_elastic_shrink_grow_reshard_e2e(tmp_path):
          "--env", f"MXTPU_ELASTIC_REC={rec}",
          "--", sys.executable,
          os.path.join(repo, "tests", "dist_elastic_reshard_worker.py")],
-        capture_output=True, text=True, timeout=600, env=env,
+        capture_output=True, text=True, timeout=300, env=env,
         cwd=repo)
     out = r.stdout + r.stderr
     assert r.returncode == 0, out[-4000:]

@@ -1,6 +1,7 @@
 """Driver-config example workloads with convergence gates:
 config 2 (image classification, mesh path),
-config 3 (bucketed LSTM perplexity), config 4 (SSD detection mAP).
+config 3 (bucketed LSTM perplexity), config 4 (SSD detection mAP);
+the transformer_lm gates are in test_examples_lm.py.
 
 Each example runs in --quick mode, which asserts its own gate
 (loss / perplexity / mAP); these tests run them in-process on the
@@ -48,22 +49,6 @@ def test_ssd_anchor_scale_8732():
     ex.anchor_scale_check(mx, nd)
 
 
-def test_transformer_lm_quick():
-    import transformer_lm as ex
-    summary = ex.main(["--quick"])
-    assert summary["final_loss"] < summary["first_loss"] * 0.5
-    assert "fox" in summary["generated"]
-
-
-def test_transformer_lm_seq_parallel_quick():
-    from incubator_mxnet_tpu.parallel import make_mesh, use_mesh
-    import transformer_lm as ex
-    with use_mesh(make_mesh(dp=2, sp=4)):
-        summary = ex.main(["--quick", "--seq-parallel",
-                           "--batch-size", "16"])
-    assert summary["final_loss"] < summary["first_loss"] * 0.5
-
-
 def test_train_mnist_quick():
     """Config 1: MLP on MNIST via the Module API (ref:
     example/image-classification/train_mnist.py)."""
@@ -81,13 +66,3 @@ def test_linear_classification_quick():
     assert summary["val_acc"] > 0.8
     # the sparse pull must actually be saving traffic
     assert summary["pull_savings"] > 0.25
-
-
-def test_transformer_lm_moe_quick():
-    """--moe-experts: the example trains a routed-MoE LM to the same
-    convergence gate, on the mesh, with the aux loss in the
-    objective."""
-    import transformer_lm as ex
-    summary = ex.main(["--quick", "--moe-experts", "4"])
-    assert summary["final_loss"] < summary["first_loss"] * 0.5
-    assert "fox" in summary["generated"]

@@ -5,6 +5,7 @@ import sys
 import tempfile
 
 import numpy as np
+import pytest
 
 from incubator_mxnet_tpu import recordio as rio
 
@@ -96,7 +97,7 @@ def test_pipeline_bench_mode(tmp_path):
     env.pop("XLA_FLAGS", None)
     repo = os.path.dirname(TOOLS)
     r = subprocess.run([sys.executable, os.path.join(repo, "bench.py")],
-                       capture_output=True, text=True, timeout=420,
+                       capture_output=True, text=True, timeout=300,
                        env=env, cwd=repo)
     assert r.returncode == 0, r.stderr[-2000:]
     rec = json.loads(r.stdout.strip().splitlines()[-1])
@@ -113,7 +114,7 @@ def _chip_smoke(*args, **env_changes):
     env.pop("XLA_FLAGS", None)
     return subprocess.run(
         [sys.executable, os.path.join(repo, "chip_smoke.py"), *args],
-        capture_output=True, text=True, timeout=420, env=env, cwd=repo)
+        capture_output=True, text=True, timeout=300, env=env, cwd=repo)
 
 
 def test_chip_smoke_needs_a_tpu():
@@ -124,6 +125,12 @@ def test_chip_smoke_needs_a_tpu():
     assert r.stdout == "" and "needs a TPU" in r.stderr
 
 
+# slow: 58 s alone and 74-82 s among six workers in a checkout whose
+# compile cache is empty, 31 s of it the ResNet-50 phase (some 170 eager
+# operators compiled to settle its shapes, then the step compiled twice,
+# PERF.md section 7.6); no argument makes ResNet-50 smaller.  Run it by
+# hand before a chip call: pytest tests/test_tools.py -k rehearsal
+@pytest.mark.slow
 def test_chip_smoke_rehearsal_on_cpu_is_never_a_result():
     """The first rehearsal of the on-chip-measurement guide, kept as a
     test: every phase end to end at a tiny size on the CPU, kernels
