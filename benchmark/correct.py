@@ -2,8 +2,9 @@
 
 Training: the program's first steps against the plain reference's
 (reference/), number by number, each with a limit of its own from
-limits/<cell>.json.  Serving: the widest gap by which a served token's
-logit lies below the reference's best.  PERF.md gives the readings
+limits/<cell>.json.  Serving: how far the served tokens' logits lie
+below the reference's best, against what the control is expected to
+lose in the same text (``serve.gaps``).  PERF.md gives the readings
 each limit was set from.
 """
 import statistics
@@ -27,11 +28,15 @@ def _norms(tree):
 
 
 def change_norms(shapes, seed, params_now, strip=""):
-    """name -> norm of (leaf now - leaf as made from the seed)."""
-    def fn(now):
-        made = weights.traced(shapes, weights.fold(seed))
+    """name -> norm of (leaf now - leaf as made from the seed).  The
+    seed's key is an argument of the program, not a constant in it:
+    one program serves every seed (as a constant it cost the train
+    cell a compilation of 30 s in every run with a new seed: PERF.md,
+    PR 26)."""
+    def fn(now, key):
+        made = weights.traced(shapes, key)
         return _norms({n: now[strip + n] - made[n] for n in made})
-    return jax.jit(fn)(params_now)
+    return jax.jit(fn)(params_now, weights.fold(seed))
 
 
 def reference_training(fam, cfg, seed, batches, mode="f32",

@@ -6,6 +6,13 @@ A multiply-add is two operations.  Recomputation (remat, the flash
 kernel's second pass over the scores) is not counted: these are the
 operations the mathematics requires, not the ones a program issues.
 
+What is here is the count of facebook/opt's block (``ffn_dim``, four
+``d x d`` matrices a layer, scores ``d`` wide).  A family with another
+block brings its own count in its own file (models/<family>.py:
+``train_flops``, ``prefill_flops``, ``decode_flops``), beside the
+family and under the benchmark's paths like this file, and held to
+the same rules; ``transformer_lm`` gives the functions below.
+
 Copied arithmetic, originals left in place for a later PR to delete
 (PERF.md, Open questions): ``TransformerLM.train_flops_per_token`` /
 ``decode_flops_per_token`` (which count the full, not the causal,

@@ -8,8 +8,11 @@ reference_loss = ref.loss
 reference_logits = ref.logits
 
 
-def build_program(mx, cfg, ctx, grad_req=None):
-    """The program's own constructor, from the file's keys."""
+def build_program(mx, cfg, ctx, grad_req=None, dtype="float32"):
+    """The program's own constructor, from the file's keys.  The
+    Parameters are cast to ``dtype`` before they are initialized, as a
+    user who loads a checkpoint of that dtype would: no wider copy is
+    ever made."""
     from incubator_mxnet_tpu.gluon.model_zoo.transformer import \
         TransformerLM
     d = cfg["hidden_size"]
@@ -23,6 +26,7 @@ def build_program(mx, cfg, ctx, grad_req=None):
         mlp_ratio=cfg["ffn_dim"] // d, dropout=0.0)
     if grad_req:
         lm.collect_params().setattr("grad_req", grad_req)
+    lm.cast(dtype)
     lm.initialize(mx.initializer.Zero(), ctx=ctx)
     return lm
 
@@ -59,6 +63,12 @@ def train_batches(cfg, traffic, key):
 def train_flops(cfg, traffic):
     return flops.lm_train_flops(cfg, traffic["batch"],
                                 traffic["seq_len"])
+
+
+# what serving a request requires, for ``step_mfu.serve``: a served
+# family counts its own work (serve.py asks for both before set-up)
+prefill_flops = flops.lm_prefill_flops
+decode_flops = flops.lm_decode_flops
 
 
 def units_per_step(traffic):

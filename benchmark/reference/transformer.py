@@ -7,10 +7,12 @@ program's ``TransformerLM`` is what it is (configs/*.json list them
 under ``assumed``): the output head is a matrix of its own, token
 embeddings are scaled by sqrt(d), positions carry no offset of 2.
 
-Parameters are a flat dict under the names of ``param_shapes``.  One
-batch row is computed at a time (``lax.map`` inside each layer, each
-layer under ``jax.checkpoint``), so that the float32 backward pass of
-the 1.3B widths fits one chip beside the optimizer's state.
+Parameters are a flat dict under the names of ``param_shapes``, in
+whatever dtype the checkpoint holds them: each leaf is widened to
+float32 where it is used, which is exact.  One batch row is computed
+at a time (``lax.map`` inside each layer, each layer under
+``jax.checkpoint``), so that the float32 backward pass of the 1.3B
+widths fits one chip beside the optimizer's state.
 """
 import math
 
@@ -81,52 +83,58 @@ def _layer(x, lp, n_heads, mode):
                    mode)
 
 
-def _layer_params(params, i):
+def _layer_params(leaf, i):
     p = f"transformerblock{i}_"
     a = p + "causalselfattention0_"
-    return {"ln1_g": params[p + "layernorm0_gamma"],
-            "ln1_b": params[p + "layernorm0_beta"],
-            "qkv_w": params[a + "dense0_weight"],
-            "qkv_b": params[a + "dense0_bias"],
-            "proj_w": params[a + "dense1_weight"],
-            "proj_b": params[a + "dense1_bias"],
-            "ln2_g": params[p + "layernorm1_gamma"],
-            "ln2_b": params[p + "layernorm1_beta"],
-            "up_w": params[p + "dense0_weight"],
-            "up_b": params[p + "dense0_bias"],
-            "down_w": params[p + "dense1_weight"],
-            "down_b": params[p + "dense1_bias"]}
+    return {"ln1_g": leaf(p + "layernorm0_gamma"),
+            "ln1_b": leaf(p + "layernorm0_beta"),
+            "qkv_w": leaf(a + "dense0_weight"),
+            "qkv_b": leaf(a + "dense0_bias"),
+            "proj_w": leaf(a + "dense1_weight"),
+            "proj_b": leaf(a + "dense1_bias"),
+            "ln2_g": leaf(p + "layernorm1_gamma"),
+            "ln2_b": leaf(p + "layernorm1_beta"),
+            "up_w": leaf(p + "dense0_weight"),
+            "up_b": leaf(p + "dense0_bias"),
+            "down_w": leaf(p + "dense1_weight"),
+            "down_b": leaf(p + "dense1_bias")}
+
+
+def _wide(leaf):
+    return leaf.astype(jnp.float32)
 
 
 def hidden(params, tokens, cfg, mode="f32"):
     """Final-norm hidden states (B, L, d) of tokens (B, L)."""
     d, n_heads = cfg["hidden_size"], cfg["num_attention_heads"]
-    if mode != "f32":
-        params = {n: carried(v, mode) for n, v in params.items()}
+
+    def leaf(name):
+        return carried(_wide(params[name]), mode)
+
     length = tokens.shape[1]
-    x = params["embedding0_weight"][tokens] * math.sqrt(d) \
-        + params["embedding1_weight"][:length][None]
+    x = leaf("embedding0_weight")[tokens] * math.sqrt(d) \
+        + leaf("embedding1_weight")[:length][None]
     x = carried(x, mode)
     for i in range(cfg["num_hidden_layers"]):
-        lp = _layer_params(params, i)
+        lp = _layer_params(leaf, i)
         row = jax.checkpoint(
             lambda xr, lp=lp: _layer(xr, lp, n_heads, mode))
         x = jax.lax.map(row, x)
-    return carried(_layer_norm(x, params["layernorm0_gamma"],
-                               params["layernorm0_beta"]), mode)
+    return carried(_layer_norm(x, leaf("layernorm0_gamma"),
+                               leaf("layernorm0_beta")), mode)
 
 
 def logits(params, tokens, cfg, mode="f32"):
     """Logits (B, L, V), float32."""
     h = hidden(params, tokens, cfg, mode)
-    head = params["dense0_weight"]
+    head = _wide(params["dense0_weight"])
     return jax.lax.map(lambda hr: _dense(hr, head, None, mode), h)
 
 
 def loss(params, tokens, labels, cfg, mode="f32"):
     """Mean next-token cross-entropy."""
     h = hidden(params, tokens, cfg, mode)
-    head = params["dense0_weight"]
+    head = _wide(params["dense0_weight"])
 
     @jax.checkpoint
     def row_loss(args):

@@ -25,7 +25,7 @@ import time
 
 import numpy as np
 
-from . import flops, trace, weights
+from . import trace, weights
 from .reduce_trace import WINDOW_SPAN
 from .traffic import Plan, prefill_buckets
 from .train import memory_peak, settled_block
@@ -35,12 +35,22 @@ PAD_TO = 512          # the reference's sequence lengths are whole
 
 
 def build(h, cell, seed, mx):
-    """The model with seeded weights in its Parameters, and an engine
-    on it."""
+    """The model with seeded weights in its Parameters, of the dtype
+    that the configuration states (``serve.weights_dtype``), and an
+    engine on it.  A family that does not count its own work is
+    turned away here, before set-up is paid."""
     cfg, traffic = cell.config, cell.traffic
     fam = h.family(cfg)
+    lacking = [f for f in ("prefill_flops", "decode_flops")
+               if not hasattr(fam, f)]
+    if lacking:
+        raise AttributeError(
+            f"family {cfg['family']!r} is served by {cell.name} but "
+            f"has no {' and no '.join(lacking)}(cfg, n): a served "
+            "family counts its own work (benchmark/README.md)")
     block = settled_block(fam, mx, cfg, mx.tpu(0),
-                          fam.param_shapes(cfg), seed, trained=False)
+                          fam.param_shapes(cfg), seed, trained=False,
+                          dtype=cfg["serve"]["weights_dtype"])
     eng = mx.serving.ServingEngine(block, **traffic["engine"])
     return fam, block, eng
 
@@ -138,16 +148,17 @@ class Drive:
                      or len(r["tokens"]) != r["req"].max_new_tokens)
         return len(recs), failed
 
-    def flops_between(self, cfg, lo, hi):
-        """Required operations of every prompt taken in and token
-        generated with its time in [lo, hi]."""
+    def flops_between(self, fam, cfg, lo, hi):
+        """Required operations, as the family counts them, of every
+        prompt taken in and token generated with its time in
+        [lo, hi]."""
         total = 0
         for r in self.records.values():
             plen = len(r["prompt"])
             for i, t in enumerate(r["times"]):
                 if lo <= t <= hi:
-                    total += flops.lm_prefill_flops(cfg, plen) if i == 0 \
-                        else flops.lm_decode_flops(cfg, plen + i)
+                    total += fam.prefill_flops(cfg, plen) if i == 0 \
+                        else fam.decode_flops(cfg, plen + i)
         return total
 
     def sample(self, k, seed):
@@ -164,59 +175,102 @@ class Drive:
                 for r in done[:1] + rest]
 
 
+def far_gap_share(gap, margin, error, sigmas, least_flips):
+    """(share, where): the gaps of ``gap`` wider than ``sigmas`` times
+    the control's noise, summed, as a share of what the control is
+    expected to lose at such margins in this text.
+
+    ``margin``  the reference's best logit less its second, at every
+                position compared;
+    ``error``   the control's error on that margin there.  Its root
+                mean square is the control's noise, sigma: every
+                position tells of it, not only the near-ties.
+
+    The control turns a margin m where its error is under -m.  The
+    share of all positions' errors that are, times m, is what it is
+    expected to lose there; the yardstick is the sum of that over the
+    margins wider than ``sigmas * sigma``.  A program half as noisy
+    seldom turns such a margin (its own 1.5 sigmas and more), so the
+    two read five times apart; the mean of all gaps read them three
+    times apart on no dozen seeds.  Where the text gives the control
+    little to turn (a model that repeats itself under wide margins),
+    no tokens tell the two apart and a ratio of a few flips is noise:
+    the yardstick is never under ``least_flips`` turns of the least
+    width that counts.  PERF.md, section 6, has the readings."""
+    gap, margin, error = (np.asarray(v, np.float64)
+                          for v in (gap, margin, error))
+    sigma = float(np.sqrt(np.mean(error ** 2)))
+    far = sigmas * sigma
+    turned = np.searchsorted(np.sort(error), -margin,
+                             side="right") / len(error)
+    keep = margin > far
+    yard = float((margin * turned)[keep].sum())
+    flips = float(turned[keep].sum())
+    share = float(gap[gap > far].sum()) / max(yard, least_flips * far,
+                                              1e-30)
+    return share, (f"{int((gap > far).sum())} gaps beyond {far:.3g}; "
+                   f"the control's yardstick {yard:.3g} over "
+                   f"{flips:.3g} turns, at the least "
+                   f"{least_flips * far:.3g}")
+
+
 def gaps(fam, cfg, seed, sample, of_control=False):
     """What ``correct`` compares in a serve cell, as name -> (number,
     where).  Over every served token of the sample, how far its logit
     lies below the best of the reference (reference/transformer.py in
     ``serve.reference_precision``), run once over prompt and served
-    tokens.  Beside it the control's yardstick: the same for the
-    tokens that the reference in ``serve.control_precision`` puts
-    first at the same positions.
+    tokens.  Beside it the control's yardstick, from the reference in
+    ``serve.control_precision`` at the same positions.
 
-    ``gap_share``  the served tokens' mean gap as a share of the
-                   control's.  A seed's own scale (how close its best
-                   two logits lie) divides out, which the widest gap
-                   alone never separated (PERF.md, section 6).
-    ``token_gap``  the widest gap of a served token.
+    ``far_gap_share``  the served tokens' gaps beyond
+                   ``serve.far_gap_sigmas`` of the control's noise, as
+                   a share of the control's expected loss at such
+                   margins (``far_gap_share`` above).
+    ``token_gap``  the widest gap of a served token (shown, not
+                   compared: no limit separates it, PERF.md).
 
     ``of_control`` puts the control in the program's place: the
-    control's tokens are read as if they had been served."""
+    tokens it puts first are read as if they had been served."""
     import jax
     import jax.numpy as jnp
-    params = weights.make(fam.param_shapes(cfg), seed)
+    params = weights.make(fam.param_shapes(cfg), seed,
+                          cfg["serve"]["weights_dtype"])
     stated = cfg["serve"]["reference_precision"]
     control = cfg["serve"]["control_precision"]
 
     def below_best(params, toks):
         lg = fam.reference_logits(params, toks[None], cfg, stated)[0]
         low = fam.reference_logits(params, toks[None], cfg, control)[0]
-        best = jnp.max(lg, axis=-1)
+        top, at = jax.lax.top_k(lg, 2)
+        low_top = jnp.take_along_axis(low, at, axis=-1)
+        margin = top[:, 0] - top[:, 1]
 
         def gap(chosen):
-            return best - jnp.take_along_axis(
+            return top[:, 0] - jnp.take_along_axis(
                 lg, chosen[:, None], axis=-1)[:, 0]
         # position i chooses the token at i + 1
-        return gap(jnp.roll(toks, -1)), gap(jnp.argmax(low, axis=-1))
+        return (gap(jnp.roll(toks, -1)), gap(jnp.argmax(low, axis=-1)),
+                margin, low_top[:, 0] - low_top[:, 1] - margin)
 
     fn = jax.jit(below_best)
-    served, yard = [], []
+    rows = []
     for prompt, tokens in sample:
         n = len(prompt) + len(tokens)
         padded = np.zeros(min(-(-n // PAD_TO) * PAD_TO,
                               cfg["max_position_embeddings"]), np.int32)
         padded[:len(prompt)] = prompt
         padded[len(prompt):n] = tokens
-        mine, theirs = (np.asarray(v)[len(prompt) - 1:n - 1]
-                        for v in fn(params, padded))
-        served.append(theirs if of_control else mine)
-        yard.append(theirs)
-    served, yard = np.concatenate(served), np.concatenate(yard)
-    where = f"{len(served)} tokens of {len(sample)} requests"
-    return {"gap_share": (float(served.mean()
-                                / max(yard.mean(), 1e-30)),
-                          f"{where}; the control's mean gap "
-                          f"{float(yard.mean()):.3g}"),
-            "token_gap": (float(served.max()), where)}
+        rows.append([np.asarray(v)[len(prompt) - 1:n - 1]
+                     for v in fn(params, padded)])
+    mine, theirs, margin, error = (np.concatenate(v)
+                                   for v in zip(*rows))
+    served = theirs if of_control else mine
+    share, where = far_gap_share(
+        served, margin, error, cfg["serve"]["far_gap_sigmas"],
+        cfg["serve"]["yardstick_flips"])
+    of = f"{len(served)} tokens of {len(sample)} requests"
+    return {"far_gap_share": (share, f"{of}; {where}"),
+            "token_gap": (float(served.max()), of)}
 
 
 def run(h, cell, args, t_start, dev, mx):
@@ -240,8 +294,11 @@ def run(h, cell, args, t_start, dev, mx):
                 span.__enter__()
             elif state["t"] is None and elapsed >= length:
                 span.__exit__(None, None, None)
-                state["rec"].stop()
+                # read before the profiler is stopped: stopping takes
+                # a while in which no step runs, and the work counted
+                # is that of [t - the span's length, t]
                 state["t"] = drive.clock()
+                state["rec"].stop()
     drive.run(args.seconds, tick, ramp)
     setup_s = drive.t0 - t_start
     if args.trace:
@@ -260,9 +317,14 @@ def run(h, cell, args, t_start, dev, mx):
     ctx = {"trace": traced, "config": cfg, "traffic": traffic,
            "run_values": e2e,
            "spans": {"bench.engine_step": drive.step_s}}
-    if traced:
+    if args.trace:
+        # a rehearsal's trace has no device plane and so no window of
+        # its own: the family's count is taken all the same, over the
+        # seconds that were recorded
+        window = traced["window_s"] if traced else length
         ctx["flops_in_trace"] = drive.flops_between(
-            cfg, state["t"] - traced["window_s"], state["t"])
+            fam, cfg, state["t"] - window, state["t"])
+    if traced:
         traced["steps"] = len(traced["spans"].get(
             "bench.engine_step", []))
         ctx["spans"] = traced["spans"]

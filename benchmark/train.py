@@ -4,7 +4,8 @@ batches resident on the device, timed over a whole window.
 Set-up builds one step object, gives it weights made from the seed,
 and drives it through its first ``checked_steps`` steps (the first of
 them compiles); those steps are what ``correct`` compares, and the
-same object goes on into the window.  The window dispatches steps back
+same object goes on, through one untimed group of ``fetch_every``
+steps, into the window.  The window dispatches steps back
 to back, fetches the loss every ``fetch_every``-th step (never every
 step) and closes on ``block_until_ready`` of the last step's outputs.
 After the window: peak memory is read, the program's state is freed,
@@ -25,32 +26,42 @@ def _dtype(name):
     return {"bfloat16": jnp.bfloat16, "float32": None}[name]
 
 
-def settled_block(fam, mx, cfg, ctx, shapes, seed, trained=True):
+def settled_block(fam, mx, cfg, ctx, shapes, seed, trained=True,
+                  dtype="float32"):
     """The program's model with weights made from the seed in its
     Parameters, as if loaded from a checkpoint.  Built with
     ``grad_req`` null and one short eager forward (it settles the
     deferred shapes), then ``grad_req`` restored: the eager tape's
     gradient buffers, which no compiled step reads, would otherwise
     hold 4 bytes a parameter on the chip (PERF.md, section 7).  A
-    model that is only served (``trained`` off) keeps it null."""
+    model that is only served (``trained`` off) keeps it null.
+
+    The Parameters are of ``dtype`` from the start (the family casts
+    the block before it initializes it), and each group of leaves
+    (``weights.groups``: a layer's) goes into its Parameters as it is
+    made, in place of the zeros there: set-up never holds more than
+    the model and one group."""
     from incubator_mxnet_tpu import autograd
     from incubator_mxnet_tpu.parallel.functional import PureBlock
     # as a user's script does first; it also keeps random_state from
     # making its root key inside the step's trace (PERF.md, section 7)
     mx.random.seed(seed % (2 ** 31))
-    block = fam.build_program(mx, cfg, ctx, grad_req="null")
+    block = fam.build_program(mx, cfg, ctx, grad_req="null", dtype=dtype)
     with autograd.pause():
         block.forward(*fam.example_args(mx, cfg, ctx))
-    made = weights.make(shapes, seed)
-    mine = {block.prefix + n: v for n, v in made.items()}
-    theirs = {n: p.shape for n, p in block.collect_params().items()}
-    if {n: v.shape for n, v in mine.items()} != theirs:
+    params = block.collect_params()
+    mine = {block.prefix + n: (tuple(s), dtype)
+            for n, (s, _) in shapes.items()}
+    theirs = {n: (p.shape, str(p.dtype)) for n, p in params.items()}
+    if mine != theirs:
         raise RuntimeError(
             "the family's leaves are not the program's: "
-            f"{sorted(set(mine) ^ set(theirs))[:6]}")
-    PureBlock(block).write_back(mine)
+            f"{sorted(set(mine.items()) ^ set(theirs.items()))[:6]}")
+    pure = PureBlock(block)
+    for group in weights.in_groups(shapes, seed, dtype):
+        pure.write_back({block.prefix + n: v for n, v in group.items()})
     if trained:
-        block.collect_params().setattr("grad_req", "write")
+        params.setattr("grad_req", "write")
     return block
 
 
@@ -171,7 +182,12 @@ def run(h, cell, args, t_start, dev, mx):
     built_s = time.perf_counter() - t_start
     prog = first_steps(step, batches, cfg, shapes, args.seed, prefix,
                        n_checked)
-    jax.block_until_ready(step.params)
+    # one group more, dispatched as the window dispatches it: the
+    # first burst of calls back to back after set-up takes 6-11 ms a
+    # call where every later one takes 2-3, some 100 ms in all, and
+    # how much of it showed turned on what had compiled just before
+    # (PERF.md, PR 26).  It is warm-up, so it is set-up.
+    window(step, batches, 0.0, traffic["fetch_every"])
     setup_s = time.perf_counter() - t_start
 
     traced = None

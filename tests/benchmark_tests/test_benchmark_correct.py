@@ -128,10 +128,15 @@ def test_chip_readings_under_the_committed_limits(cell, row):
     """readings/<cell>.jsonl keeps what calibrate.py and the sets read
     on the chip at the cell's own size: under the limits as committed
     every sound run of the program is correct, every control and
-    planted fault is not."""
+    planted fault is not.  But for a serve cell's control on a seed
+    whose text is too quiet (``..._text_too_quiet``: wide margins, so
+    that the control's yardstick lies under ``yardstick_flips``): its
+    tokens are the reference's own there, and it passes as any
+    program would."""
     from benchmark.harness import Harness
     limits = Harness().cell(cell).limits
     numbers = {k: (row[k], None) for k in limits if k in row}
     assert numbers
     ok, table = correct.verdict(numbers, limits)
-    assert ok == (row["who"] == "program"), table
+    assert ok == (row["who"] == "program"
+                  or row["who"].endswith("_text_too_quiet")), table

@@ -8,6 +8,7 @@ import os
 import sys
 
 import numpy as np
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -62,33 +63,56 @@ def test_a_token_altered_where_it_is_produced_is_not_correct(
     monkeypatch.setattr(ServingEngine, "_append_token", altered)
     result = measure("tiny-lm.serve", seconds=0.3)
     assert not result["correct"], result["compared"]
-    row = result["compared"]["gap_share"]
+    row = result["compared"]["far_gap_share"]
     assert row["value"] > 10 * row["limit"]
     assert result["compared"]["token_gap"]["value"] > 0.5
 
 
 def test_serve_control_one_precision_down_is_not_correct(harness):
     """The tokens that the bfloat16 reference puts first, read as if
-    they had been served: their mean gap is the yardstick's own, so
-    the share reads 1, over the limit.  The reference's own tokens
-    read 0."""
+    they had been served, lose about what the yardstick expects the
+    control to lose: a share about 1, over the limit.  The reference's
+    own tokens read 0."""
     from benchmark import correct
     cell = harness.cell("tiny-lm.serve")
-    fam = harness.family(cell.config)
-    assert cell.config["serve"]["control_precision"] == "bf16"
+    cfg = cell.config
+    fam = harness.family(cfg)
+    assert cfg["serve"]["control_precision"] == "bf16"
     rs = np.random.RandomState(4)
     sample = [(rs.randint(0, 256, 60).astype(np.int32),
                rs.randint(0, 256, 40).astype(np.int32))
-              for _ in range(6)]
-    numbers = serve.gaps(fam, cell.config, 4, sample, of_control=True)
-    assert numbers["gap_share"][0] == 1.0
-    assert numbers["gap_share"][1].startswith(
-        "240 tokens of 6 requests; the control's mean gap ")
+              for _ in range(48)]
+    numbers = serve.gaps(fam, cfg, 4, sample, of_control=True)
+    assert 0.6 < numbers["far_gap_share"][0] < 2
+    assert numbers["far_gap_share"][1].startswith(
+        "1920 tokens of 48 requests; 6 gaps beyond ")
     assert numbers["token_gap"][0] > 0
     assert not correct.verdict(numbers, cell.limits)[0], numbers
     # random tokens lie far below the best: a share in the thousands
-    served = serve.gaps(fam, cell.config, 4, sample)
-    assert served["gap_share"][0] > 1e3
+    served = serve.gaps(fam, cfg, 4, sample)
+    assert served["far_gap_share"][0] > 1e3
+
+
+@pytest.mark.parametrize("least_flips,share", [
+    (0.0, 4.0), (0.5, 3.5777088), (4.0, 0.4472136)])
+def test_far_gap_share_counts_gaps_beyond_the_controls_noise(
+        least_flips, share):
+    """By hand: the control's errors are -0.3, -0.1, 0.1, 0.3 at four
+    positions (noise: root mean square 0.2236), so a margin of 0.05 is
+    turned by half of them and one of 0.2 by a quarter.  Beyond half a
+    sigma (0.1118) only the margin of 0.2 counts: the yardstick is
+    0.2 * 0.25 = 0.05 over a quarter of a turn.  Of the served gaps,
+    0.05 and 0.2, the first is nearer than the noise and counts for
+    nothing.  The yardstick is never under ``least_flips`` turns of
+    0.1118."""
+    gap = np.array([0.0, 0.05, 0.2, 0.0])
+    margin = np.array([1.0, 0.05, 0.2, 1.0])
+    error = np.array([-0.3, -0.1, 0.1, 0.3])
+    got, where = serve.far_gap_share(gap, margin, error, 0.5,
+                                     least_flips)
+    assert got == pytest.approx(share, rel=1e-6)
+    assert where.startswith("1 gaps beyond 0.112; the control's "
+                            "yardstick 0.05 over 0.25 turns")
 
 
 def test_reference_at_default_precision_is_float32_off_the_chip(harness):
