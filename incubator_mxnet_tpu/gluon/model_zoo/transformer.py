@@ -749,6 +749,32 @@ class TransformerLM(Block):
     # so admission/retirement never changes the traced signature —
     # one compiled step per (max_batch, max_blocks) forever.
 
+    # what ServingEngine asks of a model beside the two builders
+    # below (docs/serving.md, "The paged protocol")
+    _paged_int8 = True      # the builders dequantize {"q", "s"} leaves
+
+    def _paged_cache(self):
+        """What one token leaves in one layer's cache: keys and
+        values per kv head, float32, a pool each."""
+        row = (self.n_kv_heads, self._d // self.n_heads)
+        return tuple({"name": n, "shape": row, "dtype": "float32"}
+                     for n in ("k", "v"))
+
+    def _decode_workspace_bytes(self, max_batch):
+        """One decode step's logits and residual stream, float32."""
+        return 4.0 * max_batch * (self.head._units + 8 * self._d)
+
+    def _decode_cost(self, context_len, batch, dtype_size):
+        """The analytic cost report of one batched decode step."""
+        from ...perf import transformer_decode_cost
+        return transformer_decode_cost(
+            d_model=self._d, n_layers=self.n_layers,
+            vocab=self.head._units, context_len=context_len,
+            n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
+            mlp_ratio=self._mlp_ratio, attn_window=self.attn_window,
+            moe_experts=self.moe_experts, batch=batch,
+            dtype_size=dtype_size)
+
     def _check_paged(self):
         if self.attn_window:
             raise NotImplementedError(
@@ -762,9 +788,12 @@ class TransformerLM(Block):
             # served logits would depend on batch occupancy and the
             # greedy-equivalence contract would silently break
             raise NotImplementedError(
-                "paged serving of MoE models is not implemented — "
-                "shared expert capacity makes logits depend on "
-                "batchmates; decode MoE models via generate()")
+                "paged serving of capacity-dropping routing "
+                "(MoEFFN over ops.moe.moe_ffn_fn) is not implemented "
+                "— shared expert capacity makes logits depend on "
+                "batchmates; decode it via generate(), or serve "
+                "dropless routing (model_zoo.latent_moe.LatentMoELM "
+                "over ops.moe.routed_ffn_fn)")
 
     def _build_paged_prefill(self, suffix_len, max_blocks,
                              block_size):

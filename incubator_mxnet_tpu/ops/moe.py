@@ -21,6 +21,22 @@ Tokens over capacity (C = ceil(cf * 2 * T / E) per expert) are
 DROPPED — their expert contribution is zero and the residual stream
 carries them, the standard GShard overflow semantic that keeps shapes
 static.
+
+Beside it, for serving: :func:`routed_ffn_fn`, a DROPLESS routed layer
+of gated experts (top k of many, sigmoid or softmax scores, a
+selection bias, normalised and scaled weights, a shared expert).  No
+token is dropped, so a token's result does not turn on its
+batch-mates.  Dispatch is sorted: the (token, expert) pairs are
+put in order of their expert, so the work is linear in tokens, an
+expert nobody chose costs nothing, and the products run in the
+tokens' own dtype.  Lowered for a TPU, with widths that are whole
+lanes, the sorted rows go through a grouped matrix product (Pallas:
+megablox ``gmm``), which reads an expert's matrix once for the rows
+that chose it; anywhere else they go expert by expert in tiles of a
+few rows, one tile a pass through one expert's three matrices.
+``held=(first, count)`` computes the part of the result that those
+experts give, which is what one rank of an expert-parallel layer
+computes.
 """
 import math
 
@@ -29,7 +45,8 @@ import jax.numpy as jnp
 
 from .registry import defop
 
-__all__ = ["moe_ffn_fn", "top2_gating"]
+__all__ = ["moe_ffn_fn", "top2_gating", "routed_ffn_fn", "route_top_k",
+           "gated_ffn"]
 
 
 def top2_gating(logits, capacity, renorm=True):
@@ -121,3 +138,206 @@ def _moe_ffn(data, router_weight, up_weight, up_bias, down_weight,
                       down_weight, down_bias,
                       capacity_factor=float(capacity_factor),
                       renorm=bool(renorm))
+
+
+# --------------------------------------------------------------------------
+# dropless top-k-of-many routed layer of gated experts (serving)
+# --------------------------------------------------------------------------
+
+
+def route_top_k(x, router_weight, top_k, scoring="sigmoid",
+                select_bias=None, normalize=True, scale=1.0):
+    """Which experts each token goes to, and with what weight.
+
+    float32 throughout, whatever ``x`` holds: ``s = scoring(x W^T)``
+    over every expert; the ``top_k`` experts are the largest of
+    ``s + select_bias`` (the bias takes part in the choice alone);
+    the weights are the chosen ``s``, over their sum if ``normalize``,
+    times ``scale``.  Returns (choice (T, k) int32, weight (T, k)
+    float32)."""
+    if scoring not in ("sigmoid", "softmax"):
+        raise ValueError(f"scoring={scoring!r}: sigmoid or softmax")
+    logits = jnp.dot(x.astype(jnp.float32),
+                     router_weight.astype(jnp.float32).T)
+    s = jax.nn.sigmoid(logits) if scoring == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
+    chosen_by = s if select_bias is None \
+        else s + select_bias.astype(jnp.float32)
+    _, choice = jax.lax.top_k(chosen_by, top_k)
+    weight = jnp.take_along_axis(s, choice, axis=-1)
+    if normalize:
+        weight = weight / (jnp.sum(weight, axis=-1, keepdims=True)
+                           + 1e-20)
+    return choice.astype(jnp.int32), weight * scale
+
+
+def gated_ffn(x, gate, up, down):
+    """``(silu(x gate^T) * (x up^T)) down^T``: products in x's dtype,
+    accumulated in float32; float32 result."""
+    hidden = jax.nn.silu(jnp.dot(
+        x, gate.T, preferred_element_type=jnp.float32)) \
+        * jnp.dot(x, up.T, preferred_element_type=jnp.float32)
+    return jnp.dot(hidden.astype(x.dtype), down.T,
+                   preferred_element_type=jnp.float32)
+
+
+def _tile_rows(tokens, top_k, n_experts):
+    """Rows of one tile of the sorted dispatch: the pairs an expert
+    expects from ``tokens`` tokens, rounded up to a power of two,
+    between 16 (a packed bfloat16 tile) and 256."""
+    expected = -(-tokens * top_k // n_experts)
+    return min(256, max(16, 1 << max(0, expected - 1).bit_length()))
+
+
+GROUP_ROWS = 128      # rows of one tile of the grouped product
+
+
+def _experts_tiled(x, order, pairs, flat_weight, gate_weight,
+                   up_weight, down_weight, top_k, base, rows_tile):
+    """The sorted pairs expert by expert, in tiles of ``rows_tile``
+    rows: (sum (T, D) float32, rows multiplied)."""
+    t, d = x.shape
+    first_row = jnp.cumsum(pairs) - pairs
+    tiles = -(-pairs // rows_tile)
+    last_tile = jnp.cumsum(tiles)
+    n_tiles = last_tile[-1]
+    lane = jnp.arange(rows_tile, dtype=jnp.int32)
+
+    def one_tile(i, out):
+        e = jnp.sum(last_tile <= i)          # the tile's expert
+        within = (i - (last_tile[e] - tiles[e])) * rows_tile + lane
+        real = within < pairs[e]
+        pair = order[jnp.minimum(first_row[e] + within,
+                                 t * top_k - 1)]
+        token = pair // top_k
+        g, u, dn = (jax.lax.dynamic_index_in_dim(w, base + e, 0, False)
+                    for w in (gate_weight, up_weight, down_weight))
+        y = gated_ffn(x[token], g, u, dn)
+        y = y * jnp.where(real, flat_weight[pair], 0.0)[:, None]
+        return out.at[token].add(y)
+
+    out = jax.lax.fori_loop(0, n_tiles, one_tile,
+                            jnp.zeros((t, d), jnp.float32))
+    return out, n_tiles * rows_tile
+
+
+def _experts_grouped(x, order, pairs, flat_weight, gate_weight,
+                     up_weight, down_weight, top_k, base,
+                     interpret=False):
+    """The sorted pairs through a grouped matrix product: the rows of
+    one expert lie together, and the kernel goes through them in tiles
+    of ``GROUP_ROWS``, a tile that holds rows of several experts once
+    for each of them: (sum (T, D) float32, rows multiplied)."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+    t, d = x.shape
+    count = pairs.shape[0]
+    gate_weight, up_weight, down_weight = (
+        w if w.shape[0] == count
+        else jax.lax.slice_in_dim(w, base, base + count)
+        for w in (gate_weight, up_weight, down_weight))
+
+    def product(rows, weight):
+        return gmm(rows, weight, pairs, jnp.float32,
+                   (GROUP_ROWS, rows.shape[1], weight.shape[1]),
+                   transpose_rhs=True, interpret=interpret)
+
+    rows = x[order // top_k]
+    hidden = jax.nn.silu(product(rows, gate_weight)) \
+        * product(rows, up_weight)
+    y = product(hidden.astype(x.dtype), down_weight)
+    # rows behind the last held pair were never written
+    live = jnp.arange(t * top_k) < jnp.sum(pairs)
+    y = jnp.where(live[:, None], y, 0.0) \
+        * jnp.where(live, flat_weight[order], 0.0)[:, None]
+    back = jnp.zeros(t * top_k, jnp.int32).at[order].set(
+        jnp.arange(t * top_k, dtype=jnp.int32))
+    out = jnp.sum(y[back].reshape(t, top_k, d), axis=1)
+    end = jnp.cumsum(pairs)
+    visits = jnp.sum(jnp.where(
+        pairs > 0, -(-end // GROUP_ROWS) - (end - pairs) // GROUP_ROWS,
+        0))
+    return out, visits * GROUP_ROWS
+
+
+def routed_ffn_fn(x, router_weight, gate_weight, up_weight,
+                  down_weight, top_k, scoring="sigmoid",
+                  select_bias=None, normalize=True, scale=1.0,
+                  shared=None, held=None, valid=None):
+    """A routed layer of gated experts, dropless.
+
+    x             : (T, D) tokens
+    router_weight : (E, D), select_bias (E,) or None: the whole
+                    router (:func:`route_top_k` has the rest)
+    gate_weight, up_weight : (E or count, H, D); down_weight
+                    (E or count, D, H): stacked experts, (out, in)
+                    as FullyConnected has them
+    shared        : None, or (gate (S, D), up (S, D), down (D, S)):
+                    an expert every token goes through, added here
+    held          : None (all), or (first, count): route over all
+                    ``E``, compute what experts ``first .. first +
+                    count - 1`` give.  The stacked weights then hold
+                    all ``E`` experts or just those ``count``
+    valid         : (T,) bool or None: rows that are tokens (padding
+                    rows are routed nowhere)
+    returns (y (T, D) in x.dtype: the sum over a token's chosen
+             experts that are held of ``weight * E_e(x)``, plus the
+             shared expert where given;
+             stats: int32 scalars ``routed_rows`` (rows the expert
+             products multiplied), ``padded_rows`` (how many of them
+             were a tile's padding), ``experts_touched`` (held
+             experts that got a token))
+
+    The sum of ``y`` over shares that partition the experts, with
+    ``shared`` given to one of them, is the whole layer."""
+    t, d = x.shape
+    n_experts = router_weight.shape[0]
+    first, count = (0, n_experts) if held is None else held
+    if not 0 <= first <= first + count <= n_experts:
+        raise ValueError(f"held={held!r} is not among {n_experts} "
+                         "experts")
+    if gate_weight.shape[0] == n_experts:
+        base = first          # the stack holds every expert
+    elif gate_weight.shape[0] == count:
+        base = 0              # the stack holds the held ones
+    else:
+        raise ValueError(
+            f"{gate_weight.shape[0]} stacked experts: neither all "
+            f"{n_experts} nor the {count} held")
+    rows_tile = _tile_rows(t, top_k, n_experts)
+    # the grouped product wants whole lanes and whole tiles of rows
+    grouped = d % 128 == 0 and gate_weight.shape[1] % 128 == 0 \
+        and (t * top_k) % GROUP_ROWS == 0 and count > 0
+
+    with jax.named_scope("moe_route"):
+        choice, weight = route_top_k(x, router_weight, top_k, scoring,
+                                     select_bias, normalize, scale)
+        local = choice - first
+        here = (local >= 0) & (local < count)
+        if valid is not None:
+            here &= valid[:, None]
+        # pairs sorted by held expert; the others sort behind them
+        key = jnp.where(here, local, count).reshape(-1)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        pairs = jnp.sum(key[:, None] == jnp.arange(count), axis=0,
+                        dtype=jnp.int32)
+        flat_weight = weight.reshape(-1)
+
+    with jax.named_scope("moe_experts"):
+        args = (x, order, pairs, flat_weight, gate_weight, up_weight,
+                down_weight)
+        if grouped:
+            out, routed_rows = jax.lax.platform_dependent(
+                *args,
+                tpu=lambda *a: _experts_grouped(*a, top_k, base),
+                default=lambda *a: _experts_tiled(*a, top_k, base,
+                                                  rows_tile))
+        else:
+            out, routed_rows = _experts_tiled(*args, top_k, base,
+                                              rows_tile)
+    if shared is not None:
+        with jax.named_scope("moe_shared"):
+            out = out + gated_ffn(x, *shared)
+    stats = {"routed_rows": routed_rows,
+             "padded_rows": routed_rows - jnp.sum(pairs),
+             "experts_touched": jnp.sum(pairs > 0, dtype=jnp.int32)}
+    return out.astype(x.dtype), stats

@@ -23,7 +23,11 @@ least one query row to emit first-token logits from.
 Eviction is LRU over entries whose block's ONLY remaining holder is
 the cache itself — a block still referenced by a running request is
 never evicted (the entry just leaves the cache; the request keeps
-its context).
+its context).  Such a block is in use now: an eviction that comes
+upon it counts that as its latest use, so the entries of long-running
+requests do not gather at the old end to be walked past at every
+call (a pool that is full calls for an eviction at every block a
+request grows into).
 """
 from collections import OrderedDict
 
@@ -117,16 +121,17 @@ class PrefixCache:
         actually freed."""
         if n <= 0:
             return 0
-        freed = 0
-        for key in list(self._entries):
-            if freed >= n:
+        victims, in_use = [], []
+        for key, bid in self._entries.items():
+            if len(victims) >= n:
                 break
-            bid = self._entries[key]
-            if self._pool.refcount(bid) == 1:       # cache-only
-                del self._entries[key]
-                self._pool.free([bid])
-                freed += 1
-        return freed
+            (victims if self._pool.refcount(bid) == 1   # cache-only
+             else in_use).append(key)
+        for key in in_use:
+            self._entries.move_to_end(key)
+        for key in victims:
+            self._pool.free([self._entries.pop(key)])
+        return len(victims)
 
     def clear(self):
         """Drop every entry (releasing the cache's refs)."""

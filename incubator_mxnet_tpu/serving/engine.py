@@ -74,12 +74,23 @@ def _next_pow2(n):
     return 1 << max(0, int(n - 1)).bit_length()
 
 
+# what a served model offers (docs/serving.md, "The paged protocol")
+PAGED_PROTOCOL = ("_max_len", "n_layers", "_check_paged", "_paged_cache",
+                  "_decode_weights", "_build_paged_prefill",
+                  "_build_paged_step", "_decode_workspace_bytes",
+                  "decode_flops_per_token", "_decode_cost")
+
+
 class ServingEngine:
-    """Continuous-batching decode engine over one TransformerLM.
+    """Continuous-batching decode engine over one model that offers
+    the paged protocol (``TransformerLM``, ``LatentMoELM``).
 
     Parameters (env defaults in parentheses; docs/env_vars.md):
 
-    model : an initialized TransformerLM (``attn_window`` must be 0)
+    model : an initialized model that offers the paged protocol
+        (docs/serving.md): the row its cache holds for one token in
+        one layer, a prefill builder, a decode-step builder, a
+        context limit
     max_batch : concurrent decode slots (``MXTPU_SERVE_MAX_BATCH``)
     block_size : tokens per KV block (``MXTPU_SERVE_BLOCK_SIZE``)
     num_blocks : pool size incl. the reserved scratch block
@@ -103,6 +114,10 @@ class ServingEngine:
         typed :class:`ServeRejectedError`
     step_timeout : decode-step watchdog budget in seconds
         (``MXTPU_SERVE_STEP_TIMEOUT``; 0 disables)
+    max_len : the most positions one request may hold, prompt and
+        new tokens together (default: the model's ``_max_len``).  It
+        bounds a table row, the top prefill bucket, and what the
+        decode program gathers for every slot
 
     Decoding is greedy (temperature-0) — the batch-invariant mode
     whose outputs are provably identical to sequential
@@ -118,12 +133,13 @@ class ServingEngine:
                  num_blocks=None, quantize=None, prefix_cache=None,
                  keep_logits=False, ttft_deadline=None,
                  deadline=None, queue_limit=None, queue_tokens=None,
-                 step_timeout=None):
-        from ..gluon.model_zoo.transformer import TransformerLM
-        if not isinstance(model, TransformerLM):
+                 step_timeout=None, max_len=None):
+        lacking = [a for a in PAGED_PROTOCOL if not hasattr(model, a)]
+        if lacking:
             raise TypeError(
-                "ServingEngine serves TransformerLM models, got "
-                f"{type(model).__name__}")
+                "ServingEngine serves models that offer the paged "
+                f"protocol (docs/serving.md); {type(model).__name__} "
+                f"lacks {', '.join(lacking)}")
         model._check_paged()
         self.block_size = int(block_size if block_size is not None
                               else get_env("MXTPU_SERVE_BLOCK_SIZE"))
@@ -164,8 +180,14 @@ class ServingEngine:
             else get_env("MXTPU_SERVE_STEP_TIMEOUT"))
 
         self.model = model
-        # one table row spans the model's full context budget
-        self.max_blocks = -(-model._max_len // self.block_size)
+        self.max_len = int(model._max_len if max_len is None
+                           else max_len)
+        if not 1 <= self.max_len <= model._max_len:
+            raise ValueError(
+                f"max_len={self.max_len} is not within the model's "
+                f"{model._max_len} positions")
+        # one table row spans the context budget
+        self.max_blocks = -(-self.max_len // self.block_size)
         self._sched = Scheduler(self.max_batch)
         self.keep_logits = bool(keep_logits)
 
@@ -173,6 +195,10 @@ class ServingEngine:
         # real (possibly quantized) weight bytes on the chip
         wts = self._settled_weights(model)
         if quantize in ("int8", True):
+            if not getattr(model, "_paged_int8", False):
+                raise ValueError(
+                    f"{type(model).__name__}'s paged programs do not "
+                    "read int8 weights: quantize='off'")
             self._wts = quantize_weights(wts)
             self.quantized = True
         elif quantize in ("off", "", False, None):
@@ -183,20 +209,29 @@ class ServingEngine:
                 f"quantize must be 'off' or 'int8', got {quantize!r}")
 
         import jax.numpy as jnp
-        kvh = model.n_kv_heads
-        dh = model._d // model.n_heads
+        # the model's cache: what one token leaves in one layer, a
+        # pool each (TransformerLM: keys and values per head, float32;
+        # LatentMoELM: one latent row in the weights' dtype)
+        self.cache_spec = tuple(
+            {**c, "shape": tuple(c["shape"]),
+             "dtype": str(jnp.dtype(c["dtype"]))}
+            for c in model._paged_cache())
+        self.token_bytes = model.n_layers * sum(
+            int(np.prod(c["shape"])) * jnp.dtype(c["dtype"]).itemsize
+            for c in self.cache_spec)
         if self.auto_blocks:
-            self.num_blocks = self._auto_num_blocks(kvh, dh)
+            self.num_blocks = self._auto_num_blocks()
         if self.num_blocks < 1:
             raise ValueError(
                 f"bad serving config: num_blocks={self.num_blocks}")
         self.pool = BlockPool(self.num_blocks, self.block_size)
         self.cache = PrefixCache(self.pool, enabled=prefix_cache)
-        shape = (self.num_blocks, self.block_size, kvh, dh)
-        self._kpools = [jnp.zeros(shape, jnp.float32)
-                        for _ in range(model.n_layers)]
-        self._vpools = [jnp.zeros(shape, jnp.float32)
-                        for _ in range(model.n_layers)]
+        # one list of per-layer arrays a pool; the programs take and
+        # return them in this order, right after the weights
+        self._pools = tuple(
+            [jnp.zeros((self.num_blocks, self.block_size) + c["shape"],
+                       c["dtype"]) for _ in range(model.n_layers)]
+            for c in self.cache_spec)
 
         self._step_fn = None
         self._prefill_fns = {}
@@ -254,7 +289,7 @@ class ServingEngine:
             eng = ref()
             if eng is None:
                 return []
-            return list(eng._kpools) + list(eng._vpools)
+            return [a for pool in eng._pools for a in pool]
 
         self._mem_unregister = tracing.register_memory(
             "kv_pools", _kv_arrays, owner=self)
@@ -291,6 +326,16 @@ class ServingEngine:
         self._m_qdepth = telemetry.gauge("serving_queue_depth")
         self._m_qtokens = telemetry.gauge(
             "serving_queued_prompt_tokens")
+        # each pool's bytes under its own name, beside the
+        # device_bytes_kv_pools they are all counted in
+        for c, pool in zip(self.cache_spec, self._pools):
+            telemetry.gauge(f"serving_pool_{c['name']}_bytes").set(
+                sum(a.nbytes for a in pool))
+        # a routed layer's statistics, where the model has one: they
+        # come back behind the tokens, in the same fetch
+        self._m_moe = [telemetry.counter(f"serving_moe_{n}_total")
+                       for n in ("routed_rows", "padded_rows",
+                                 "experts_touched", "layer_steps")]
         # perf observatory (docs/observability.md): MFU from the
         # analytic decode-FLOPs ledger — token counts and context
         # lengths are already host-side, so this adds no syncs
@@ -305,25 +350,23 @@ class ServingEngine:
         self._perf_caps = None
 
     # ---------------------------------------------------------- setup
-    def _auto_num_blocks(self, kvh, dh):
+    def _auto_num_blocks(self):
         """Size the KV pool from planner headroom (docs/memory.md):
         usable device capacity (MXTPU_HBM_BYTES override honored,
         MXTPU_MEM_GATE_MARGIN reserved) minus the settled weights and
         a per-step decode workspace (hidden states + logits), divided
-        by per-block KV bytes — capped at a full context row for
-        every slot plus the scratch block, so tiny models never hoard
-        the chip.  Refuses with a typed MemoryPlanError when the
-        model alone leaves no room for one block per slot."""
+        by per-block bytes of the model's own row — capped at a full
+        context row for every slot plus the scratch block, so tiny
+        models never hoard the chip.  Refuses with a typed
+        MemoryPlanError when the model alone leaves no room for one
+        block per slot."""
         from ..perf import memory_planner as mp
         from ..perf.device_db import headroom, hbm_capacity
         wts_bytes = mp.tree_bytes(self._wts)
-        d = self.model._d
-        vocab = self.model.head._units
         # decode workspace: one step's logits + residual stream per
         # slot (fp32), the transient XLA scratch next to the pools
-        workspace = 4.0 * self.max_batch * (vocab + 8 * d)
-        per_block = 2.0 * self.model.n_layers * self.block_size \
-            * kvh * dh * 4
+        workspace = self.model._decode_workspace_bytes(self.max_batch)
+        per_block = float(self.token_bytes * self.block_size)
         avail = headroom(wts_bytes + workspace)
         floor = self.max_batch + 1   # one block per slot + scratch
         if avail < per_block * floor:
@@ -381,12 +424,13 @@ class ServingEngine:
         # (jit_serve_decode, jit_serve_prefill_<bucket>)
         traced.__name__ = traced.__qualname__ = f"serve_{name}"
 
-        # donate the KV pools (args 1, 2 in both the prefill and the
-        # step signature): the compiled call updates the cache IN
-        # PLACE instead of copying every pool array out per token —
-        # the engine always rebinds self._kpools/_vpools from the
+        # donate the pools (right after the weights in both the
+        # prefill and the step signature): the compiled call updates
+        # the cache IN PLACE instead of copying every pool array out
+        # per token — the engine always rebinds self._pools from the
         # outputs, so the consumed buffers are never reused
-        jfn = jax.jit(traced, donate_argnums=(1, 2))
+        jfn = jax.jit(traced, donate_argnums=tuple(
+            range(1, 1 + len(self._pools))))
 
         def called(*args):
             # a call that ran the Python trace just compiled: record
@@ -420,7 +464,7 @@ class ServingEngine:
         # than the padded rows it saves
         bucket = min(max(_next_pow2(suffix_len),
                          _next_pow2(self.block_size)),
-                     _next_pow2(self.model._max_len))
+                     _next_pow2(self.max_len))
         fn = self._prefill_fns.get(bucket)
         if fn is None:
             fn = self._prefill_fns[bucket] = self._counted_jit(
@@ -437,10 +481,10 @@ class ServingEngine:
         be served by this engine — queueing it would hang the
         schedule forever (docs/serving.md)."""
         total = n_tokens + max_new
-        if total > self.model._max_len:
+        if total > self.max_len:
             raise RequestTooLargeError(
                 f"prompt+new = {total} exceeds max_len "
-                f"{self.model._max_len}")
+                f"{self.max_len}")
         need = -(-total // self.block_size)
         if need > min(self.max_blocks, self.pool.capacity):
             raise RequestTooLargeError(
@@ -619,6 +663,23 @@ class ServingEngine:
             sp.set(emitted=len(events))
         return events
 
+    def _count_moe(self, stats, decode=False):
+        """What follows the tokens in a program's ``next``: the
+        routed layers' statistics summed over the layers (rows the
+        grouped product multiplied, rows of them padding, distinct
+        experts read, routed layers).  Rows count for every program;
+        experts touched a layer is a decode step's number.  Returns
+        the experts touched."""
+        if not len(stats):
+            return None
+        rows, padded, touched, layers = (int(v) for v in stats)
+        self._m_moe[0].inc(rows)
+        self._m_moe[1].inc(padded)
+        if decode:
+            self._m_moe[2].inc(touched)
+            self._m_moe[3].inc(layers)
+        return touched
+
     # ------------------------------------------------ perf observatory
     def _serve_dtype(self):
         """Weight-stream dtype for roofline math: int8 when the
@@ -661,24 +722,17 @@ class ServingEngine:
         running KV length (half the model's context when idle) and
         ``batch`` is the running-slot count (``max_batch`` when
         idle).  Pure host arithmetic — safe to call in production."""
-        from ..perf import transformer_decode_cost
         m = self.model
         running = [r for r in self._sched.slots if r is not None]
         if context_len is None:
             context_len = (
                 int(sum(r.n_past for r in running) / len(running))
-                if running else max(1, m._max_len // 2))
+                if running else max(1, self.max_len // 2))
         if batch is None:
             batch = len(running) or self.max_batch
         dtype = self._serve_dtype()
         dtype_size = {"int8": 1, "bfloat16": 2}.get(dtype, 4)
-        rep = transformer_decode_cost(
-            d_model=m._d, n_layers=m.n_layers,
-            vocab=m.head._units, context_len=context_len,
-            n_heads=m.n_heads, n_kv_heads=m.n_kv_heads,
-            mlp_ratio=m._mlp_ratio, attn_window=m.attn_window,
-            moe_experts=m.moe_experts, batch=batch,
-            dtype_size=dtype_size)
+        rep = m._decode_cost(context_len, batch, dtype_size)
         from ..perf import roofline
         caps = self._caps()
         return {
@@ -886,7 +940,8 @@ class ServingEngine:
                        "prefix_cache": self.cache.enabled,
                        "quantize": ("int8" if self.quantized
                                     else "off"),
-                       "max_len": self.model._max_len},
+                       "max_len": self.max_len,
+                       "cache": [dict(c) for c in self.cache_spec]},
             "next_id": self._next_id,
             "requests": reqs,
         }
@@ -925,7 +980,7 @@ class ServingEngine:
                 f"version): {snapshot!r:.80}")
         cfg = snapshot.get("engine", {})
         for key in ("max_batch", "block_size", "num_blocks",
-                    "prefix_cache", "quantize"):
+                    "prefix_cache", "quantize", "max_len"):
             if cfg.get(key) is not None:
                 engine_kw.setdefault(key, cfg[key])
         eng = cls(model, **engine_kw)
@@ -1202,14 +1257,15 @@ class ServingEngine:
         with telemetry.span("serve_prefill", rid=req.id,
                             tokens=len(suffix),
                             bucket=bucket) as sp_pre:
-            self._kpools, self._vpools, nxt, logits = fn(
-                self._wts, self._kpools, self._vpools,
+            *pools, nxt, logits = fn(
+                self._wts, *self._pools,
                 row, np.int32(n_cached), suf, np.int32(len(suffix)))
+            self._pools = tuple(pools)
             # completion barrier, not a transfer: dispatching the
             # next call while its DONATED pool buffers are still
             # pending hits a pathological slow path (~7x) in the
             # runtime's donation bookkeeping
-            jax.block_until_ready(self._kpools)
+            jax.block_until_ready(self._pools[0])
         req.prefill_s += sp_pre.elapsed
         tracing.trace_event(
             "serve_prefill", rid=req.id, engine=self.engine_id,
@@ -1226,8 +1282,9 @@ class ServingEngine:
         self.cache.insert(toks, req.block_ids)
         req.n_past = len(toks)
         with telemetry.span("serve_token_fetch"):
-            tok = int(np.asarray(nxt))  # sync-ok: first-token read seeds the decode loop
-        self._append_token(req, tok, events)
+            nxt = np.asarray(nxt).reshape(-1)  # sync-ok: first-token read seeds the decode loop
+        self._count_moe(nxt[1:])
+        self._append_token(req, int(nxt[0]), events)
         return True
 
     def _grow(self):
@@ -1327,14 +1384,21 @@ class ServingEngine:
                                      jnp.asarray(tokens))
         fn = self._get_step_fn()
         with telemetry.span("serve_decode", running=running) as sp_dec:
-            self._kpools, self._vpools, nxt, logits = fn(
-                self._wts, self._kpools, self._vpools,
-                tables, npast, tokens)
+            *pools, nxt, logits = fn(
+                self._wts, *self._pools, tables, npast, tokens)
+            self._pools = tuple(pools)
             # completion barrier (see _admit_one): the token read
             # below already serializes the loop; waiting on the
             # donated pools too keeps the NEXT dispatch off the slow
             # path
-            jax.block_until_ready(self._kpools)
+            jax.block_until_ready(self._pools[0])
+            if nxt.shape[0] > B:
+                # a routed model's statistics ride behind the tokens.
+                # Read here they are the step's one transfer (the
+                # token fetch below finds the host copy made), and
+                # the span can say how many experts the step read
+                sp_dec.set(experts_touched=self._count_moe(
+                    np.asarray(nxt)[B:], decode=True))  # sync-ok: the per-iteration token read
         dt_step = sp_prep.elapsed + sp_dec.elapsed
         if self.step_timeout > 0 and dt_step > self.step_timeout:
             tracing.trace_event(
@@ -1675,6 +1739,9 @@ class ServingEngine:
             "batch_occupancy":
                 self._sched.n_running() / self.max_batch,
             "pool_utilization": self.pool.utilization(),
+            # what a token leaves in the cache, as the model says
+            "cache": {"pools": [dict(c) for c in self.cache_spec],
+                      "bytes_per_token": self.token_bytes},
             # SLO/survival view: how every request ended
             # ('rejected' counts submissions shed at the door),
             # plus the admission controller's live pressure

@@ -102,6 +102,31 @@ def test_prefix_cache_match_insert_evict():
     assert len(cache) == 0 and pool.num_free == pool.capacity
 
 
+@pytest.mark.parametrize("held_first", [True, False])
+def test_prefix_cache_eviction_walks_past_a_held_entry_once(held_first):
+    """A block that a request still holds is never evicted, and an
+    eviction that comes upon it counts that as its latest use: the
+    next eviction does not walk past it again, and once its request
+    lets go it is evicted after what was cached before that walk."""
+    pool = BlockPool(num_blocks=16, block_size=4)
+    cache = PrefixCache(pool)
+    held, idle = pool.alloc(2), pool.alloc(2)
+    streams = {"held": list(range(8)), "idle": list(range(50, 58))}
+    for name in (("held", "idle") if held_first else ("idle", "held")):
+        cache.insert(streams[name] + [0], held if name == "held"
+                     else idle)
+    pool.free(idle)                    # its request is done
+    assert cache.evict(1) == 1 and pool.refcount(idle[0]) == 0
+    keys = list(cache._entries)
+    # the held entries lie behind the idle one that is left, whether
+    # the walk came upon them (held first) or never reached them
+    assert [cache._entries[k] for k in keys] == [idle[1]] + held
+    assert cache.evict(5) == 1 and len(cache) == 2
+    pool.free(held)
+    assert cache.evict(5) == 2
+    assert len(cache) == 0 and pool.num_free == pool.capacity
+
+
 # ------------------------------------------- equivalence with generate()
 def test_continuous_batching_matches_sequential_generate():
     net = _tiny()
@@ -327,7 +352,8 @@ def test_engine_rejects_unsupported_models():
     win = _tiny(attn_window=4)
     with pytest.raises(NotImplementedError, match="window"):
         ServingEngine(win, max_batch=1, num_blocks=16)
-    with pytest.raises(TypeError, match="TransformerLM"):
+    # served by protocol, not by class: what is lacking is named
+    with pytest.raises(TypeError, match="paged protocol.*_max_len"):
         ServingEngine(object(), max_batch=1)
     # MoE: shared expert capacity makes logits depend on batchmates,
     # which would break the greedy generate() equivalence contract

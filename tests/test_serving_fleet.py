@@ -654,7 +654,8 @@ def test_fleet_env_and_status_helpers():
     snaps2 = {0: {"counters": {"serving_requests_total": 30}}}
     line2 = launch._fleet_status(snaps2, 3, 3, rate_state)
     assert "fleet: 3/3 healthy" in line2
-    assert "0.0 req/s" not in line2     # 20 reqs since last tick
+    # 20 reqs since last tick (", 370.0 req/s" also ends in "0.0 req/s")
+    assert ", 0.0 req/s" not in line2
 
 
 # ------------------------------------------------------- lint rule

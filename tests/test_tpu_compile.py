@@ -117,6 +117,33 @@ def test_flash_under_dp_mesh_compiles_for_v5e(topo):
         jax.jit(_attention_loss(0)).lower(x, x, x).compile()
 
 
+@pytest.mark.parametrize("rows,width", [
+    pytest.param(64, 768, id="decode-64x8"),
+    pytest.param(1024, 768, id="prefill-1024x8"),
+    pytest.param(40, 768, id="rows-no-whole-tile"),
+    pytest.param(64, 96, id="width-no-whole-lane")])
+def test_routed_layer_compiles_for_v5e(one_chip, rows, width):
+    """``ops.moe.routed_ffn_fn`` lowered for the chip: rows that make
+    whole tiles of 128 pairs at widths of whole lanes go through the
+    grouped Pallas product (gate, up, down: three kernels), anything
+    else through the loop over tiles, which is plain XLA."""
+    from incubator_mxnet_tpu.ops.moe import routed_ffn_fn
+    n, d, k = 16, 2048, 8
+
+    def leaf(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def layer(x, router, gate, up, down):
+        return routed_ffn_fn(x, router, gate, up, down, k)[0]
+
+    text = jax.jit(layer).lower(
+        leaf(rows, d), leaf(n, d), leaf(n, width, d),
+        leaf(n, width, d), leaf(n, d, width)).compile().as_text()
+    grouped = (rows * k) % 128 == 0 and width % 128 == 0
+    assert text.count("tpu_custom_call") == (3 if grouped else 0)
+
+
 def test_rtc_example_kernel_compiles_for_v5e(one_chip):
     """examples/custom_pallas_kernel.py's kernel through
     ``rtc.compile_kernel``: the compiled-or-interpreted choice follows
