@@ -87,6 +87,14 @@ def _rope_rows(x, pos, base=10000.0):
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
 
+
+def _paged_write(pool, blk, off, rows):
+    """A paged program's new rows into the donated pool, row ``i`` at
+    ``[blk[i], off[i]]``.  The result is only ever returned: with no
+    reader inside the program the update is made in place."""
+    return pool.at[blk, off].set(rows.astype(pool.dtype))
+
+
 # once-per-process notice when an explicit ulysses request falls back
 _ULYSSES_WARNED = False
 
@@ -743,20 +751,26 @@ class TransformerLM(Block):
     # ---------------------------------------------------- paged decode
     # Block-table variants of prefill/step for the serving tier
     # (serving/engine.py, docs/serving.md).  KV lives in fixed pools
-    # of shape (num_blocks, block_size, kv_heads, head_dim) per
-    # layer; a request's context is the ordered block-id row it owns.
-    # Scatter/gather by block id happens INSIDE the jitted function,
-    # so admission/retirement never changes the traced signature —
-    # one compiled step per (max_batch, max_blocks) forever.
+    # of shape (num_blocks, block_size, kv_heads * head_dim) per
+    # layer, a cached row in whole lanes; a request's context is the
+    # ordered block-id row it owns.  The programs go through the block
+    # table INSIDE the jitted function, so admission/retirement never
+    # changes the traced signature — one compiled step per
+    # (max_batch, max_blocks) forever.  A program's new rows are
+    # written into the donated pools and read by nothing else in it
+    # (it attends over the pools as they came in plus the rows it
+    # holds), so the write stays in place and no pool is ever copied.
 
     # what ServingEngine asks of a model beside the two builders
     # below (docs/serving.md, "The paged protocol")
     _paged_int8 = True      # the builders dequantize {"q", "s"} leaves
 
     def _paged_cache(self):
-        """What one token leaves in one layer's cache: keys and
-        values per kv head, float32, a pool each."""
-        row = (self.n_kv_heads, self._d // self.n_heads)
+        """What one token leaves in one layer's cache: its keys and
+        its values, every kv head's side by side in one row
+        (``kv_heads * head_dim`` lanes: the layout the decode read
+        walks, ops/paged_attention.py), float32, a pool each."""
+        row = (self.n_kv_heads * (self._d // self.n_heads),)
         return tuple({"name": n, "shape": row, "dtype": "float32"}
                      for n in ("k", "v"))
 
@@ -774,6 +788,19 @@ class TransformerLM(Block):
             mlp_ratio=self._mlp_ratio, attn_window=self.attn_window,
             moe_experts=self.moe_experts, batch=batch,
             dtype_size=dtype_size)
+
+    def _paged_read(self, block_size, platform):
+        """Which read of the cache the decode step takes where it is
+        traced now and lowered for ``platform``, with the shapes that
+        decide it (``ops.paged_attention.read_kind``): what the
+        engine's ``serve_paged_read`` event carries."""
+        from ...ops.paged_attention import read_kind
+        h, kv = self.n_heads, self.n_kv_heads
+        dh = self._d // h
+        dtype = self._paged_cache()[0]["dtype"]
+        return dict(read=read_kind(h, kv, dh, int(block_size), dtype,
+                                   platform),
+                    heads=h, kv_heads=kv, head_dim=dh, dtype=dtype)
 
     def _check_paged(self):
         if self.attn_window:
@@ -800,13 +827,17 @@ class TransformerLM(Block):
         """Suffix prefill over the block-table cache.
 
         One traced signature per padded suffix length: embeds ``S``
-        suffix tokens at absolute positions ``n_past + i``, scatters
-        their K/V into the request's blocks, and attends over the
-        whole block-table context — ``n_past = 0`` is a full
+        suffix tokens at absolute positions ``n_past + i``, writes
+        their K/V into the request's blocks (``_paged_write``: in
+        place, read by nothing in the program), and attends over the
+        row's context as the pools held it on entry, gathered through
+        the table, with the suffix's own rows laid over positions
+        ``n_past ..`` of the gathered copy — ``n_past = 0`` is a full
         prefill; ``n_past > 0`` resumes after a prefix-cache hit
         without recomputing the shared blocks.  Rows past
-        ``true_len`` are padding: they scatter to the scratch block
-        (id 0) and their outputs are discarded.
+        ``true_len`` are padding: they are written to the scratch
+        block (id 0), no real row sees them, and their outputs are
+        discarded.
 
         Returns ``prefill(wts, kpools, vpools, table, n_past,
         tokens, true_len) -> (kpools, vpools, next_token, logits)``
@@ -827,6 +858,7 @@ class TransformerLM(Block):
         use_rope = self._pos_kind == "rope"
         max_len = self._max_len
         from ...ops.matrix import rope_fn
+        from ...ops.paged_attention import gathered_context
         S, MB, bs = int(suffix_len), int(max_blocks), int(block_size)
         C = MB * bs
         cfs = [blk.moe._cf if blk.moe_experts else None
@@ -845,6 +877,13 @@ class TransformerLM(Block):
                 valid, table[jnp.minimum(wpos // bs, MB - 1)], 0)
             off = wpos % bs
             keep = jnp.arange(C)[None, :] <= pos[:, None]   # (S, C)
+
+            def context(pool, rows):
+                # the row's context as the pool came in, the suffix's
+                # own rows over positions n_past .. of the copy
+                return gathered_context(pool, table, n_past, rows) \
+                    .reshape(C, kv, dh).transpose(1, 0, 2)
+
             new_k, new_v = [], []
             for li, (lw, cf) in enumerate(zip(wts["layers"], cfs)):
                 xa = _jln(x, lw["ln1"])
@@ -855,14 +894,12 @@ class TransformerLM(Block):
                 if use_rope:
                     q = rope_fn(q[None], offset=n_past)[0]
                     k = rope_fn(k[None], offset=n_past)[0]
-                kp = kpools[li].at[blk, off].set(k)
-                vp = vpools[li].at[blk, off].set(v)
-                # gather the whole context back through the table:
-                # lane c of the flattened (C,) axis IS absolute
-                # position c, because the row is ordered by logical
-                # block index
-                kc = kp[table].reshape(C, kv, dh).transpose(1, 0, 2)
-                vc = vp[table].reshape(C, kv, dh).transpose(1, 0, 2)
+                kp = _paged_write(kpools[li], blk, off,
+                                  k.reshape(S, kvd))
+                vp = _paged_write(vpools[li], blk, off,
+                                  v.reshape(S, kvd))
+                kc = context(kpools[li], k.reshape(S, kvd))
+                vc = context(vpools[li], v.reshape(S, kvd))
                 qg = q.transpose(1, 0, 2).reshape(kv, rep, S, dh)
                 s = jnp.einsum("krsd,kcd->krsc", qg, kc) \
                     / math.sqrt(dh)
@@ -887,33 +924,41 @@ class TransformerLM(Block):
     def _build_paged_step(self, max_batch, max_blocks, block_size):
         """One continuous-batching decode step over the block pool.
 
-        Feeds every slot's newest token at its own position, scatters
-        the new K/V through each slot's block-table row, and attends
-        over the gathered context.  Inactive slots ride along with
+        Feeds every slot's newest token at its own position, writes
+        the new K/V through each slot's block-table row
+        (``_paged_write``: in place, read by nothing in the program)
+        and attends over the slot's cached positions ``0 .. n_past -
+        1`` as the pools held them on entry plus the step's own key
+        and value (``ops.paged_attention.decode_attention``).  Where
+        the shapes can be tiled and the step is lowered for a TPU
+        that read is a kernel that walks each slot's blocks through
+        the table as far as ``n_past`` and never materialises a
+        context; anywhere else (another platform, a matmul precision
+        above the platform's default in force at the trace) it is the
+        plain gather of ``max_blocks * block_size`` positions a slot
+        (``_paged_read`` says which).  Inactive slots ride along with
         ``n_past = 0`` and an all-scratch row — their writes land in
-        block 0 and their outputs are ignored by the host — so the
-        step needs NO liveness branch and admission/retirement reuse
-        the one compiled executable.
+        block 0, they read no block, and their outputs are ignored
+        by the host — so the step needs NO liveness branch and
+        admission/retirement reuse the one compiled executable.
 
         Returns ``step(wts, kpools, vpools, tables, n_past, tokens)
         -> (kpools, vpools, next_tokens, logits)`` (greedy argmax
         per slot).
         """
-        import jax
         import jax.numpy as jnp
 
         self._check_paged()
         d, h = self._d, self.n_heads
         dh = d // h
         kv = self.n_kv_heads
-        rep = h // kv
         kvd = kv * dh
         scale = math.sqrt(d)
         use_rope = self._pos_kind == "rope"
         B, MB, bs = int(max_batch), int(max_blocks), int(block_size)
-        C = MB * bs
         cfs = [blk.moe._cf if blk.moe_experts else None
                for blk in self.blocks]
+        from ...ops.paged_attention import decode_attention
 
         def step(wts, kpools, vpools, tables, n_past, tokens):
             x = _q_rows(wts["embed"], tokens) * scale       # (B, D)
@@ -922,31 +967,21 @@ class TransformerLM(Block):
             blk = jnp.take_along_axis(
                 tables, (n_past // bs)[:, None], axis=1)[:, 0]
             off = n_past % bs
-            keep = jnp.arange(C)[None, :] <= n_past[:, None]
             new_k, new_v = [], []
             for li, (lw, cf) in enumerate(zip(wts["layers"], cfs)):
                 xa = _jln(x, lw["ln1"])
                 qkvm = xa @ _q_mat(lw["qkv"][0]).T + lw["qkv"][1]
                 q = qkvm[:, :d].reshape(B, h, dh)
-                k = qkvm[:, d:d + kvd].reshape(B, kv, dh)
-                v = qkvm[:, d + kvd:].reshape(B, kv, dh)
+                k = qkvm[:, d:d + kvd]
+                v = qkvm[:, d + kvd:]
                 if use_rope:
                     q = _rope_rows(q, n_past)
-                    k = _rope_rows(k, n_past)
-                kp = kpools[li].at[blk, off].set(k)
-                vp = vpools[li].at[blk, off].set(v)
-                kc = kp[tables].reshape(B, C, kv, dh) \
-                    .transpose(0, 2, 1, 3)          # (B, kv, C, dh)
-                vc = vp[tables].reshape(B, C, kv, dh) \
-                    .transpose(0, 2, 1, 3)
-                qg = q.reshape(B, kv, rep, dh)
-                s = jnp.einsum("bkrd,bkcd->bkrc", qg, kc) \
-                    / math.sqrt(dh)
-                att = jax.nn.softmax(
-                    jnp.where(keep[:, None, None, :], s, -1e9),
-                    axis=-1)
-                o = jnp.einsum("bkrc,bkcd->bkrd", att, vc) \
-                    .reshape(B, d)
+                    k = _rope_rows(k.reshape(B, kv, dh),
+                                   n_past).reshape(B, kvd)
+                kp = _paged_write(kpools[li], blk, off, k)
+                vp = _paged_write(vpools[li], blk, off, v)
+                o = decode_attention(q, k, v, kpools[li], vpools[li],
+                                     tables, n_past)
                 x = x + o @ _q_mat(lw["proj"][0]).T + lw["proj"][1]
                 xm = _jln(x, lw["ln2"])
                 x = x + _ffn_rows(lw, cf, xm)

@@ -2,8 +2,9 @@
 
 The serving tier's memory model (docs/serving.md): instead of one
 dense ``(max_len, ...)`` KV buffer per sequence, every layer owns ONE
-pool array of shape ``(num_blocks, block_size, kv_heads, head_dim)``
-and each running request holds an ordered list of block ids — its
+pool array of shape ``(num_blocks, block_size) + row`` for each pool
+the model describes (``TransformerLM``: keys and values, a row of
+``kv_heads * head_dim``) and each running request holds an ordered list of block ids — its
 *block table*.  A sequence of ``n`` tokens costs ``ceil(n /
 block_size)`` blocks at its ACTUAL length, so thousands of mixed-
 length sequences share HBM with at most ``block_size - 1`` wasted

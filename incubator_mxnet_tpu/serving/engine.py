@@ -116,8 +116,9 @@ class ServingEngine:
         (``MXTPU_SERVE_STEP_TIMEOUT``; 0 disables)
     max_len : the most positions one request may hold, prompt and
         new tokens together (default: the model's ``_max_len``).  It
-        bounds a table row, the top prefill bucket, and what the
-        decode program gathers for every slot
+        bounds a table row, the top prefill bucket, and what a
+        decode program that gathers its context (the plain read) takes
+        in for every slot
 
     Decoding is greedy (temperature-0) — the batch-invariant mode
     whose outputs are provably identical to sequential
@@ -210,8 +211,8 @@ class ServingEngine:
 
         import jax.numpy as jnp
         # the model's cache: what one token leaves in one layer, a
-        # pool each (TransformerLM: keys and values per head, float32;
-        # LatentMoELM: one latent row in the weights' dtype)
+        # pool each (TransformerLM: a row of keys and one of values,
+        # float32; LatentMoELM: one latent row in the weights' dtype)
         self.cache_spec = tuple(
             {**c, "shape": tuple(c["shape"]),
              "dtype": str(jnp.dtype(c["dtype"]))}
@@ -309,6 +310,10 @@ class ServingEngine:
         self._m_preempt = telemetry.counter(
             "serving_preemptions_total")
         self._m_evict = telemetry.counter("serving_evictions_total")
+        self._m_live_blocks = telemetry.counter(
+            "serving_decode_blocks_live_total")
+        self._m_allowed_blocks = telemetry.counter(
+            "serving_decode_blocks_allowed_total")
         self._m_occ = telemetry.gauge("serving_batch_occupancy")
         self._m_util = telemetry.gauge(
             "serving_block_pool_utilization")
@@ -448,6 +453,18 @@ class ServingEngine:
 
     def _get_step_fn(self):
         if self._step_fn is None:
+            describe = getattr(self.model, "_paged_read", None)
+            if describe is not None:
+                # built where it is first called, so under the matmul
+                # precision its trace will see
+                import jax
+                platform = jax.default_backend()
+                tracing.trace_event(
+                    "serve_paged_read", engine=self.engine_id,
+                    platform=platform, max_batch=self.max_batch,
+                    max_blocks=self.max_blocks,
+                    block_size=self.block_size,
+                    **describe(self.block_size, platform))
             self._step_fn = self._counted_jit(
                 "decode", self.model._build_paged_step(
                     self.max_batch, self.max_blocks,
@@ -1362,7 +1379,7 @@ class ServingEngine:
         the process is the heartbeat monitor's."""
         import jax
         import jax.numpy as jnp
-        B, MB = self.max_batch, self.max_blocks
+        B, MB, bs = self.max_batch, self.max_blocks, self.block_size
         slots = self._sched.slots
         running = self._sched.n_running()
         with telemetry.span("serve_decode_prep",
@@ -1373,17 +1390,27 @@ class ServingEngine:
             tokens = np.zeros(B, np.int32)
             npast = np.zeros(B, np.int32)
             tables = np.zeros((B, MB), np.int32)
+            live_blocks = 0
             for i, req in enumerate(slots):
                 if req is None:
                     continue
                 tokens[i] = req.generated[-1]
                 npast[i] = req.n_past
                 tables[i, :len(req.block_ids)] = req.block_ids
+                # the blocks that hold positions 0 .. n_past: what a
+                # read through the table has to touch for this slot
+                live_blocks += req.n_past // bs + 1
             tables, npast, tokens = (jnp.asarray(tables),
                                      jnp.asarray(npast),
                                      jnp.asarray(tokens))
+        # live over allowed: the share of the allowed context
+        # (running x max_blocks, what a gather at max_len reads) that
+        # this step's slots hold
+        self._m_live_blocks.inc(live_blocks)
+        self._m_allowed_blocks.inc(running * MB)
         fn = self._get_step_fn()
-        with telemetry.span("serve_decode", running=running) as sp_dec:
+        with telemetry.span("serve_decode", running=running,
+                            live_blocks=live_blocks) as sp_dec:
             *pools, nxt, logits = fn(
                 self._wts, *self._pools, tables, npast, tokens)
             self._pools = tuple(pools)

@@ -597,10 +597,11 @@ def served_as_the_parent_did():
 def test_transformer_lm_serves_what_the_parent_served(
         served_as_the_parent_did, pos, kv):
     eng, reqs = served_as_the_parent_did[pos]
-    # its cache: keys and values per kv head, float32, as before
+    # its cache: keys and values, float32, as before; since PR 29
+    # every kv head's side by side in one row of whole lanes
     assert [c["name"] for c in eng.cache_spec] == ["k", "v"]
     assert [(a.shape, str(a.dtype)) for pool in eng._pools
-            for a in pool] == [((48, 4, kv or 4, 8), "float32")] * 4
+            for a in pool] == [((48, 4, (kv or 4) * 8), "float32")] * 4
     assert eng.max_len == 64 and eng.max_blocks == 16
     tokens, logits = PARENT[pos]
     assert [r.generated for r in reqs] == tokens

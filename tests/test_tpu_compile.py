@@ -8,8 +8,10 @@ Every such test lives in this one file: the topology is described in
 a module-scoped fixture, so only the xdist worker that is handed this
 file loads the TPU library, and every worker collects the same tests.
 """
+import functools
 import importlib.util
 import os
+import re
 
 import numpy as np
 import pytest
@@ -142,6 +144,108 @@ def test_routed_layer_compiles_for_v5e(one_chip, rows, width):
         leaf(n, width, d), leaf(n, d, width)).compile().as_text()
     grouped = (rows * k) % 128 == 0 and width % 128 == 0
     assert text.count("tpu_custom_call") == (3 if grouped else 0)
+
+
+@pytest.mark.parametrize("heads,kv_heads,head_dim,precision", [
+    pytest.param(32, 32, 64, None, id="opt-1.3b"),
+    pytest.param(16, 4, 128, None, id="gqa"),
+    pytest.param(32, 8, 128, "bfloat16", id="gqa-wide"),
+    pytest.param(32, 32, 64, "float32", id="opt-1.3b-float32"),
+])
+def test_paged_read_kernel_compiles_for_v5e(one_chip, heads, kv_heads,
+                                            head_dim, precision):
+    """``ops.paged_attention``: shapes that ``read_kind`` calls
+    ``kernel`` are shapes Mosaic takes (whole lanes, VMEM), and lowered
+    for the chip ``decode_attention`` is that kernel, unless the
+    caller asked for products above its one bfloat16 pass: then the
+    program holds no kernel (``chip_smoke.py``'s serve phase)."""
+    from incubator_mxnet_tpu.ops import paged_attention as pa
+    slots, block, row = 16, 16, kv_heads * head_dim
+    assert pa.read_kind(heads, kv_heads, head_dim, block,
+                        "float32") == "kernel"
+
+    def leaf(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt),
+                                    sharding=one_chip)
+
+    pool = leaf((1025, block, row))
+    with jax.default_matmul_precision(precision):
+        text = jax.jit(pa.decode_attention).lower(
+            leaf((slots, heads, head_dim)), leaf((slots, row)),
+            leaf((slots, row)), pool, pool,
+            leaf((slots, 128), jnp.int32),
+            leaf((slots,), jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") == \
+        (0 if precision == "float32" else 1)
+
+
+@pytest.fixture(scope="module")
+def opt_cell(one_chip):
+    """``TransformerLM`` at ``benchmark/configs/opt-1.3b.json``'s
+    widths, and the engine's arguments at the cell's shapes (16 slots,
+    128 blocks of 16 a row, 2049 blocks a pool, 8 layers, float32), all
+    abstract: nothing is initialized and nothing is held."""
+    from incubator_mxnet_tpu.gluon.model_zoo.transformer import \
+        TransformerLM
+    vocab, d, layers, slots, row_blocks, block, blocks = \
+        50272, 2048, 8, 16, 128, 16, 2049
+    lm = TransformerLM(vocab, d_model=d, n_layers=layers, n_heads=32,
+                       max_len=row_blocks * block)
+
+    def leaf(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def vec(n):
+        return leaf(n), leaf(n)
+
+    layer = dict(ln1=vec(d), qkv=(leaf(3 * d, d), leaf(3 * d)),
+                 proj=(leaf(d, d), leaf(d)), ln2=vec(d),
+                 up=(leaf(4 * d, d), leaf(4 * d)),
+                 down=(leaf(d, 4 * d), leaf(d)))
+    wts = dict(embed=leaf(vocab, d), pos=leaf(row_blocks * block, d),
+               ln_f=vec(d), head=leaf(vocab, d),
+               layers=[layer] * layers)
+    pools = [[leaf(blocks, block, *c["shape"], dtype=c["dtype"])
+              for _ in range(layers)] for c in lm._paged_cache()]
+    ints = functools.partial(leaf, dtype=jnp.int32)
+    return dict(
+        lm=lm, wts=wts, pools=pools, layers=layers,
+        decode=(lm._build_paged_step(slots, row_blocks, block),
+                (ints(slots, row_blocks), ints(slots), ints(slots))),
+        prefill=(lm._build_paged_prefill(1024, row_blocks, block),
+                 (ints(row_blocks), ints(), ints(1024), ints())))
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_paged_programs_copy_no_pool_on_v5e(opt_cell, program):
+    """The serve cell's programs as the engine jits them (pools
+    donated), compiled for the chip: the decode step reads through the
+    kernel, one a layer; no instruction copies an array of a pool's
+    shape (the parent's decode step held 32 such copies of 268.6 MB,
+    its temporaries 5.39 GB); every pool that goes in comes out in the
+    same buffer; the decode program's temporaries stay under 0.5 GB.
+    Counts of a compile, no times."""
+    fn, rest = opt_cell[program]
+    pools, layers = opt_cell["pools"], opt_cell["layers"]
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
+        opt_cell["wts"], *pools, *rest).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == \
+        (layers if program == "decode" else 0)
+    shape = ",".join(str(n) for n in pools[0][0].shape)
+    copied = [line.strip()[:120] for line in text.splitlines()
+              if re.search(rf"\[{shape}\]\S* copy\(", line)]
+    assert not copied, copied
+    # the module's header: {output index}: (parameter, {}, may-alias)
+    aliased = re.search(r"input_output_alias=\{(.*?) \}", text).group(1)
+    assert len(re.findall(r"\{\d+\}: \(\d+, \{\}", aliased)) \
+        == 2 * layers
+    memory = compiled.memory_analysis()
+    held = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+               for pool in pools for a in pool)
+    assert memory.alias_size_in_bytes >= held
+    if program == "decode":
+        assert memory.temp_size_in_bytes < 0.5e9
 
 
 def test_rtc_example_kernel_compiles_for_v5e(one_chip):
