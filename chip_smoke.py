@@ -307,7 +307,7 @@ def phase_serve(mx, args, lm):
     say("serve", requests=len(reqs),
         prompt_lengths=[len(p) for p in prompts],
         new_tokens=args.serve_new, stream_seconds=serve_s,
-        weights_dtype=eng.perf_report()["dtype"],
+        weights_dtype=str(lm.head.weight.data().dtype),
         matmul_precision="float32")
 
 

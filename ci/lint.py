@@ -13,7 +13,7 @@ import sys
 from pathlib import Path
 
 DEFAULT_PATHS = ["incubator_mxnet_tpu", "tools", "examples", "ci",
-                 "bench.py", "chip_smoke.py", "__graft_entry__.py"]
+                 "chip_smoke.py", "__graft_entry__.py"]
 MAX_LINE = 100
 
 # Framework modules that write checkpoint/state files.  In these,
@@ -81,10 +81,6 @@ HOT_SYNC_FILES = (
     # read array METADATA only — an accidental device sync here
     # would stall the hot paths every beat
     "incubator_mxnet_tpu/tracing.py",
-    # perf observatory: the MFU clock ticks on EVERY train step and
-    # the serving publisher runs inside the decode loop — both are
-    # wall-clock-only by contract (docs/observability.md)
-    "incubator_mxnet_tpu/perf/clock.py",
     # introspection plane: every debugz op is zero-device-sync by
     # contract — a varz/statusz poll against a busy rank must never
     # stall the step or decode loop (docs/observability.md
@@ -108,9 +104,6 @@ HOT_SYNC_FUNCS = {"step", "update", "__call__", "begin_step",
                   # tracing producers + memory sampling
                   "trace_event", "record", "device_memory_stats",
                   "update_memory_gauges", "_rss_bytes",
-                  # perf observatory (MFU gauges must stay
-                  # wall-clock-only; docs/observability.md)
-                  "tick", "_publish_perf",
                   # debugz op handlers + dispatch + provider fan-in:
                   # the whole introspection read path is host-side
                   "_handle", "_status_payload", "_op_varz",

@@ -13,9 +13,6 @@ stage="${1:-all}"
 
 run_lint() {
   python ci/lint.py
-  # bench regression gate: the committed BENCH history must gate
-  # clean (latest round vs best-so-far within the noise band)
-  python tools/bench_gate.py --check
 }
 
 run_native() {

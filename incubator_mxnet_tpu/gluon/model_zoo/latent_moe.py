@@ -510,59 +510,3 @@ class LatentMoELM(Block):
     def _decode_workspace_bytes(self, max_batch):
         """One decode step's logits and residual rows, float32."""
         return 4.0 * max_batch * (self.vocab + 8 * self._d)
-
-    def _matrix_params(self):
-        """Parameters a token passes through: (a layer's attention,
-        one expert, the dense FFN)."""
-        c, d, h = self._c, self._d, self.n_heads
-        attention = d * c["q_lora_rank"] \
-            + c["q_lora_rank"] * h * (self.d_nope + self.d_rope) \
-            + d * (self.rank + self.d_rope) \
-            + self.rank * h * (self.d_nope + self.d_v) \
-            + h * self.d_v * d
-        return (attention, 3 * d * c["moe_intermediate_size"],
-                3 * d * c["intermediate_size"])
-
-    def decode_flops_per_token(self, context_len):
-        """Operations to decode one token against ``context_len``
-        cached rows, as this model computes them: every matrix the
-        token passes through, the held experts at their expected
-        load, attention absorbed."""
-        attention, expert, dense = self._matrix_params()
-        routed = self._d * self.n_experts \
-            + expert * self._c.get("n_shared_experts", 0) \
-            * self.shared_here \
-            + expert * self.top_k * self.held[1] / self.n_experts
-        per_token = self.n_layers * attention + self.n_dense * dense \
-            + (self.n_layers - self.n_dense) * routed \
-            + self._d * self.vocab
-        return 2 * per_token + self.n_layers * 2 * self.n_heads \
-            * (2 * self.rank + self.d_rope) * context_len
-
-    def _decode_cost(self, context_len, batch, dtype_size):
-        """The analytic cost report of one batched decode step: every
-        held matrix read once, a latent row a cached position."""
-        from ...perf.cost_model import CostReport
-        head = 2.0 * batch * self._d * self.vocab
-        flat = batch * self.decode_flops_per_token(0)
-        att = batch * self.decode_flops_per_token(context_len) - flat
-        # embedding rows are looked up, the head is its own family
-        held = sum(int(np.prod(p.shape))
-                   for p in self.collect_params().values()) \
-            - 2 * self.vocab * self._d
-        row = self._paged_cache()[0]["shape"][0] * dtype_size
-        fams = {
-            "matmul": {"flops": flat - head, "ops": 12 * self.n_layers,
-                       "bytes": float(held) * dtype_size},
-            "attention": {"flops": att, "ops": self.n_layers,
-                          "bytes": float(batch * self.n_layers
-                                         * context_len * row)},
-            "embedding": {"flops": head, "ops": 1,
-                          "bytes": float(self.vocab * self._d)
-                          * dtype_size}}
-        n = 13 * self.n_layers + 1
-        return CostReport(
-            fams, sum(f["flops"] for f in fams.values()),
-            sum(f["bytes"] for f in fams.values()),
-            {"modeled": n, "zero": 0, "default": 0, "unknown": 0},
-            [], [], n)

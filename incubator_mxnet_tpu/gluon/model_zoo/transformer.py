@@ -778,17 +778,6 @@ class TransformerLM(Block):
         """One decode step's logits and residual stream, float32."""
         return 4.0 * max_batch * (self.head._units + 8 * self._d)
 
-    def _decode_cost(self, context_len, batch, dtype_size):
-        """The analytic cost report of one batched decode step."""
-        from ...perf import transformer_decode_cost
-        return transformer_decode_cost(
-            d_model=self._d, n_layers=self.n_layers,
-            vocab=self.head._units, context_len=context_len,
-            n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
-            mlp_ratio=self._mlp_ratio, attn_window=self.attn_window,
-            moe_experts=self.moe_experts, batch=batch,
-            dtype_size=dtype_size)
-
     def _paged_read(self, block_size, platform):
         """Which read of the cache the decode step takes where it is
         traced now and lowered for ``platform``, with the shapes that
@@ -992,50 +981,6 @@ class TransformerLM(Block):
             return new_k, new_v, nxt, logits
 
         return step
-
-    def train_flops_per_token(self, seq_len):
-        """Deterministic matmul-FLOPs per token for one fwd+bwd step
-        (the 3x-forward rule), for MFU accounting.  MoE: each token
-        runs TWO experts' FFNs (top-2 routing) plus the router."""
-        d = self._d
-        hid = self._mlp_ratio * d
-        if self.moe_experts:
-            e = self.moe_experts
-            # top-2: 2x one expert's up+down, + router matmul
-            mlp = 2 * (2 * 2 * d * hid) + 2 * d * e
-        else:
-            mlp = 2 * 2 * d * hid          # dense up+down
-        kvd = self.n_kv_heads * (d // self.n_heads)
-        att_span = min(seq_len, self.attn_window) \
-            if self.attn_window else seq_len
-        per_layer = (2 * d * (d + 2 * kvd)  # qkv (GQA-sized)
-                     + 2 * d * d            # proj
-                     + 2 * 2 * att_span * d  # scores + att@v (banded)
-                     + mlp)
-        vocab = self.head._units
-        fwd = self.n_layers * per_layer + 2 * d * vocab
-        return 3 * fwd
-
-    def decode_flops_per_token(self, context_len):
-        """Matmul FLOPs to decode ONE token against a KV cache of
-        ``context_len`` entries (no 3x rule — forward only), for
-        ``serving_mfu`` accounting (docs/observability.md)."""
-        d = self._d
-        hid = self._mlp_ratio * d
-        if self.moe_experts:
-            e = self.moe_experts
-            mlp = 2 * (2 * 2 * d * hid) + 2 * d * e
-        else:
-            mlp = 2 * 2 * d * hid
-        kvd = self.n_kv_heads * (d // self.n_heads)
-        span = min(context_len, self.attn_window) \
-            if self.attn_window else context_len
-        per_layer = (2 * d * (d + 2 * kvd)
-                     + 2 * d * d
-                     + 2 * 2 * span * d
-                     + mlp)
-        vocab = self.head._units
-        return self.n_layers * per_layer + 2 * d * vocab
 
 
 def transformer_lm(vocab_size=32000, size="small", **kwargs):

@@ -76,7 +76,6 @@ class Trainer:
 
         self._mem_unregister = tracing.register_param_opt_providers(
             self, _param_arrays, _opt_arrays)
-        self._perf_clock = None
         self._kvstore_spec = kvstore
         self._kvstore = None
         self._kv_initialized = False
@@ -117,26 +116,6 @@ class Trainer:
         """The step sentinel's NumericGuard (skip/bad-step counters,
         host-read accounting)."""
         return self._guard
-
-    def arm_perf(self, flops_per_step=0.0, bytes_per_step=0.0,
-                 tokens_per_step=0.0, dtype=None):
-        """Arm MFU/roofline gauges (docs/observability.md).
-
-        The Trainer has no graph to cost, so the caller supplies the
-        per-step work — e.g. ``perf.transformer_train_flops_per_token``
-        times tokens/step, or ``net.train_flops_per_token(...)``.  The
-        clock is wall-clock only: ``step()`` ticks it and it publishes
-        ``train_mfu``/``train_mbu``/``train_tokens_per_sec`` every
-        MXTPU_PERF_INTERVAL steps with zero device reads."""
-        from ..perf import TrainPerfClock
-        dev = jax.devices()[0]
-        if dtype is None:
-            dtype = "bfloat16" if dev.platform == "tpu" else "float32"
-        self._perf_clock = TrainPerfClock(
-            flops_per_step=flops_per_step,
-            bytes_per_step=bytes_per_step,
-            tokens_per_step=tokens_per_step, device=dev, dtype=dtype)
-        return self._perf_clock
 
     def set_learning_rate(self, lr):
         self._optimizer.set_learning_rate(lr)
@@ -263,9 +242,6 @@ class Trainer:
                 "call backward first, or set ignore_stale_grad=True")
 
         telemetry.counter("train_steps_total").inc()
-        if self._perf_clock is not None:
-            # wall-clock only: no device reads added to the step
-            self._perf_clock.tick()
         guarded = self._guard.enabled
         if self._fused_active():
             with telemetry.span("optimizer"):

@@ -52,9 +52,7 @@ class Module(BaseModule):
         self._mesh_dirty = False    # step params newer than exec dicts
         self._mesh_pending = False  # fused step ran; update() owes a no-op
         self._mesh_stale = False    # exec dicts newer than step params
-        self._perf_clock = None     # MFU gauges (perf observatory)
         self._perf_cost = None      # cached graph CostReport (3x fwd)
-        self._perf_tried = False    # don't re-cost after a failure
 
     # ------------------------------------------------------------ bind
     @property
@@ -437,13 +435,6 @@ class Module(BaseModule):
         DivergedError for fit's checkpoint rollback."""
         assert self.optimizer_initialized
         telemetry.counter("train_steps_total").inc()
-        # perf observatory: wall-clock-only MFU clock — the mesh
-        # step ticks its own, so only the executor path ticks here
-        if self._mesh_step is None:
-            if self._perf_clock is None and not self._perf_tried:
-                self._arm_perf_clock()
-            if self._perf_clock is not None:
-                self._perf_clock.tick()
         if self._mesh_step is not None:
             if self._mesh_pending:
                 # the optimizer already ran inside the fused mesh
@@ -530,19 +521,6 @@ class Module(BaseModule):
             self._perf_cost = perf.symbol_cost(
                 self._symbol, self._bound_shapes()).scaled(3.0)
         return self._perf_cost
-
-    def _arm_perf_clock(self):
-        """One-time arm of the train_mfu/train_mbu clock from the
-        graph cost model (bind-time work; never re-tried on
-        failure, never on the step path)."""
-        self._perf_tried = True
-        try:
-            from .. import perf
-            rep = self._graph_cost()
-            self._perf_clock = perf.TrainPerfClock(rep.flops,
-                                                   rep.bytes)
-        except Exception:
-            self._perf_clock = None
 
     def perf_report(self, xla_check=True):
         """Per-family cost/roofline report for the bound graph

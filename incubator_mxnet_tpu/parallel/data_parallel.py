@@ -162,9 +162,6 @@ class ShardedTrainStep:
         self.opt_state = self.opt.init(self.params)
         self._step = None
         self._eval = None
-        # perf observatory: armed by cost_analysis()/arm_perf(); a
-        # ticking clock publishes train_mfu/train_mbu from wall time
-        self._perf_clock = None
         self._calls = 0     # host-side count of __call__ (span field)
         # memory planner (docs/memory.md): the preflight gate's
         # accepted plan + the cached forward-liveness walk (both
@@ -425,31 +422,9 @@ class ShardedTrainStep:
                 if attempt:
                     raise oom from exc
                 self._oom_rung(oom, x)   # raises when the ladder is dry
-        if self._perf_clock is not None:
-            self._perf_clock.tick()   # wall-clock only, no syncs
         return loss
 
     step = __call__
-
-    def arm_perf(self, flops_per_step=0.0, bytes_per_step=0.0,
-                 tokens_per_step=0.0, dtype=None):
-        """Arm the train_mfu/train_mbu/train_tokens_per_sec gauges
-        with an analytic per-step cost (e.g. from the graph cost
-        model or ``model.train_flops_per_token * tokens``).  The
-        clock reads only wall time — zero added device syncs."""
-        from ..perf import TrainPerfClock
-        if dtype is None:
-            dtype = str(self.compute_dtype) if self.compute_dtype \
-                else "float32"
-        dev = self.mesh.devices.flat[0]
-        if self._perf_clock is None:
-            self._perf_clock = TrainPerfClock(
-                flops_per_step, bytes_per_step, tokens_per_step,
-                device=dev, dtype=dtype)
-        else:
-            self._perf_clock.arm(flops_per_step, bytes_per_step,
-                                 tokens_per_step, device=dev)
-        return self._perf_clock
 
     def lowered(self, x, y):
         """This train step lowered (``jax.stages.Lowered``) for a
@@ -487,21 +462,6 @@ class ShardedTrainStep:
             return compiled.memory_analysis()
         except Exception:   # oom-ok: probing an optional backend API
             return None
-
-    def cost_analysis(self, x, y):
-        """XLA's FLOP/bytes-accessed accounting for this train step —
-        the sibling of :meth:`memory_analysis`, and the cross-check
-        anchor for the analytic cost model (docs/observability.md
-        "Perf observatory").  Returns ``{"flops", "bytes"}`` or None
-        where the backend doesn't report.  On success the
-        train_mfu/train_mbu clock is armed with the measured step
-        cost (if not already armed), so subsequent steps publish MFU
-        with no further compiles or syncs."""
-        from ..perf import xla_cost
-        cost = xla_cost(self.lowered(x, y).compile())
-        if cost is not None and self._perf_clock is None:
-            self.arm_perf(cost["flops"], cost["bytes"])
-        return cost
 
     def evaluate(self, x, rng=None):
         """Compiled inference forward on a global batch."""

@@ -73,10 +73,6 @@ class SymbolTrainStep:
         run_symbol, self.graph_report = optimize_symbol(symbol)
         self._symbol = run_symbol
         self._run = build_graph_fn(run_symbol)
-        # perf observatory: analytic cost + MFU clock, armed on the
-        # first compile when concrete batch shapes are known
-        self.cost_report = None
-        self._perf_clock = None
         self._param_names = tuple(sorted(param_vals))
         self._input_names = tuple(input_names)
         self._batch_axis = batch_axis
@@ -259,7 +255,6 @@ class SymbolTrainStep:
                 raise
             raise oom from exc
         if compiled:
-            cost = self._arm_perf(vals)
             # first call = trace + compile of the whole mesh step;
             # recorded with the batch signature so a rebuilt step
             # (fresh Module bind / rollback) attributes what differed
@@ -269,36 +264,8 @@ class SymbolTrainStep:
                  "dtype": tuple(sorted(
                      (n, str(v.dtype)) for n, v in vals.items())),
                  "train_flag": True},
-                time.monotonic() - t0, cost=cost)
-        if self._perf_clock is not None:
-            self._perf_clock.tick()
+                time.monotonic() - t0)
         return outs
-
-    def _arm_perf(self, vals):
-        """Cost the optimized graph at the first batch's shapes (a
-        shape-only eval_shape walk — bind-time, never the step path)
-        and arm the train_mfu/train_mbu clock.  Returns the compile
-        ledger's cost summary, or None when costing fails."""
-        try:
-            from ..perf import TrainPerfClock, symbol_cost
-            shapes = {n: tuple(v.shape) for n, v in vals.items()}
-            shapes.update({n: tuple(v.shape)
-                           for n, v in self.params.items()})
-            shapes.update({n: tuple(v.shape)
-                           for n, v in dict(self.aux).items()})
-            # train step ~= 3x the forward graph (fwd + bwd)
-            self.cost_report = symbol_cost(self._symbol,
-                                           shapes).scaled(3.0)
-            dtype = str(next(iter(self.params.values())).dtype) \
-                if self.params else "float32"
-            self._perf_clock = TrainPerfClock(
-                self.cost_report.flops, self.cost_report.bytes,
-                dtype=dtype)
-            return self.cost_report.summary()
-        except Exception:
-            self.cost_report = None
-            self._perf_clock = None
-            return None
 
     def evaluate(self, inputs, rng=None):
         """Compiled inference forward over the mesh (score/predict)."""

@@ -1,5 +1,5 @@
 """Perf observatory: analytic graph cost model, device capability
-DB, roofline/MFU attribution, HBM memory planner
+DB, roofline attribution, HBM memory planner
 (docs/observability.md, docs/memory.md).
 
     from incubator_mxnet_tpu import perf
@@ -9,14 +9,10 @@ DB, roofline/MFU attribution, HBM memory planner
 """
 from .cost_model import (CostReport, DEFAULT_COST, ZERO_COST,
                          coverage_gaps, covered_ops, jit_cost,
-                         symbol_cost,
-                         transformer_decode_cost,
-                         transformer_decode_flops_per_token,
-                         transformer_train_flops_per_token, xla_cost)
+                         symbol_cost, xla_cost)
 from .device_db import (DEVICE_DB, DeviceCaps, caps_for,
                         caps_for_kind, hbm_capacity, headroom,
                         peak_flops, roofline)
-from .clock import TrainPerfClock
 from .memory_planner import (MemoryPlan, PreflightResult,
                              jaxpr_liveness, max_leaf_bytes,
                              next_divisor, plan_memory, preflight,
@@ -25,12 +21,9 @@ from .memory_planner import (MemoryPlan, PreflightResult,
 
 __all__ = [
     "CostReport", "DEFAULT_COST", "ZERO_COST", "coverage_gaps",
-    "covered_ops", "jit_cost", "symbol_cost",
-    "transformer_decode_cost", "transformer_decode_flops_per_token",
-    "transformer_train_flops_per_token", "xla_cost",
+    "covered_ops", "jit_cost", "symbol_cost", "xla_cost",
     "DEVICE_DB", "DeviceCaps", "caps_for", "caps_for_kind",
     "hbm_capacity", "headroom", "peak_flops", "roofline",
-    "TrainPerfClock",
     "MemoryPlan", "PreflightResult", "jaxpr_liveness",
     "max_leaf_bytes", "next_divisor", "plan_memory", "preflight",
     "sharded_tree_bytes", "symbol_liveness", "tree_bytes",

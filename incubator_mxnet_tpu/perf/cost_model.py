@@ -30,10 +30,7 @@ import numpy as np
 
 __all__ = ["symbol_cost", "CostReport", "covered_ops",
            "coverage_gaps", "ZERO_COST", "DEFAULT_COST",
-           "xla_cost", "jit_cost",
-           "transformer_train_flops_per_token",
-           "transformer_decode_flops_per_token",
-           "transformer_decode_cost"]
+           "xla_cost", "jit_cost"]
 
 
 def _prod(shape):
@@ -261,8 +258,7 @@ _register("_contrib_DeformableConvolution", "conv", _conv_flops)
 # --- attention family
 def _flash_flops(i, o, p):
     # q/k/v: (B*H, L, D); banded (window > 0) skips dead blocks, so
-    # the attended span per query is min(L, window) — the same
-    # ``att_span`` convention as transformer.train_flops_per_token
+    # the attended span per query is min(L, window)
     q = i[0]
     bh, length, d = q[0], q[1], q[2]
     window = int(p.get("window", 0) or 0)
@@ -592,97 +588,3 @@ def jit_cost(fn, *avals):
     except Exception:
         return None
     return xla_cost(compiled)
-
-
-# ------------------------------------------- analytic transformer cost
-def _transformer_dims(d_model, n_heads, n_kv_heads, mlp_ratio):
-    n_kv = n_kv_heads or n_heads
-    kv_d = d_model * n_kv // n_heads
-    hid = int(d_model * mlp_ratio)
-    return kv_d, hid
-
-
-def transformer_train_flops_per_token(
-        d_model, n_layers, vocab, seq_len, n_heads, n_kv_heads=None,
-        mlp_ratio=4, attn_window=0, moe_experts=0):
-    """Closed-form train FLOPs/token for the TransformerLM family —
-    the same primitive formulas as the graph pass (qkv/proj/mlp
-    matmuls at 2mnk, attention at 2 x 2 x att_span x d), times 3 for
-    fwd+bwd.  ``transformer.train_flops_per_token`` is asserted
-    against this (+-2%) by bench.py."""
-    kv_d, hid = _transformer_dims(d_model, n_heads, n_kv_heads,
-                                  mlp_ratio)
-    att_span = min(seq_len, attn_window) if attn_window else seq_len
-    per_layer = (2 * d_model * (d_model + 2 * kv_d)    # qkv proj
-                 + 2 * d_model * d_model               # out proj
-                 + 2 * 2 * att_span * d_model)         # scores + att@v
-    if moe_experts:
-        per_layer += (2 * 2 * (2 * d_model * hid)      # top-2 experts
-                      + 2 * d_model * moe_experts)     # router
-    else:
-        per_layer += 2 * 2 * d_model * hid             # dense mlp
-    fwd = n_layers * per_layer + 2 * d_model * vocab   # + lm head
-    return 3 * fwd
-
-
-def transformer_decode_flops_per_token(
-        d_model, n_layers, vocab, context_len, n_heads,
-        n_kv_heads=None, mlp_ratio=4, attn_window=0, moe_experts=0):
-    """Forward FLOPs to decode ONE token at a given KV-cache length
-    (attention span = min(context, window); no backward)."""
-    kv_d, hid = _transformer_dims(d_model, n_heads, n_kv_heads,
-                                  mlp_ratio)
-    span = min(context_len, attn_window) if attn_window \
-        else context_len
-    per_layer = (2 * d_model * (d_model + 2 * kv_d)
-                 + 2 * d_model * d_model
-                 + 2 * 2 * span * d_model)
-    if moe_experts:
-        per_layer += (2 * 2 * (2 * d_model * hid)
-                      + 2 * d_model * moe_experts)
-    else:
-        per_layer += 2 * 2 * d_model * hid
-    return n_layers * per_layer + 2 * d_model * vocab
-
-
-def transformer_decode_cost(
-        d_model, n_layers, vocab, context_len, n_heads,
-        n_kv_heads=None, mlp_ratio=4, attn_window=0, moe_experts=0,
-        batch=1, dtype_size=4):
-    """Per-family CostReport for one batched decode step (the serving
-    engine's unit of work): matmul / attention / embedding split with
-    bytes dominated by weight + KV-cache streaming."""
-    kv_d, hid = _transformer_dims(d_model, n_heads, n_kv_heads,
-                                  mlp_ratio)
-    span = min(context_len, attn_window) if attn_window \
-        else context_len
-    b = float(batch)
-    mm_flops = b * n_layers * (
-        2 * d_model * (d_model + 2 * kv_d) + 2 * d_model * d_model
-        + (2 * 2 * (2 * d_model * hid) + 2 * d_model * moe_experts
-           if moe_experts else 2 * 2 * d_model * hid))
-    att_flops = b * n_layers * 2 * 2 * span * d_model
-    emb_flops = b * 2 * d_model * vocab
-    # decode is weight-streaming: every weight read once per step,
-    # plus the live KV window per layer, plus the logits row
-    n_experts_live = 2 if moe_experts else 1
-    w_bytes = n_layers * (
-        d_model * (d_model + 2 * kv_d) + d_model * d_model
-        + n_experts_live * 2 * d_model * hid) * dtype_size \
-        + d_model * vocab * dtype_size
-    kv_bytes = b * n_layers * 2 * span * kv_d * dtype_size
-    emb_bytes = b * vocab * dtype_size
-    fams = {
-        "matmul": {"flops": mm_flops, "bytes": float(w_bytes),
-                   "ops": 4 * n_layers},
-        "attention": {"flops": att_flops, "bytes": float(kv_bytes),
-                      "ops": n_layers},
-        "embedding": {"flops": emb_flops, "bytes": float(emb_bytes),
-                      "ops": 1},
-    }
-    flops = mm_flops + att_flops + emb_flops
-    byts = float(w_bytes + kv_bytes + emb_bytes)
-    return CostReport(fams, flops, byts,
-                      {"modeled": 6 * n_layers + 1, "zero": 0,
-                       "default": 0, "unknown": 0},
-                      [], [], 6 * n_layers + 1)

@@ -2,10 +2,8 @@
 FLOP/s and HBM bandwidth per device kind (docs/observability.md
 "Perf observatory").
 
-Previously ``bench.py`` kept a private ``_PEAK_FLOPS`` table and every
-MFU number in a BENCH round was computed against it; roofline
-classification needs bandwidth too, so both live here and ``bench.py``
-imports them.  Peaks are dense-matmul peaks for the MXU-native dtype
+Roofline classification needs bandwidth beside the FLOP/s peak, so
+both live here.  Peaks are dense-matmul peaks for the MXU-native dtype
 (bf16 on TPU); other dtypes derive by documented convention:
 
 - ``bf16`` / ``fp16``: the MXU peak (the table value)
@@ -14,8 +12,8 @@ imports them.  Peaks are dense-matmul peaks for the MXU-native dtype
 - ``int8``: 2x the bf16 peak on v5e-generation and newer parts that
   advertise int8 MXU throughput; bf16 peak elsewhere
 
-CPU hosts get *nominal* numbers so the roofline plumbing (bench
-``perf_report`` mode, CI tests) produces a verdict on a CPU-only
+CPU hosts get *nominal* numbers so the roofline plumbing
+(``Module.perf_report()``, CI tests) produces a verdict on a CPU-only
 host; they are order-of-magnitude placeholders, overridable via
 ``MXTPU_PERF_CPU_PEAK_GFLOPS`` / ``MXTPU_PERF_CPU_GBPS``, and every
 report that uses them carries ``"nominal_peaks": true``.

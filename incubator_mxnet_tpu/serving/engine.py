@@ -77,8 +77,7 @@ def _next_pow2(n):
 # what a served model offers (docs/serving.md, "The paged protocol")
 PAGED_PROTOCOL = ("_max_len", "n_layers", "_check_paged", "_paged_cache",
                   "_decode_weights", "_build_paged_prefill",
-                  "_build_paged_step", "_decode_workspace_bytes",
-                  "decode_flops_per_token", "_decode_cost")
+                  "_build_paged_step", "_decode_workspace_bytes")
 
 
 class ServingEngine:
@@ -341,18 +340,6 @@ class ServingEngine:
         self._m_moe = [telemetry.counter(f"serving_moe_{n}_total")
                        for n in ("routed_rows", "padded_rows",
                                  "experts_touched", "layer_steps")]
-        # perf observatory (docs/observability.md): MFU from the
-        # analytic decode-FLOPs ledger — token counts and context
-        # lengths are already host-side, so this adds no syncs
-        self._m_mfu = telemetry.gauge("serving_mfu")
-        self._m_ftok = telemetry.gauge("serving_flops_per_token")
-        self._perf_interval = max(1, int(get_env(
-            "MXTPU_PERF_INTERVAL")))
-        self._perf_flops = 0.0
-        self._perf_tokens = 0
-        self._perf_iters = 0
-        self._perf_t0 = None
-        self._perf_caps = None
 
     # ---------------------------------------------------------- setup
     def _auto_num_blocks(self):
@@ -674,9 +661,6 @@ class ServingEngine:
             self._m_util.set(self.pool.utilization())
             self._m_qdepth.set(len(self._sched.waiting))
             self._m_qtokens.set(self._sched.queued_tokens)
-            self._perf_iters += 1
-            if self._perf_iters >= self._perf_interval:
-                self._publish_perf()
             sp.set(emitted=len(events))
         return events
 
@@ -696,73 +680,6 @@ class ServingEngine:
             self._m_moe[2].inc(touched)
             self._m_moe[3].inc(layers)
         return touched
-
-    # ------------------------------------------------ perf observatory
-    def _serve_dtype(self):
-        """Weight-stream dtype for roofline math: int8 when the
-        weights are quantized, else the dtype the parameters are held
-        and multiplied in."""
-        if self.quantized:
-            return "int8"
-        return str(self._wts["head"].dtype)
-
-    def _caps(self):
-        if self._perf_caps is None:
-            import jax
-            from ..perf import caps_for
-            self._perf_caps = caps_for(jax.devices()[0])
-        return self._perf_caps
-
-    def _publish_perf(self):
-        """Publish ``serving_mfu`` / ``serving_flops_per_token`` from
-        the decode-FLOPs ledger accumulated over the last
-        MXTPU_PERF_INTERVAL iterations.  Wall-clock only."""
-        now = time.monotonic()
-        if self._perf_t0 is not None and self._perf_tokens:
-            dt = now - self._perf_t0
-            if dt > 0:
-                peak = self._caps().peak(self._serve_dtype())
-                if peak:
-                    self._m_mfu.set(self._perf_flops / dt / peak)
-                self._m_ftok.set(
-                    self._perf_flops / self._perf_tokens)
-        self._perf_t0 = now
-        self._perf_flops = 0.0
-        self._perf_tokens = 0
-        self._perf_iters = 0
-
-    def perf_report(self, context_len=None, batch=None):
-        """Analytic per-family cost/roofline report for one batched
-        decode step (docs/observability.md "Perf observatory").
-
-        Defaults reflect the live batch: ``context_len`` is the mean
-        running KV length (half the model's context when idle) and
-        ``batch`` is the running-slot count (``max_batch`` when
-        idle).  Pure host arithmetic — safe to call in production."""
-        m = self.model
-        running = [r for r in self._sched.slots if r is not None]
-        if context_len is None:
-            context_len = (
-                int(sum(r.n_past for r in running) / len(running))
-                if running else max(1, self.max_len // 2))
-        if batch is None:
-            batch = len(running) or self.max_batch
-        dtype = self._serve_dtype()
-        dtype_size = {"int8": 1, "bfloat16": 2}.get(dtype, 4)
-        rep = m._decode_cost(context_len, batch, dtype_size)
-        from ..perf import roofline
-        caps = self._caps()
-        return {
-            "context_len": int(context_len),
-            "batch": int(batch),
-            "dtype": dtype,
-            "device": caps.kind,
-            "flops_per_token": float(
-                m.decode_flops_per_token(context_len)),
-            "per_family": rep.table(caps, dtype),
-            "total": rep.summary(),
-            "roofline": roofline(rep.flops, rep.bytes, caps, dtype),
-        }
 
     def stream(self):
         """Drive the engine, yielding ``(request, token_id)`` events
@@ -1445,11 +1362,6 @@ class ServingEngine:
             for i, req in enumerate(list(slots)):
                 if req is None:
                     continue
-                # perf ledger: analytic FLOPs for this token at its
-                # context length (host arithmetic; no device reads)
-                self._perf_flops += self.model.decode_flops_per_token(
-                    req.n_past)
-                self._perf_tokens += 1
                 req.n_past += 1
                 if self.keep_logits:
                     req.logits = logits[i]

@@ -517,14 +517,10 @@ class CompileLedger:
         self._sigs = deque(maxlen=self.MAX_SIGS)
         self._lock = threading.Lock()
 
-    def record(self, signature, seconds, cost=None):
+    def record(self, signature, seconds):
         """Attribute + publish one compile.  ``signature`` is the
         flat component dict (see :func:`signature_diff`); ``seconds``
-        the wall-clock trace+compile time the caller measured;
-        ``cost`` (optional) the analytic cost-model summary of the
-        recompiled graph (``perf.CostReport.summary()``: total
-        GFLOPs, GBytes, arithmetic intensity), so retrace
-        attribution also says how expensive the graph is.
+        the wall-clock trace+compile time the caller measured.
         Returns the attribution reason.
 
         Honors the disabled-mode contract: with ``MXTPU_TELEMETRY=0``
@@ -539,11 +535,9 @@ class CompileLedger:
             self._sigs.append(sig)
         telemetry.counter("compile_events_total").inc()
         telemetry.histogram("compile_seconds").observe(seconds)
-        extra = {"cost": dict(cost)} if cost else {}
         trace_event("compile", site=self.site, reason=reason,
                     changed=changed, seconds=round(float(seconds), 6),
-                    signature={k: repr(v) for k, v in sig.items()},
-                    **extra)
+                    signature={k: repr(v) for k, v in sig.items()})
         _budget_check(self.site, seconds)
         return reason
 

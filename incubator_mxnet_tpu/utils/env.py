@@ -386,19 +386,10 @@ register_env("MXTPU_DEVICE_PREFETCH_DEPTH", int, 2,
              "producer outruns the depth-2 default")
 
 # Perf observatory (docs/observability.md "Perf observatory").
-register_env("MXTPU_PERF_INTERVAL", int, 10,
-             "training steps between train_mfu/train_mbu/"
-             "train_tokens_per_sec gauge publications when no guard "
-             "cadence is supplied; publication is wall-clock-only "
-             "and never adds a device->host sync")
-register_env("MXTPU_PERF_GATE_BAND", float, 0.10,
-             "relative noise band tools/bench_gate.py tolerates "
-             "before a headline metric below best-so-far counts as "
-             "a regression (0.10 = 10%)")
 register_env("MXTPU_PERF_CPU_PEAK_GFLOPS", float, 100.0,
              "nominal peak GFLOP/s assumed for a CPU host in the "
-             "device capability DB (perf/device_db.py) so roofline/"
-             "MFU plumbing produces a verdict on CPU-only runs; "
+             "device capability DB (perf/device_db.py) so roofline "
+             "plumbing produces a verdict on CPU-only runs; "
              "reports computed against it carry nominal_peaks=true")
 register_env("MXTPU_PERF_CPU_GBPS", float, 25.0,
              "nominal CPU memory bandwidth (GB/s) for the device "

@@ -20,6 +20,8 @@ from incubator_mxnet_tpu.graph import (CachedOp, Graph, PassManager,
 from incubator_mxnet_tpu.graph.fuse import FusedOp
 from incubator_mxnet_tpu.symbol.symbol import _topo
 
+import _graphs
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -85,6 +87,38 @@ def test_golden_equivalence_bitwise_across_levels(seed, monkeypatch):
         for o_ref, o_opt in zip(outs[0], outs[lv]):
             assert np.array_equal(o_ref, o_opt), \
                 f"level {lv} diverged (seed {seed})"
+
+
+# nodes before -> after level 2 on the graphs a frontend hands over
+# (tests/_graphs.py): what the passes remove is counted, not timed
+@pytest.mark.parametrize("graph,before,after", [
+    ("mlp", 32, 30), ("resnet_block", 18, 17),
+    ("transformer_step", 167, 121)])
+def test_frontend_graphs_bitwise_and_smaller_at_level_2(
+        graph, before, after, monkeypatch):
+    s, shapes = getattr(_graphs, f"_graph_{graph}")(sym)
+    _, report = s.optimize(level=2)
+    assert (report["nodes_before"], report["nodes_after"]) == \
+        (before, after)
+    outs = {}
+    for level in (0, 2):
+        monkeypatch.setenv("MXTPU_GRAPH_OPT", str(level))
+        exe = s.simple_bind(mx.cpu(), grad_req="null", **shapes)
+        rs = np.random.RandomState(42)
+        vals = {}
+        for name in sorted(exe.arg_dict):
+            shape = exe.arg_dict[name].shape
+            if name in ("label", "labels", "tokens"):
+                vals[name] = nd.array(
+                    rs.randint(0, 10, shape).astype("float32"))
+            else:
+                vals[name] = nd.array(
+                    (rs.rand(*shape) * 0.1 - 0.05).astype("float32"))
+        exe.copy_params_from(vals)
+        outs[level] = [o.asnumpy() for o in exe.forward()]
+    assert outs[0] and len(outs[0]) == len(outs[2])
+    for a, b in zip(outs[0], outs[2]):
+        assert np.array_equal(a, b)
 
 
 def test_golden_equivalence_gradients(monkeypatch):

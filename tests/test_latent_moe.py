@@ -22,12 +22,14 @@ from benchmark import train, weights  # noqa: E402
 from benchmark.models import latent_moe_lm as fam  # noqa: E402
 from benchmark.reference import latent_moe as ref  # noqa: E402
 from incubator_mxnet_tpu import telemetry  # noqa: E402
-from incubator_mxnet_tpu.gluon.model_zoo.latent_moe import \
-    LatentMoELM  # noqa: E402
+from incubator_mxnet_tpu.gluon.model_zoo.transformer import \
+    TransformerLM  # noqa: E402
 from incubator_mxnet_tpu.ops import moe  # noqa: E402
 from incubator_mxnet_tpu.ops.moe import (  # noqa: E402
     gated_ffn, route_top_k, routed_ffn_fn)
 from incubator_mxnet_tpu.serving import ServingEngine  # noqa: E402
+from incubator_mxnet_tpu.serving.engine import \
+    PAGED_PROTOCOL  # noqa: E402
 
 # hidden 64, 4 heads, latent 16 + rope 8, 8 experts top 2, one dense
 # and two expert layers
@@ -447,6 +449,55 @@ def test_max_len_bounds_what_the_decode_program_gathers(f32):
         ServingEngine(block, max_len=5000)
 
 
+EIGHT = ("_max_len", "n_layers", "_check_paged", "_paged_cache",
+         "_decode_weights", "_build_paged_prefill",
+         "_build_paged_step", "_decode_workspace_bytes")
+
+
+class _Offers:
+    """The named members of a model, and nothing else of it."""
+
+    def __init__(self, model, names):
+        for name in names:
+            setattr(self, name, getattr(model, name))
+
+
+@pytest.fixture(scope="module")
+def families(f32):
+    mx.random.seed(0)
+    opt = TransformerLM(CFG["vocab_size"], d_model=32, n_layers=2,
+                        n_heads=4, max_len=64)
+    opt.initialize(mx.initializer.Xavier())
+    opt(mx.nd.array(np.zeros((1, 4), "int32")))   # deferred shapes
+    return {"TransformerLM": opt, "LatentMoELM": f32[0]}
+
+
+@pytest.mark.parametrize("lacking", EIGHT + (None,))
+@pytest.mark.parametrize("family", ["TransformerLM", "LatentMoELM"])
+def test_the_engine_serves_by_the_eight_members(families, family,
+                                                lacking):
+    """Each of the eight is asked for by name, and the eight are all
+    a model has to bring: no count of its operations, no class."""
+    assert PAGED_PROTOCOL == EIGHT
+    model = families[family]
+    kw = dict(max_batch=2, block_size=4, num_blocks=32)
+    if lacking is not None:
+        less = _Offers(model, [n for n in EIGHT if n != lacking])
+        with pytest.raises(TypeError,
+                           match=rf"_Offers lacks {lacking}$"):
+            ServingEngine(less, **kw)
+        return
+    prompt = [int(t) for t in _tokens(1, 7, seed=3)[0]]
+    served = []
+    for offered in (_Offers(model, EIGHT), model):
+        eng = ServingEngine(offered, **kw)
+        req = eng.submit(prompt, 5)
+        eng.run()
+        assert req.state == "finished" and len(req.generated) == 5
+        served.append(req.generated)
+    assert served[0] == served[1]
+
+
 def test_the_engine_asks_for_the_protocol_not_the_class(f32):
     with pytest.raises(TypeError, match="paged protocol"):
         ServingEngine(mx.gluon.nn.Dense(4))
@@ -455,9 +506,6 @@ def test_the_engine_asks_for_the_protocol_not_the_class(f32):
         ServingEngine(block, quantize="int8")
     eng = ServingEngine(block, max_batch=2, block_size=4,
                         num_blocks=16)
-    report = eng.perf_report(100, 2)
-    assert report["flops_per_token"] == \
-        block.decode_flops_per_token(100)
     assert eng.stats()["cache"] == {
         "pools": [{"name": "latent", "shape": (128,),
                    "dtype": "float32", "values": 24}],
@@ -530,11 +578,6 @@ def test_the_familys_counts_are_the_publisheds_form():
         + pair * 1000
     assert fam.prefill_flops(cfg, 1000) == 2 * layers * 1000 + head \
         + pair * 1000 * 1001 // 2
-    # the program's own ledger counts the absorbed form: more a
-    # position, the same matrices
-    lm = LatentMoELM(cfg)
-    assert lm.decode_flops_per_token(0) == 2 * layers + head
-    assert lm.decode_flops_per_token(1000) > fam.decode_flops(cfg, 1000)
 
 
 # tokens and the first six logits of each request's last step that the

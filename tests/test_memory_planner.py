@@ -23,6 +23,8 @@ from incubator_mxnet_tpu import resilience as rz
 from incubator_mxnet_tpu import symbol as symmod
 from incubator_mxnet_tpu.perf import memory_planner as mp
 
+import _graphs
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # which placeholder names in each bench graph are inputs (the rest
@@ -32,14 +34,6 @@ GRAPH_INPUTS = {
     "resnet_block": {"data"},
     "transformer_step": {"tokens", "labels"},
 }
-
-
-def _load_bench():
-    sys.path.insert(0, REPO)
-    try:
-        return importlib.import_module("bench")
-    finally:
-        sys.path.pop(0)
 
 
 @pytest.fixture(autouse=True)
@@ -97,8 +91,7 @@ def test_memory_plan_total_and_describe():
 
 # ---------------------------------------------------------- grads model
 def _mlp_liveness():
-    bench = _load_bench()
-    s, shapes = bench._graph_mlp(symmod)
+    s, shapes = _graphs._graph_mlp(symmod)
     return mp.symbol_liveness(s, shapes,
                               input_names=GRAPH_INPUTS["mlp"])
 
@@ -195,7 +188,7 @@ def _train_compiled(s, shapes, inputs, grad_accum=1, remat=False,
 
 # The band for resnet_block-1 is 30%, not 20%: XLA:CPU's accounting
 # moved, the planner did not.  The plan is 1,296,384 B today as it was
-# when BENCH_r19.json recorded it (1.24 MiB, -6.1% of XLA:CPU's 1.32
+# under an earlier jaxlib (1.24 MiB, -6.1% of that XLA:CPU's 1.32
 # MiB); the XLA:CPU of the installed jaxlib 0.9.0 assigns this conv
 # block 1,347,328 B of temporaries and comes to 1.69 MiB (-26.9%),
 # the other four cases moving by 0-3%.  Those temporaries belong to
@@ -210,8 +203,7 @@ def _train_compiled(s, shapes, inputs, grad_accum=1, remat=False,
 ], ids=["mlp-1", "mlp-2", "resnet_block-1", "resnet_block-2",
         "transformer_step-1"])
 def test_planner_within_20pct_of_xla(graph, accum, band):
-    bench = _load_bench()
-    s, shapes = getattr(bench, f"_graph_{graph}")(symmod)
+    s, shapes = getattr(_graphs, f"_graph_{graph}")(symmod)
     inputs = GRAPH_INPUTS[graph]
     compiled = _train_compiled(s, shapes, inputs, grad_accum=accum)
     xla = mp.xla_live_bytes(compiled.memory_analysis())
@@ -230,8 +222,7 @@ def test_planner_within_20pct_of_xla(graph, accum, band):
 def test_remat_and_accum_move_the_plan_directionally(graph):
     # planner-only: CPU XLA does not shrink temps under
     # jax.checkpoint, so remat is asserted against the model itself
-    bench = _load_bench()
-    s, shapes = getattr(bench, f"_graph_{graph}")(symmod)
+    s, shapes = getattr(_graphs, f"_graph_{graph}")(symmod)
     live = mp.symbol_liveness(s, shapes,
                               input_names=GRAPH_INPUTS[graph])
     base = mp.plan_memory(liveness=live)
@@ -244,8 +235,7 @@ def test_remat_and_accum_move_the_plan_directionally(graph):
 
 
 def test_remat_strictly_helps_on_a_deep_graph():
-    bench = _load_bench()
-    s, shapes = bench._graph_resnet_block(symmod)
+    s, shapes = _graphs._graph_resnet_block(symmod)
     live = mp.symbol_liveness(s, shapes,
                               input_names=GRAPH_INPUTS["resnet_block"])
     assert live["forward_peak_bytes"] < live["retained_bytes"]

@@ -1,46 +1,26 @@
-"""Perf observatory (docs/observability.md): analytic graph cost
-model vs XLA's own cost_analysis on the three bench graphs, device-DB
-/ roofline unit semantics, model-method FLOPs parity with the shared
-formulas, the transfer-budget proof that the MFU gauges add zero
-device->host reads, Module/ServingEngine perf_report tables,
-launch.py fleet-MFU aggregation, op-cost lint coverage, and the
-bench_gate regression gate over synthetic and real trajectories."""
-import json
+"""The Symbol graph's static cost model (docs/observability.md):
+its totals against XLA's own cost_analysis on the three graphs of
+tests/_graphs.py and on ResNet-50, device-DB / roofline unit
+semantics, Module.perf_report tables, and op-cost lint coverage."""
 import os
 import sys
-import warnings
 
 import numpy as np
 import pytest
 
+import jax
+
 import incubator_mxnet_tpu as mx
-from incubator_mxnet_tpu import autograd, gluon, nd, perf
-from incubator_mxnet_tpu import optimizer as opt_mod
+from incubator_mxnet_tpu import perf
 from incubator_mxnet_tpu import symbol as symmod
 from incubator_mxnet_tpu import telemetry as tel
-from incubator_mxnet_tpu.gluon import nn
+from incubator_mxnet_tpu.executor import build_graph_fn
 from incubator_mxnet_tpu.ops import registry as op_registry
 from incubator_mxnet_tpu.perf import cost_model, device_db
 
+import _graphs
+
 REPO = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
-
-
-def _load_tool(name):
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import importlib
-        return importlib.import_module(name)
-    finally:
-        sys.path.pop(0)
-
-
-def _load_bench():
-    sys.path.insert(0, REPO)
-    try:
-        import importlib
-        return importlib.import_module("bench")
-    finally:
-        sys.path.pop(0)
 
 
 @pytest.fixture(autouse=True)
@@ -52,29 +32,63 @@ def _fresh_telemetry():
 
 # ------------------------------------------------- analytic vs XLA
 # The acceptance bar for the cost model: its totals must track XLA's
-# own compiled cost_analysis within 10% on the three bench graphs.
+# own compiled cost_analysis within 10% on the three graphs.
+def _analytic_vs_xla(s, shapes):
+    """(CostReport, XLA's cost dict or None, relative FLOPs gap or
+    None) for one graph's forward at fixed shapes."""
+    rep = perf.symbol_cost(s, shapes)
+    arg_names = s.list_arguments()
+    aux_names = s.list_auxiliary_states()
+    known = {k: v for k, v in shapes.items()
+             if k in set(arg_names) | set(aux_names)}
+    arg_shapes, _, aux_shapes = s.infer_shape_partial(**known)
+    run = build_graph_fn(s)
+    args = {n: jax.ShapeDtypeStruct(tuple(sh), np.float32)
+            for n, sh in zip(arg_names, arg_shapes)}
+    auxs = {n: jax.ShapeDtypeStruct(tuple(sh), np.float32)
+            for n, sh in zip(aux_names, aux_shapes)}
+    rng = jax.ShapeDtypeStruct((2,), np.uint32)
+    xc = perf.jit_cost(lambda av, xv, r: run(av, xv, r, False),
+                       args, auxs, rng)
+    delta = (abs(rep.flops - xc["flops"]) / xc["flops"]
+             if xc and xc.get("flops") else None)
+    return rep, xc, delta
+
+
 @pytest.mark.parametrize("graph", ["mlp", "resnet_block",
                                    "transformer_step"])
 def test_analytic_flops_within_10pct_of_xla(graph):
-    bench = _load_bench()
-    builder = getattr(bench, f"_graph_{graph}")
-    s, shapes = builder(symmod)
-    rep, xc, delta = bench._analytic_vs_xla(s, shapes)
+    s, shapes = getattr(_graphs, f"_graph_{graph}")(symmod)
+    rep, xc, delta = _analytic_vs_xla(s, shapes)
     assert rep.flops > 0 and rep.bytes > 0
     assert xc is not None and xc["flops"] > 0, \
         "backend reported no cost_analysis"
     assert delta is not None and delta <= 0.10, \
         f"{graph}: analytic {rep.flops:.3e} vs XLA " \
         f"{xc['flops']:.3e} (delta {delta:.1%})"
-    # full coverage on the bench graphs: no unknown or default-cost
-    # nodes sneak into the headline numbers
+    # full coverage on these graphs: no unknown or default-cost
+    # nodes sneak into the totals
     assert rep.coverage["unknown"] == 0, rep.unknown_ops
     assert rep.coverage["default"] == 0, rep.default_ops
 
 
+def test_resnet50_forward_flops_are_the_published_count():
+    """7.826 GFLOPs a 224 x 224 image with a multiply-add counted as
+    two: an edit to the model or to the cost pass that moves the
+    count by 2% shows here."""
+    mx.random.seed(0)
+    net = mx.gluon.model_zoo.vision.resnet50_v1()
+    net.initialize(mx.initializer.Xavier())
+    net(mx.nd.zeros((1, 3, 32, 32)))     # settles the deferred shapes
+    rep = perf.symbol_cost(net._to_symbol(symmod.Variable("data")),
+                           {"data": (1, 3, 224, 224)})
+    assert rep.flops == pytest.approx(7.826e9, rel=0.02)
+    assert rep.coverage["unknown"] == 0, rep.unknown_ops
+    assert rep.scaled(3.0).flops == pytest.approx(3 * rep.flops)
+
+
 def test_cost_report_families_scaling_and_table():
-    bench = _load_bench()
-    s, shapes = bench._graph_transformer_step(symmod)
+    s, shapes = _graphs._graph_transformer_step(symmod)
     rep = perf.symbol_cost(s, shapes)
     # the symbol-level transformer step spells attention out as
     # matmuls + elementwise (no fused attention op in the graph)
@@ -154,98 +168,6 @@ def test_roofline_units_and_bound_classification():
     assert device_db.roofline(0, 0, caps)["bound"] == "idle"
 
 
-# ------------------------------------- model-method formula parity
-def test_transformer_flops_methods_match_shared_formulas():
-    from incubator_mxnet_tpu.gluon.model_zoo.transformer import \
-        TransformerLM
-    net = TransformerLM(64, d_model=32, n_layers=2, n_heads=4,
-                        max_len=16)
-    assert net.train_flops_per_token(16) == \
-        perf.transformer_train_flops_per_token(
-            d_model=32, n_layers=2, vocab=64, seq_len=16, n_heads=4)
-    assert net.decode_flops_per_token(12) == \
-        perf.transformer_decode_flops_per_token(
-            d_model=32, n_layers=2, vocab=64, context_len=12,
-            n_heads=4)
-    # windowed attention caps the context term
-    win = TransformerLM(64, d_model=32, n_layers=2, n_heads=4,
-                        max_len=64, attn_window=8)
-    assert win.decode_flops_per_token(64) == \
-        win.decode_flops_per_token(8)
-
-
-# -------------------------------------------- transfer-budget proof
-def test_mfu_gauges_add_zero_host_reads(monkeypatch):
-    """The zero-added-syncs contract: with the sentinel at guard
-    interval 4 and the MFU clock armed and PUBLISHING (interval 2),
-    the sole device->host transfer point (read_window_bad) still
-    fires exactly twice over 8 steps — the same count as the
-    perf-off baseline in test_sentinel.py."""
-    monkeypatch.setenv("MXTPU_NONFINITE_POLICY", "skip")
-    monkeypatch.setenv("MXTPU_GUARD_INTERVAL", "4")
-    monkeypatch.setenv("MXTPU_PERF_INTERVAL", "2")
-    reads = []
-    orig = opt_mod.read_window_bad
-    monkeypatch.setattr(opt_mod, "read_window_bad",
-                        lambda g: reads.append(1) or orig(g))
-    mx.random.seed(0)
-    rs = np.random.RandomState(0)
-    data = rs.randn(80, 10).astype("float32")
-    labels = rs.randint(0, 3, 80).astype("float32")
-    net = nn.HybridSequential()
-    net.add(nn.Dense(16, activation="relu"))
-    net.add(nn.Dense(3))
-    net.initialize(mx.init.Xavier())
-    trainer = gluon.Trainer(net.collect_params(), "sgd",
-                            {"learning_rate": 0.01})
-    clock = trainer.arm_perf(flops_per_step=1e9,
-                             bytes_per_step=1e8,
-                             tokens_per_step=10)
-    assert clock is trainer._perf_clock
-    loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore")
-        for step in range(8):
-            lo = (step * 10) % len(data)
-            x = nd.array(data[lo:lo + 10])
-            y = nd.array(labels[lo:lo + 10])
-            with autograd.record():
-                loss = loss_fn(net(x), y)
-            loss.backward()
-            trainer.step(10)
-    assert len(reads) == 2, \
-        f"perf gauges changed the transfer budget: {len(reads)} reads"
-    gauges = tel.snapshot()["gauges"]
-    assert gauges["train_mfu"] > 0
-    assert gauges["train_mbu"] > 0
-    assert gauges["train_tokens_per_sec"] > 0
-
-
-def test_sharded_step_cost_analysis_arms_clock(monkeypatch):
-    monkeypatch.setenv("MXTPU_PERF_INTERVAL", "2")
-    from incubator_mxnet_tpu import parallel
-    mx.random.seed(0)
-    net = nn.HybridSequential()
-    net.add(nn.Dense(16, activation="relu"))
-    net.add(nn.Dense(4))
-    net.initialize(mx.init.Xavier())
-    step = parallel.ShardedTrainStep(
-        net, optimizer="sgd",
-        optimizer_params={"learning_rate": 0.01},
-        example_args=[mx.nd.zeros((2, 8))])
-    rs = np.random.RandomState(0)
-    x = np.asarray(rs.rand(8, 8), np.float32)
-    y = np.asarray(rs.randint(0, 4, (8,)), np.int32)
-    cost = step.cost_analysis(x, y)
-    assert cost is not None and cost["flops"] > 0 \
-        and cost["bytes"] > 0
-    assert step._perf_clock is not None       # auto-armed
-    for _ in range(4):
-        loss = step(x, y)
-    assert np.isfinite(float(loss))
-    assert tel.snapshot()["gauges"]["train_mfu"] > 0
-
-
 # ------------------------------------------------ perf_report views
 def test_module_perf_report_tables():
     data = symmod.Variable("data")
@@ -269,62 +191,6 @@ def test_module_perf_report_tables():
         assert xla["rel_delta"] <= 0.10
 
 
-def test_serving_engine_perf_report_and_gauges(monkeypatch):
-    monkeypatch.setenv("MXTPU_PERF_INTERVAL", "2")
-    from incubator_mxnet_tpu.gluon.model_zoo.transformer import \
-        TransformerLM
-    from incubator_mxnet_tpu.serving.engine import ServingEngine
-    mx.random.seed(0)
-    net = TransformerLM(64, d_model=32, n_layers=2, n_heads=4,
-                        max_len=32)
-    net.initialize(mx.initializer.Xavier())
-    net(mx.nd.array(np.zeros((1, 4), "int32")))
-    eng = ServingEngine(net, max_batch=2, block_size=8,
-                        num_blocks=16)
-    rs = np.random.RandomState(0)
-    for _ in range(3):
-        eng.submit([int(t) for t in rs.randint(1, 64, 5)],
-                   max_new_tokens=6)
-    events = list(eng.stream())
-    assert len(events) == 3 * 6
-    gauges = tel.snapshot()["gauges"]
-    assert gauges["serving_mfu"] > 0
-    assert gauges["serving_flops_per_token"] > 0
-    rep = eng.perf_report()
-    assert rep["flops_per_token"] > 0
-    assert rep["per_family"], "empty decode per-family table"
-    fams = {r["family"] for r in rep["per_family"]}
-    assert "matmul" in fams and "attention" in fams
-    assert rep["roofline"]["bound"] in (
-        "compute", "memory", "balanced")
-
-
-# ------------------------------------------- launch.py fleet view
-def test_launch_fleet_mfu_aggregation():
-    launch = _load_tool("launch")
-    snaps = {
-        0: {"counters": {"train_steps_total": 10},
-            "gauges": {"train_mfu": 0.5}, "histograms": {}},
-        1: {"counters": {"train_steps_total": 10},
-            "gauges": {"train_mfu": 0.3}, "histograms": {}},
-        2: {"counters": {}, "gauges": {"serving_mfu": 0.4},
-            "histograms": {}},
-    }
-    agg = launch._aggregate_telemetry(snaps)
-    assert agg["mfu"] == pytest.approx((0.5 + 0.3 + 0.4) / 3)
-    assert agg["mfu_slowest"] == (1, 0.3)
-    status = launch._format_status(agg)
-    assert "mfu: 40.0%" in status
-    assert "slowest rank 1 at 30.0%" in status
-    report = launch._format_report(snaps)
-    assert "mfu=50.0%" in report and "mfu=30.0%" in report
-    # no rank publishing MFU -> the part is absent, not 0%
-    agg0 = launch._aggregate_telemetry(
-        {0: {"counters": {}, "gauges": {}, "histograms": {}}})
-    assert agg0["mfu"] is None
-    assert "mfu" not in launch._format_status(agg0)
-
-
 # ------------------------------------------------- op-cost coverage
 def test_cost_model_covers_entire_op_registry():
     names = {op.name for op in op_registry.OPS.values()}
@@ -337,69 +203,3 @@ def test_cost_model_covers_entire_op_registry():
     finally:
         sys.path.pop(0)
     assert hasattr(lint, "check_op_cost_coverage")
-
-
-# ------------------------------------------------------ bench_gate
-def _rec(metric, value, rnd, hib=True):
-    return {"schema": "bench-v1", "round": rnd, "metric": metric,
-            "value": value, "unit": "u", "higher_is_better": hib}
-
-
-def test_bench_gate_catches_injected_regression():
-    bg = _load_tool("bench_gate")
-    history = [_rec("tok_s", 100.0, 1), _rec("tok_s", 110.0, 2),
-               _rec("p99_s", 1.0, 1, hib=False)]
-    # 20% below best-so-far (110) with a 10% band -> regression
-    failures, checked = bg.gate([_rec("tok_s", 88.0, 3)], history,
-                                band=0.10)
-    assert checked == 1 and len(failures) == 1
-    assert failures[0]["metric"] == "tok_s"
-    assert failures[0]["limit"] == pytest.approx(99.0)
-    # within the band -> pass
-    failures, _ = bg.gate([_rec("tok_s", 100.0, 3)], history, 0.10)
-    assert failures == []
-    # lower-is-better: +20% past the ceiling fails, first-seen skips
-    failures, checked = bg.gate(
-        [_rec("p99_s", 1.2, 3, hib=False), _rec("new_metric", 1, 3)],
-        history, 0.10)
-    assert checked == 1 and len(failures) == 1
-    assert failures[0]["metric"] == "p99_s"
-
-
-def test_bench_gate_normalizes_heterogeneous_rounds():
-    bg = _load_tool("bench_gate")
-    doc = {"metric": "perf_report", "train": {"mfu": 0.4},
-           "serving": {"tokens_per_s": 50.0}}
-    recs = bg.normalize(doc, round_no=18)
-    assert {r["metric"] for r in recs} == \
-        {"perf_train_mfu", "perf_serving_tokens_per_s"}
-    assert all(r["schema"] == "bench-v1" and r["round"] == 18
-               for r in recs)
-    # r01-style driver envelopes unwrap; failed rounds -> no records
-    wrapped = {"n": 3, "rc": 0, "parsed": doc}
-    assert len(bg.normalize(wrapped)) == 2
-    assert bg.normalize({"n": 4, "rc": 1, "parsed": None}) == []
-    assert bg.normalize({"metric": "unknown_experiment"}) == []
-
-
-def test_bench_gate_real_history_passes_and_appends(tmp_path,
-                                                    capsys):
-    bg = _load_tool("bench_gate")
-    history = bg.load_history()
-    assert history, "committed BENCH history normalized to nothing"
-    traj = bg.trajectory_summary(history)
-    assert len(traj) >= 10
-    assert "serving_tokens_per_s" in traj
-    # the ci gate over the committed history passes
-    assert bg.main(["--check"]) == 0
-    out = capsys.readouterr().out
-    assert "bench_gate: OK" in out
-    # trajectory records append once (dedup on round+metric)
-    p = tmp_path / "PROGRESS.jsonl"
-    p.write_text('{"driver": "unrelated line"}\n')
-    n = bg.append_progress(history, str(p))
-    assert n == len(history)
-    assert bg.append_progress(history, str(p)) == 0
-    lines = [json.loads(x) for x in p.read_text().splitlines()]
-    assert sum(1 for d in lines
-               if d.get("schema") == "bench-v1") == len(history)

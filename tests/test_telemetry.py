@@ -786,3 +786,68 @@ def test_lint_metric_catalog_and_perf_counter_rules(tmp_path):
                  "    t0 = time.perf_counter()  # timing-ok: bench\n"
                  "    return t0\n")
     assert not any("perf_counter" in p for p in lint.check_file(g))
+
+
+# ------------------------------------- the program computes no MFU
+def test_the_program_publishes_no_utilisation_of_its_own():
+    """Utilisation is the benchmark's to measure, from a device trace
+    (docs/observability.md "Perf observatory").  An engine that has
+    stepped, a sharded step that has run twice and a Module that has
+    updated twice leave rates and counts in the registry and no
+    share of a peak."""
+    from incubator_mxnet_tpu import parallel
+    from incubator_mxnet_tpu.gluon.model_zoo.transformer import \
+        TransformerLM
+    from incubator_mxnet_tpu.serving import ServingEngine
+    mx.random.seed(0)
+    rs = np.random.RandomState(0)
+    lm = TransformerLM(64, d_model=32, n_layers=2, n_heads=4,
+                       max_len=32)
+    lm.initialize(mx.init.Xavier())
+    lm(mx.nd.array(np.zeros((1, 4), "int32")))
+    eng = ServingEngine(lm, max_batch=2, block_size=8, num_blocks=16)
+    eng.submit([int(t) for t in rs.randint(1, 64, 5)],
+               max_new_tokens=4)
+    assert len(list(eng.stream())) == 4
+
+    net = nn.HybridSequential()
+    net.add(nn.Dense(16, activation="relu"))
+    net.add(nn.Dense(4))
+    net.initialize(mx.init.Xavier())
+    step = parallel.ShardedTrainStep(
+        net, optimizer="sgd",
+        optimizer_params={"learning_rate": 0.01},
+        example_args=[mx.nd.zeros((2, 8))])
+    x = np.asarray(rs.rand(8, 8), np.float32)
+    y = np.asarray(rs.randint(0, 4, (8,)), np.int32)
+    for _ in range(2):
+        loss = step(x, y)
+    assert np.isfinite(float(loss))
+
+    data = mx.sym.Variable("data")
+    out = mx.sym.SoftmaxOutput(
+        mx.sym.FullyConnected(data, num_hidden=4, name="fc"),
+        name="softmax")
+    mod = mx.mod.Module(out, context=mx.cpu())
+    mod.bind(data_shapes=[("data", (8, 8))],
+             label_shapes=[("softmax_label", (8,))])
+    mod.init_params(initializer=mx.init.Xavier())
+    mod.init_optimizer(optimizer="sgd")
+    batch = mx.io.DataBatch(
+        data=[nd.array(x)],
+        label=[nd.array(y.astype("float32"))])
+    for _ in range(2):
+        mod.forward_backward(batch)
+        mod.update()
+
+    snap = tel.snapshot()
+    names = set(snap["counters"]) | set(snap["gauges"]) \
+        | set(snap["histograms"])
+    assert snap["counters"]["train_steps_total"] >= 2
+    assert "serving_batch_occupancy" in names
+    assert "span_train_step_seconds" in names
+    gone = {f"{side}_{what}" for side in ("serving", "train")
+            for what in ("mfu", "mbu", "flops_per_token",
+                         "tokens_per_sec")}
+    assert not names & gone, sorted(names & gone)
+    assert not [n for n in names if n.endswith(("_mfu", "_mbu"))]

@@ -29,8 +29,8 @@ from incubator_mxnet_tpu.gluon.model_zoo.transformer import \
 from incubator_mxnet_tpu.ops.flash import flash_attention
 from incubator_mxnet_tpu.perf import memory_planner as mp
 
-from test_memory_planner import GRAPH_INPUTS, _load_bench, \
-    _train_compiled
+import _graphs
+from test_memory_planner import GRAPH_INPUTS, _train_compiled
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -284,8 +284,7 @@ def test_planner_never_under_the_v5e_compiler(one_chip, graph, accum):
     HBM at all, and at the smoke's real sizes the plan is about twice
     its number (ResNet-50 B=32: 3.94 vs 1.48 GiB; the 150M LM step:
     13.47 vs 6.76 GiB — PERF.md, ISSUE 21; compiles, not chip runs)."""
-    bench = _load_bench()
-    s, shapes = getattr(bench, f"_graph_{graph}")(symmod)
+    s, shapes = getattr(_graphs, f"_graph_{graph}")(symmod)
     inputs = GRAPH_INPUTS[graph]
     compiled = _train_compiled(s, shapes, inputs, grad_accum=accum,
                                sharding=one_chip)

@@ -524,6 +524,34 @@ def test_serving_stats_ttft_decomposition():
     assert stats["trace_counts"].get("decode") == 1
 
 
+def test_serving_compiles_once_a_signature_each_with_its_reason():
+    """A stream of mixed lengths compiles the decode step and one
+    prefill a bucket, each once; every compile event of the engine's
+    site says why, and the same stream again compiles nothing."""
+    from incubator_mxnet_tpu.serving import ServingEngine
+    net = _tiny_lm()
+    eng = ServingEngine(net, max_batch=2, block_size=4,
+                        num_blocks=64)
+    site = f"serving_engine:{eng.engine_id}"
+    prompts = [[1, 2, 3], list(range(1, 7)), list(range(2, 14)),
+               [5, 6, 7, 8, 9]]
+
+    def stream():
+        for p in prompts:
+            eng.submit(p, 3)
+        eng.run()
+        return tracing.events("compile", site=site)
+
+    first = stream()
+    signatures = [json.dumps(e["signature"], sort_keys=True)
+                  for e in first]
+    assert len(first) == sum(eng.trace_counts.values()) >= 3
+    assert len(set(signatures)) == len(signatures)
+    assert first[0]["reason"] == "first_compile"
+    assert all(e["reason"] and e["seconds"] > 0 for e in first)
+    assert len(stream()) == len(first)
+
+
 def test_serving_disabled_telemetry_records_nothing(monkeypatch):
     from incubator_mxnet_tpu.serving import ServingEngine
     monkeypatch.setenv("MXTPU_TELEMETRY", "0")

@@ -388,8 +388,7 @@ def _aggregate_telemetry(snaps):
            "steps": {}, "straggler": None, "memory": {},
            "compiles": {}, "max_memory": None, "data_img_s": 0.0,
            "data_img_s_by_rank": {}, "serve_queue": 0,
-           "serve_queued_tokens": 0, "mfu_by_rank": {},
-           "mfu": None, "mfu_slowest": None,
+           "serve_queued_tokens": 0,
            "plan_delta": {}, "plan_delta_worst": None}
     for rank, snap in snaps.items():
         for name, v in (snap.get("counters") or {}).items():
@@ -407,14 +406,6 @@ def _aggregate_telemetry(snaps):
             gauges.get("serving_queue_depth", 0) or 0)
         agg["serve_queued_tokens"] += int(
             gauges.get("serving_queued_prompt_tokens", 0) or 0)
-        # perf observatory (docs/observability.md): each rank ships
-        # its model-FLOPs utilization in the heartbeat; the fleet
-        # view is the mean plus the slowest rank (MFU stragglers are
-        # invisible in step counts when steps are synchronized)
-        mfu = (gauges.get("train_mfu", 0.0)
-               or gauges.get("serving_mfu", 0.0) or 0.0)
-        if mfu > 0:
-            agg["mfu_by_rank"][rank] = mfu
         agg["steps"][rank] = (snap.get("counters") or {}).get(
             "train_steps_total", 0)
         mem = _rank_memory(snap)
@@ -441,11 +432,6 @@ def _aggregate_telemetry(snaps):
         worst = max(agg["plan_delta"],
                     key=lambda r: abs(agg["plan_delta"][r]))
         agg["plan_delta_worst"] = (worst, agg["plan_delta"][worst])
-    if agg["mfu_by_rank"]:
-        vals = agg["mfu_by_rank"]
-        agg["mfu"] = sum(vals.values()) / len(vals)
-        lo = min(vals, key=vals.get)
-        agg["mfu_slowest"] = (lo, vals[lo])
     return agg
 
 
@@ -468,12 +454,6 @@ def _format_status(agg):
     if agg.get("serve_queue", 0) > 0:
         parts.append(f"serve queue: {agg['serve_queue']} req "
                      f"({agg['serve_queued_tokens']} tok)")
-    if agg.get("mfu") is not None:
-        part = f"mfu: {agg['mfu'] * 100:.1f}%"
-        if len(agg["mfu_by_rank"]) > 1:
-            rank, lo = agg["mfu_slowest"]
-            part += f" (slowest rank {rank} at {lo * 100:.1f}%)"
-        parts.append(part)
     errs = [f"{n}={agg['counters'][n]}" for n in _ERROR_COUNTERS
             if agg["counters"].get(n)]
     if errs:
@@ -510,12 +490,10 @@ def _format_report(snaps):
         mem = agg["memory"].get(rank)
         compiles = agg["compiles"].get(rank)
         data_tp = agg["data_img_s_by_rank"].get(rank)
-        mfu = agg["mfu_by_rank"].get(rank)
         lines.append(
             f"launch.py:   rank {rank}: steps="
             f"{agg['steps'].get(rank, 0)}"
             + (f" {tp:.1f} samples/s" if tp else "")
-            + (f" mfu={mfu * 100:.1f}%" if mfu else "")
             + (f" data={data_tp:.0f} img/s" if data_tp else "")
             + (f" mem={_fmt_bytes(mem)}" if mem else "")
             + (f" compiles={compiles}" if compiles else ""))
@@ -700,8 +678,6 @@ def _fleet_status(snaps, healthy, n, rate_state):
     if agg.get("serve_queue", 0) > 0:
         parts.append(f"serve queue: {agg['serve_queue']} req "
                      f"({agg['serve_queued_tokens']} tok)")
-    if agg.get("mfu") is not None:
-        parts.append(f"mfu: {agg['mfu'] * 100:.1f}%")
     errs = [f"{nm}={agg['counters'][nm]}" for nm in _ERROR_COUNTERS
             if agg["counters"].get(nm)]
     if errs:
