@@ -338,3 +338,134 @@ def test_sliding_window_banded_grid_math():
     k = jnp.zeros((1, 128, 16), jnp.float32)
     with pytest.raises(ValueError, match="lq == lk"):
         flash_attention(q, k, k, causal=True, window=64)
+
+
+# bfloat16 keeps 8 significant bits, so one rounding moves a value by
+# at most 2^-9 of itself: the kernel's result and the reference's are
+# each rounded once, P and dS once more inside the kernel.  Four such
+# steps at the largest reference value.
+BF16_TOL = 2.0 ** -7
+
+
+# the rows ops.flash._tiles gives a length at its most (bfloat16, a
+# head of 16, no window): 1024 where it divides, else 512, 256, 128
+TILE_OF = {128: 128, 384: 128, 640: 128, 768: 256, 1024: 1024,
+           1536: 512, 2048: 1024}
+
+
+@pytest.mark.parametrize("mode", ["causal", "cross", "window256"])
+@pytest.mark.parametrize("l", sorted(TILE_OF))
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_every_tile_size_matches_reference(dtype, l, mode):
+    """Output and the three gradients at every tile size the tile
+    function can choose and every fall-back to a smaller one — a
+    larger does not divide the length (TILE_OF), does not fit the
+    VMEM budget (float32) or is over twice the window — causal,
+    non-causal with lq != lk (key tiles of 256), and the band;
+    float32 operands at the float32 tolerances of the tests above,
+    bfloat16 operands at bfloat16's 8 bits."""
+    from incubator_mxnet_tpu.ops.flash import _tiles
+    causal = mode != "cross"
+    window = 256 if mode == "window256" else 0
+    lk = 256 if mode == "cross" else l
+    # float32 blocks of 1024 x 1024 are over the VMEM budget (of
+    # 1024 x 256 not), and a tile is no longer than twice the window
+    tile = min(TILE_OF[l],
+               512 if mode == "window256" or
+               (dtype, mode) == ("float32", "causal") else 1024)
+    assert _tiles(l, lk, 16, dtype, window) == (
+        tile, 256 if mode == "cross" else tile)
+    rs = np.random.RandomState(l)
+    q, g = (jnp.asarray(rs.normal(0, 1, (1, l, 16)), dtype)
+            for _ in range(2))
+    k, v = (jnp.asarray(rs.normal(0, 1, (1, lk, 16)), dtype)
+            for _ in range(2))
+    out, vjp = jax.vjp(
+        lambda a, b, c: flash_attention(a, b, c, causal=causal,
+                                        interpret=True, window=window),
+        q, k, v)
+    ref, ref_vjp = jax.vjp(
+        lambda a, b, c: _reference_attention(a, b, c, causal, 0.25,
+                                             window=window), q, k, v)
+    pairs = zip(("out", "dq", "dk", "dv"), (out,) + vjp(g),
+                (ref,) + ref_vjp(g))
+    for name, got, want in pairs:
+        assert got.dtype == want.dtype == jnp.dtype(dtype), name
+        got = np.asarray(got, np.float32)
+        want = np.asarray(want, np.float32)
+        if dtype == "float32":
+            tol = 2e-5 if name == "out" else 1e-3
+            np.testing.assert_allclose(got, want, rtol=tol, atol=tol,
+                                       err_msg=name)
+        else:
+            err = np.abs(got - want).max() / np.abs(want).max()
+            assert err <= BF16_TOL, (name, err)
+
+
+def _walk(index_map, index, n_res, nj):
+    """resident tile -> the streamed tiles of its live steps, in
+    order; a step that is not live has to name the tile of the step
+    before it (no DMA), and the index_map the tile ``index`` gives."""
+    live_steps = {}
+    for i in range(n_res):
+        before = None
+        for j in range(nj):
+            tile, live = (int(t) for t in index(i, j))
+            assert int(index_map(0, i, j)[1]) == tile
+            if live:
+                live_steps.setdefault(i, []).append(tile)
+            else:
+                assert tile == before, (i, j, tile, before)
+            before = tile
+    return live_steps
+
+
+def test_the_cells_call_in_grid_steps():
+    """What needs no chip of the train cell's call, (64, 2048, 64)
+    bfloat16 causal: the tiles, the grid's length, and — by walking
+    the index maps — that a step past a tile's last live block names
+    the block before it (the pipeline fetches nothing for it) and is
+    not live, for the plain triangle as
+    test_sliding_window_banded_grid_math has it for the band."""
+    from incubator_mxnet_tpu.ops.flash import (_band_k_index,
+                                               _band_q_index, _k_grid,
+                                               _on_edge, _q_grid,
+                                               _tiles)
+    bh, d = 64, 64
+    for l, tiles, steps in ((2048, (1024, 1024), 256),
+                            (1536, (512, 512), 576)):
+        bq, bk = _tiles(l, l, d, jnp.bfloat16, 0)
+        assert (bq, bk) == tiles
+        nq, nk = l // bq, l // bk
+        nj_k, kmap = _k_grid(True, 0, bq, bk, nk)
+        nj_q, qmap = _q_grid(True, 0, bq, bk, nq)
+        # the cell: 256 grid steps a kernel where tiles of 128 made
+        # 16,384
+        assert bh * nq * nj_k == bh * nk * nj_q == steps
+        # forward and dq: q-tile i sees k-tiles 0..i, in order
+        assert _walk(
+            kmap, lambda i, j: _band_k_index(i, j, bq, bk, nk, 0),
+            nq, nj_k) == {i: list(range(i + 1)) for i in range(nq)}
+        # dk/dv: k-tile jk is seen by q-tiles jk..nq-1
+        assert _walk(
+            qmap, lambda i, j: _band_q_index(i, j, bq, bk, nq, 0),
+            nk, nj_q) == {i: list(range(i, nq)) for i in range(nk)}
+        # the mask on the diagonal's blocks alone
+        assert [[bool(_on_edge(i, jk, bq, bk, 0))
+                 for jk in range(i + 1)] for i in range(nq)] == [
+                     [False] * i + [True] for i in range(nq)]
+    # the band's lower edge cuts too: window 256 reaches one tile back
+    assert bool(_on_edge(2, 1, 512, 512, 256))
+    assert not bool(_on_edge(2, 1, 512, 512, 1024))
+    # a length that a size does not divide falls to the next one
+    assert _tiles(768, 768, d, jnp.bfloat16, 0) == (256, 256)
+    assert _tiles(640, 2048, d, jnp.bfloat16, 0) == (128, 1024)
+    assert _tiles(64, 64, d, jnp.bfloat16, 0) == (64, 64)
+    # operands too wide for the VMEM budget at 1024 rows take fewer
+    assert _tiles(2048, 2048, 128, jnp.bfloat16, 0) == (1024, 1024)
+    assert _tiles(2048, 2048, d, jnp.float32, 0) == (512, 512)
+    assert _tiles(2048, 2048, 512, jnp.float32, 0) == (256, 256)
+    # and under a window a tile is no longer than twice the window
+    assert _tiles(4096, 4096, d, jnp.bfloat16, 256) == (512, 512)
+    assert _tiles(4096, 4096, d, jnp.bfloat16, 64) == (128, 128)
+    assert _tiles(4096, 4096, d, jnp.bfloat16, 4096) == (1024, 1024)

@@ -68,13 +68,17 @@ def _attention_loss(window):
 
 
 # (BH, L, d), window, dtype: the smoke's kernel comparison and LM step
-# (128, 1024, 64), a long context, the banded grid, wide heads, f32
+# (128, 1024, 64), a long context, the banded grid, wide heads, f32,
+# and the train cell's call with its float32 twin (ops.flash._tiles:
+# 1024 x 1024 for bfloat16, 512 x 512 for float32 and for the band)
 FLASH_CASES = [
     pytest.param((128, 1024, 64), 0, "bfloat16", id="1024x64"),
     pytest.param((128, 4096, 64), 0, "bfloat16", id="4096x64"),
     pytest.param((128, 1024, 64), 256, "bfloat16", id="1024x64-w256"),
     pytest.param((32, 1024, 128), 0, "bfloat16", id="1024x128"),
     pytest.param((128, 1024, 64), 0, "float32", id="1024x64-f32"),
+    pytest.param((64, 2048, 64), 0, "bfloat16", id="2048x64-cell"),
+    pytest.param((64, 2048, 64), 0, "float32", id="2048x64-cell-f32"),
 ]
 
 
