@@ -72,11 +72,14 @@ def _rope(x, positions, inv_freq):
 
 # ------------------------------------------------------------- caches
 # What a cache hands the layer: ``context(li, rows)`` takes the rows
-# this call adds to layer ``li`` and gives back every row the queries
-# may see, (C, r) for one sequence or (T, C, r) with a sequence a
-# query; ``absorbed`` picks the attention path.  Row c of a context IS
-# absolute position c.  A context's rows may be wider than the rows
-# handed in (a pool's rows are whole lanes): the rest is zeros.
+# this call adds to layer ``li`` and gives back what the queries may
+# see; ``absorbed`` picks the attention path.  For the expanded path
+# that is every such row, (C, r): row c of a context IS absolute
+# position c.  For the absorbed path (one query a sequence) it is the
+# pool as it came in, the tables that say each sequence's blocks, and
+# the rows this call adds, which lie at the sequences' positions.  A
+# pool's rows may be wider than the rows handed in (whole lanes): the
+# rest is zeros.
 
 
 class _NoCache:
@@ -91,7 +94,9 @@ class _PagedCache:
     """The engine's latent pool, through block tables.  ``blk`` /
     ``off`` say where each row of this call is written; ``tables`` is
     one row of block ids (a prefill) or one a slot (a decode step,
-    ``absorbed``).  ``pools`` ends as the updated pools."""
+    ``absorbed``).  ``pools`` ends as the updated pools.  A decode step
+    reads the pool as it came in, so its write has no reader in the
+    program and stays in place in the donated pool."""
 
     def __init__(self, pools, tables, blk, off, absorbed):
         self.pools = list(pools)
@@ -103,9 +108,11 @@ class _PagedCache:
         pool = self.pools[li]
         rows = jnp.pad(rows.astype(pool.dtype), (
             (0, 0), (0, pool.shape[-1] - rows.shape[-1])))
-        pool = self.pools[li] = pool.at[self.blk, self.off].set(rows)
-        got = pool[self.tables]            # (..., MB, bs, r)
-        return got.reshape(got.shape[:-3] + (-1, got.shape[-1]))
+        self.pools[li] = pool.at[self.blk, self.off].set(rows)
+        if self.absorbed:
+            return pool, self.tables, rows
+        got = self.pools[li][self.tables]  # (MB, bs, r)
+        return got.reshape(-1, got.shape[-1])
 
 
 class LatentMoELM(Block):
@@ -301,30 +308,30 @@ class LatentMoELM(Block):
         return out.reshape((-1,) + out.shape[2:])[:t]
 
     def _absorbed(self, q_nope, q_rope, positions, ctx, kv_b):
-        """One query a sequence, each against its own context (T, C,
-        r): the key's expansion moves onto the query, the value's
-        behind the weighted latents.  Scores and weighted rows run
-        over the whole cached row (latent and rope lanes at once), so
-        the context is read as it was gathered and never sliced."""
-        import jax
+        """One query a sequence at ``positions``, each over its cached
+        rows ``0 .. positions - 1`` and its own new row: ``ctx`` is the
+        pool as it came in, the tables, and the new rows
+        (``ops.paged_attention.decode_attention``, which reads a
+        sequence's blocks through its table as far as it is live, one
+        pool for keys and values).  The key's expansion moves onto the
+        query, the value's behind the weighted latents.  Scores and
+        weighted rows run over the whole cached row (latent and rope
+        lanes at once), so a row is read as the pool holds it."""
         import jax.numpy as jnp
-        f32 = jnp.float32
+        from ...ops.paged_attention import decode_attention
+        pool, tables, rows = ctx
         t, heads = q_nope.shape[0], self.n_heads
         rank, d_nope, d_rope = self.rank, self.d_nope, self.d_rope
+        lanes = pool.shape[-1]
         q_row = jnp.concatenate(
             [jnp.einsum("thn,hnc->thc", q_nope, kv_b[:, :d_nope]),
-             q_rope, jnp.zeros((t, heads, ctx.shape[-1] - rank
-                                - d_rope), q_nope.dtype)], axis=-1)
-        seen = jnp.arange(ctx.shape[-2])[None, :] <= positions[:, None]
-        scores = jnp.einsum("thr,tkr->thk", q_row, ctx,
-                            preferred_element_type=f32)
-        p = jax.nn.softmax(jnp.where(
-            seen[:, None, :], scores * self.softmax_scale,
-            -jnp.inf), axis=-1).astype(q_nope.dtype)
-        o_lat = jnp.einsum("thk,tkr->thr", p, ctx,
-                           preferred_element_type=f32) \
-            .astype(q_nope.dtype)[..., :rank]
-        return jnp.einsum("thc,hvc->thv", o_lat, kv_b[:, d_nope:])
+             q_rope, jnp.zeros((t, heads, lanes - rank - d_rope),
+                               q_nope.dtype)], axis=-1)
+        o = decode_attention(q_row, rows, rows, pool, pool, tables,
+                             positions, scale=self.softmax_scale)
+        o_lat = o.reshape(t, heads, lanes).astype(q_nope.dtype)
+        return jnp.einsum("thc,hvc->thv", o_lat[..., :rank],
+                          kv_b[:, d_nope:])
 
     def _attend(self, lw, h, positions, cache, li, last):
         """Latent attention of rows ``h`` (T, d) at ``positions``."""
@@ -449,6 +456,20 @@ class LatentMoELM(Block):
                  "dtype": str(self.embed_weight.dtype),
                  "values": values},)
 
+    def _paged_read(self, block_size, platform):
+        """Which read of the pool the decode step takes where it is
+        traced now and lowered for ``platform``, with the shapes that
+        decide it (``ops.paged_attention.read_kind``: every head's
+        absorbed query against the one pool's whole row, one kv head):
+        what the engine's ``serve_paged_read`` event carries."""
+        from ...ops.paged_attention import read_kind
+        cache, = self._paged_cache()
+        row, = cache["shape"]
+        return dict(read=read_kind(self.n_heads, 1, row, int(block_size),
+                                   cache["dtype"], platform),
+                    heads=self.n_heads, kv_heads=1, head_dim=row,
+                    dtype=cache["dtype"])
+
     def _decode_weights(self):
         def w(param):
             return param.data()._data
@@ -489,9 +510,14 @@ class LatentMoELM(Block):
     def _build_paged_step(self, max_batch, max_blocks, block_size):
         """``step(wts, pool, tables, n_past, tokens) -> (pool, next,
         logits)``: every slot's newest token at its own position
-        through the absorbed path.  A slot with nothing cached is
-        idle: it writes to the scratch block and is routed to no
-        expert.  ``next`` is a token a slot, then the statistics."""
+        through the absorbed path, which reads each slot's blocks
+        through its table row as far as the slot is live where it is
+        lowered for a TPU and the shapes tile (``_paged_read`` says
+        which), and gathers the row's ``max_blocks * block_size``
+        positions anywhere else.  A slot with nothing cached is idle:
+        it writes to the scratch block, reads no block and is routed
+        to no expert.  ``next`` is a token a slot, then the
+        statistics."""
         import jax.numpy as jnp
         bs = int(block_size)
 

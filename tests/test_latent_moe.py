@@ -5,7 +5,9 @@ small size on the CPU: the eager forward, prefill then decode through
 the engine's generalised protocol.  Weights are seeded
 (benchmark/weights.py, the harness's own path); each tolerance says
 why."""
+import functools
 import os
+import re
 import sys
 
 import numpy as np
@@ -21,10 +23,11 @@ import incubator_mxnet_tpu as mx  # noqa: E402
 from benchmark import train, weights  # noqa: E402
 from benchmark.models import latent_moe_lm as fam  # noqa: E402
 from benchmark.reference import latent_moe as ref  # noqa: E402
-from incubator_mxnet_tpu import telemetry  # noqa: E402
+from incubator_mxnet_tpu import telemetry, tracing  # noqa: E402
 from incubator_mxnet_tpu.gluon.model_zoo.transformer import \
     TransformerLM  # noqa: E402
 from incubator_mxnet_tpu.ops import moe  # noqa: E402
+from incubator_mxnet_tpu.ops import paged_attention as pa  # noqa: E402
 from incubator_mxnet_tpu.ops.moe import (  # noqa: E402
     gated_ffn, route_top_k, routed_ffn_fn)
 from incubator_mxnet_tpu.serving import ServingEngine  # noqa: E402
@@ -148,7 +151,18 @@ def test_prefill_then_decode_through_the_latent_pool(built):
             for a in pool] == [((4, 128), dtype)] * 3   # whole lanes
     assert eng.cache_spec[0]["values"] == 24            # 16 + 8
     prompts = [t[:n] for t, n in zip(_tokens(3, 50, 1), (50, 23, 37))]
+    before = len(tracing.events("serve_paged_read"))
     reqs, got = _served_logits(eng, prompts, 6)
+    # the engine says which read of the pool it built, and on what
+    assert [(e["read"], e["platform"], e["kv_heads"], e["head_dim"],
+             e["dtype"]) for e in tracing.events("serve_paged_read")[
+                 before:]] == [("plain", "cpu", 1, 128, dtype)]
+    # the parent commit's tokens (5e547c0, whose decode step gathered
+    # every slot's table row from the pool it had just written), on
+    # the CPU, at both dtypes
+    assert [r.generated for r in reqs] == [
+        [67, 41, 79, 89, 28, 75], [41, 0, 92, 30, 24, 63],
+        [29, 31, 15, 92, 30, 53]]
     for r, prompt in zip(reqs, prompts):
         assert r.state == "finished" and len(r.generated) == 6
         seq = np.concatenate([prompt, r.generated])[None]
@@ -430,7 +444,86 @@ def test_a_prefix_hit_reads_latent_blocks(f32):
     assert req.generated == plain.generated
 
 
+def _eight_heads(dtype, seed=14):
+    """The model at 8 heads (heads in whole sublanes, so a decode step
+    lowered for a TPU reads through the paged kernel) and leaves of
+    its parameters' shapes drawn as the harness draws them (matrices
+    0.02 a standard normal, gains 1 + 0.1 of one): nothing is
+    initialized."""
+    from incubator_mxnet_tpu.gluon.model_zoo.latent_moe import \
+        LatentMoELM
+    lm = LatentMoELM(dict(CFG, num_attention_heads=8))
+    rs = np.random.RandomState(seed)
+
+    def leaf(name, param):
+        base = 1.0 if "norm" in name else 0.0
+        spread = 0.1 if "norm" in name else 0.02
+        return jnp.asarray(base + spread * rs.randn(*param.shape), dtype)
+
+    wts = {"embed": leaf("embed", lm.embed_weight),
+           "norm": leaf("norm", lm.norm),
+           "head": leaf("head", lm.head_weight),
+           "layers": [{k: leaf(k, p) for k, p in lw.items()}
+                      for lw in lm.layers]}
+    return lm, wts
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_decode_step_through_the_kernel_is_the_gathers(dtype,
+                                                           monkeypatch):
+    """The absorbed decode step with the paged kernel (interpreted) in
+    the place of the gather the CPU takes, over a pool of random rows
+    and six slots (idle, one row, a block's last and first row, past
+    two blocks, the whole table row): the first layer's rows are
+    written alike to the bit, the logits agree to the bfloat16
+    tolerance.  Not to float32's: the kernel's products take bfloat16
+    operands at any pool dtype (what a TPU's default precision makes
+    of a float32 product), which moves a float32 step by about 2e-3
+    of the logits' spread."""
+    lm, wts = _eight_heads(dtype)
+    bs, mb = 16, 6
+    assert lm._paged_read(bs, "tpu")["read"] == "kernel"
+    assert lm._paged_read(bs, "cpu")["read"] == "plain"
+    rs = np.random.RandomState(15)
+    n_past = np.array([0, 1, bs - 1, bs, 2 * bs + 3, mb * bs - 1],
+                      np.int32)
+    ids = rs.permutation(np.arange(1, 64))
+    tables = np.zeros((len(n_past), mb), np.int32)
+    at = 0
+    for i, n in enumerate(n_past):
+        tables[i, :n // bs + 1] = ids[at:at + n // bs + 1]
+        at += n // bs + 1
+    pools = [jnp.asarray(rs.randn(64, bs, 128), dtype)
+             for _ in lm.layers]
+    args = (wts, pools, jnp.asarray(tables), jnp.asarray(n_past),
+            jnp.asarray(rs.randint(0, CFG["vocab_size"], len(n_past)),
+                        jnp.int32))
+
+    def run():
+        step = lm._build_paged_step(len(n_past), mb, bs)
+        return jax.jit(step)(*args)
+
+    pools_p, _, logits_p = run()
+    monkeypatch.setattr(pa, "decode_attention", functools.partial(
+        pa.kernel_read, interpret=True))
+    pools_k, _, logits_k = run()
+    live = n_past > 0
+    got, want = np.asarray(logits_k)[live], np.asarray(logits_p)[live]
+    print(dtype, _gap(got, want), _gap(got, want, np.median))
+    assert 0 < _gap(got, want) and \
+        _gap(got, want, OVER["bfloat16"]) < TOL["bfloat16"]
+    np.testing.assert_array_equal(pools_k[0], pools_p[0])
+    for a, b in zip(pools_k[1:], pools_p[1:]):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), atol=0.1)
+
+
 def test_max_len_bounds_what_the_decode_program_gathers(f32):
+    """``max_len`` bounds a table row, and so what the decode step
+    reads: in blocks of 4 (no kernel tiles them) the step gathers a
+    slot's 48 positions and nothing of the model's own 4096; in blocks
+    of 8 lowered for a TPU it holds no slot's context at all, only the
+    table row of 6 blocks that the kernel walks."""
     block, _ = f32
     eng = ServingEngine(block, max_batch=2, block_size=4,
                         num_blocks=64, max_len=48)
@@ -440,8 +533,19 @@ def test_max_len_bounds_what_the_decode_program_gathers(f32):
     text = str(jax.make_jaxpr(step)(
         eng._wts, eng._pools[0], tables, jnp.ones(2, jnp.int32),
         jnp.zeros(2, jnp.int32)))
-    assert "2,48,128]" in text.replace(" ", "")   # a slot's context
+    assert "2,48,1,128]" in text.replace(" ", "")  # a slot's context
     assert "4096" not in text
+    wide, wts = _eight_heads("float32")
+    assert ServingEngine(block, max_batch=2, block_size=8, num_blocks=64,
+                         max_len=48).max_blocks == 6
+    tpu = jax.jit(wide._build_paged_step(2, 6, 8)).trace(
+        wts, [jnp.zeros((64, 8, 128))] * len(wide.layers),
+        jnp.zeros((2, 6), jnp.int32), jnp.ones(2, jnp.int32),
+        jnp.zeros(2, jnp.int32)).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert "paged_decode_attention" in tpu and "tensor<2x6xi32>" in tpu
+    assert not re.search(r"tensor<2x(48|6x8)x", tpu)
+    assert "4096" not in tpu
     with pytest.raises(mx.serving.RequestTooLargeError):
         eng.submit(list(range(40)), 9)
     assert eng.submit(list(range(40)), 8).id == 0

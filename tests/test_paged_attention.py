@@ -29,25 +29,31 @@ def _bf16(x):
     return x.astype(jnp.bfloat16).astype(jnp.float32)
 
 
-def _case(heads, kv_heads, head_dim, n_past, seed=0, blocks=64):
+def _case(heads, kv_heads, head_dim, n_past, seed=0, blocks=64,
+          block=BS, dtype="float32", shared=False):
     """Random operands; every slot's row is a scrambled draw of block
-    ids that covers positions 0 .. n_past, scratch (0) behind it."""
+    ids that covers positions 0 .. n_past, scratch (0) behind it.
+    ``shared``: one pool for keys and values, the step's own row the
+    same for both."""
     rng = np.random.RandomState(seed)
     b, row = len(n_past), kv_heads * head_dim
 
     def draw(*shape):
-        return jnp.asarray(rng.randn(*shape), jnp.float32)
+        return jnp.asarray(rng.randn(*shape), dtype)
 
     ids = rng.permutation(np.arange(1, blocks))
     tables = np.zeros((b, MB), np.int32)
     at = 0
     for i, n in enumerate(n_past):
-        held = n // BS + 1
+        held = n // block + 1
         tables[i, :held] = ids[at:at + held]
         at += held
-    return (draw(b, heads, head_dim), draw(b, row), draw(b, row),
-            draw(blocks, BS, row), draw(blocks, BS, row),
-            jnp.asarray(tables), jnp.asarray(n_past, jnp.int32))
+    q, kn, vn = draw(b, heads, head_dim), draw(b, row), draw(b, row)
+    kp, vp = draw(blocks, block, row), draw(blocks, block, row)
+    if shared:
+        vn, vp = kn, kp
+    return (q, kn, vn, kp, vp, jnp.asarray(tables),
+            jnp.asarray(n_past, jnp.int32))
 
 
 # an inactive slot, one cached row, a block's last row, a block's
@@ -55,39 +61,76 @@ def _case(heads, kv_heads, head_dim, n_past, seed=0, blocks=64):
 RAGGED = (0, 1, BS - 1, BS, 2 * BS + 3, MB * BS - 1)
 
 
-@pytest.mark.parametrize("heads,kv_heads,head_dim", [
-    pytest.param(8, 8, 16, id="mha"),
-    pytest.param(8, 2, 64, id="gqa"),
-    pytest.param(16, 4, 32, id="gqa-4x"),
+@pytest.mark.parametrize("heads,kv_heads,head_dim,block,dtype,shared,scale", [
+    pytest.param(8, 8, 16, BS, "float32", False, None, id="mha"),
+    pytest.param(8, 2, 64, BS, "float32", False, None, id="gqa"),
+    pytest.param(16, 4, 32, BS, "float32", False, None, id="gqa-4x"),
+    pytest.param(8, 8, 16, BS, "float32", True, None, id="one-pool"),
+    pytest.param(8, 2, 64, 16, "bfloat16", False, None, id="bfloat16"),
+    pytest.param(16, 1, 384, BS, "float32", False, None,
+                 id="one-kv-head"),
+    pytest.param(8, 2, 64, BS, "float32", False, 0.3, id="scale"),
+    pytest.param(32, 1, 640, 16, "bfloat16", True, 192 ** -0.5,
+                 id="latent"),
 ])
 def test_kernel_reads_what_the_plain_read_reads(heads, kv_heads,
-                                                head_dim):
-    assert pa.read_kind(heads, kv_heads, head_dim, BS,
-                        "float32") == "kernel"
-    args = _case(heads, kv_heads, head_dim, RAGGED)
-    got = pa.kernel_read(*args, interpret=True)
+                                                head_dim, block, dtype,
+                                                shared, scale):
+    """Grouped heads, one pool for keys and values, a bfloat16 pool in
+    whole 16-row tiles, one kv head under a row of several lanes (the
+    query as it comes), the caller's scale: one kernel."""
+    assert pa.read_kind(heads, kv_heads, head_dim, block,
+                        dtype) == "kernel"
+    n_past = (0, 1, block - 1, block, 2 * block + 3, MB * block - 1)
+    args = _case(heads, kv_heads, head_dim, n_past, block=block,
+                 dtype=dtype, shared=shared)
+    got = pa.kernel_read(*args, scale=scale, interpret=True)
     q, kn, vn, kp, vp, tables, n = args
     # the kernel's products take bfloat16 operands and add in float32
     # (a TPU's default precision); the plain read on the CPU is exact,
     # so it is given the rounded operands.  What is left is the
     # rounding of the softmax's weights before the second product
     want = pa.plain_read(_bf16(q), _bf16(kn), _bf16(vn), _bf16(kp),
-                         _bf16(vp), tables, n)
+                         vp if shared else _bf16(vp), tables, n, scale)
     np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
-    exact = pa.plain_read(*args)
+    exact = pa.plain_read(*args, scale)
     np.testing.assert_allclose(got, exact, rtol=5e-2, atol=5e-2)
     # the inactive slot attends to its own row alone
     np.testing.assert_allclose(
         got[0].reshape(heads, head_dim),
-        jnp.repeat(vn[0].reshape(kv_heads, head_dim),
+        jnp.repeat(vn[0].astype(jnp.float32).reshape(kv_heads, head_dim),
                    heads // kv_heads, axis=0), rtol=0, atol=1e-6)
+    if shared:
+        # a block copied once and read by both products: the numbers
+        # of two pools that hold the same rows
+        assert vp is kp and vn is kn
+        np.testing.assert_array_equal(
+            got, pa.kernel_read(q, kn, kn + 0, kp, kp + 0, tables, n,
+                                scale=scale, interpret=True))
+    if scale is not None:
+        assert float(jnp.max(jnp.abs(
+            got - pa.kernel_read(*args, interpret=True)))) > 1e-2
 
 
-@pytest.mark.parametrize("read,garbage", [("kernel", np.nan),
-                                          ("plain", 1e30)])
-def test_nothing_behind_n_past_reaches_the_output(read, garbage):
-    n_past = (0, 1, BS - 1, BS, 2 * BS + 3, 3 * BS)
-    q, kn, vn, kp, vp, tables, n = _case(8, 8, 16, n_past, seed=1)
+@pytest.mark.parametrize("read,garbage,dtype,shared", [
+    pytest.param("kernel", np.nan, "float32", False, id="kernel-nan"),
+    pytest.param("plain", 1e30, "float32", False, id="plain-1e+30"),
+    pytest.param("kernel", np.nan, "float32", True,
+                 id="kernel-nan-one-pool"),
+    pytest.param("plain", 1e30, "float32", True,
+                 id="plain-1e+30-one-pool"),
+    pytest.param("kernel", np.nan, "bfloat16", True,
+                 id="kernel-nan-one-pool-bfloat16"),
+    pytest.param("plain", 1e30, "bfloat16", True,
+                 id="plain-1e+30-one-pool-bfloat16"),
+])
+def test_nothing_behind_n_past_reaches_the_output(read, garbage, dtype,
+                                                  shared):
+    block = 16 if dtype == "bfloat16" else BS
+    n_past = (0, 1, block - 1, block, 2 * block + 3, 3 * block)
+    q, kn, vn, kp, vp, tables, n = _case(8, 8, 16, n_past, seed=1,
+                                         block=block, dtype=dtype,
+                                         shared=shared)
     fn = functools.partial(pa.kernel_read, interpret=True) \
         if read == "kernel" else pa.plain_read
     clean = fn(q, kn, vn, kp, vp, tables, n)
@@ -95,13 +138,16 @@ def test_nothing_behind_n_past_reaches_the_output(read, garbage):
     # behind n_past in a slot's last block (the row at n_past is the
     # step's own, not yet in the pool), every block no slot holds,
     # scratch.  The kernel leaves such rows out (NaN goes nowhere);
-    # the plain read gives them the weight 0, as it always has
+    # the plain read gives them the weight 0, as it always has.  In
+    # one pool for keys and values they are neither
     dead = np.ones(kp.shape[:2], bool)
     for row, upto in zip(np.asarray(tables), n_past):
         for pos in range(upto):
-            dead[row[pos // BS], pos % BS] = False
+            dead[row[pos // block], pos % block] = False
     poison = jnp.where(jnp.asarray(dead)[:, :, None], garbage, 0.0)
-    dirty = fn(q, kn, vn, kp + poison, vp + poison, tables, n)
+    kd = (kp + poison).astype(dtype)
+    vd = kd if shared else (vp + poison).astype(dtype)
+    dirty = fn(q, kn, vn, kd, vd, tables, n)
     assert np.isfinite(np.asarray(dirty)).all()
     np.testing.assert_array_equal(np.asarray(dirty), np.asarray(clean))
 
@@ -131,9 +177,12 @@ def test_table_order_is_the_context_order():
     (4, 4, 8, 4, "float32", "plain"),           # a row of 32 lanes
     (4, 2, 64, 8, "float32", "plain"),          # heads no whole tile
     (8, 8, 16, 4, "float32", "plain"),          # a block of 4 rows
-    (8, 8, 16, 16, "bfloat16", "plain"),        # no model states one
+    (8, 8, 16, 16, "bfloat16", "kernel"),       # whole 16-row tiles
     (8, 8, 16, 8, "int8", "plain"),
     (64, 64, 128, 16, "float32", "plain"),      # (64, 8192) no VMEM
+    (8, 8, 16, 8, "bfloat16", "plain"),         # half a bfloat16 tile
+    (32, 1, 640, 16, "bfloat16", "kernel"),     # the latent cell
+    (32, 1, 640, 8, "bfloat16", "plain"),
 ])
 def test_shapes_decide_the_read(heads, kv_heads, head_dim, block,
                                 dtype, kind):

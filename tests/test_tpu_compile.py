@@ -252,6 +252,72 @@ def test_paged_programs_copy_no_pool_on_v5e(opt_cell, program):
         assert memory.temp_size_in_bytes < 0.5e9
 
 
+@pytest.fixture(scope="module")
+def latent_cell(one_chip):
+    """``LatentMoELM`` at ``benchmark/configs/joyai-llm-flash.json``'s
+    widths with its leading dense layer and one routed layer, and the
+    engine's arguments at the cell's shapes (64 slots, 512 blocks of 16
+    a row, 19,521 blocks of 640 lanes, bfloat16), all abstract."""
+    import json
+
+    from incubator_mxnet_tpu.gluon.model_zoo.latent_moe import \
+        LatentMoELM
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "joyai-llm-flash.json")) as f:
+        cfg = dict(json.load(f), num_hidden_layers=2)
+    lm = LatentMoELM(cfg)
+    slots, row_blocks, block, blocks = 64, 512, 16, 19521
+
+    def leaf(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    wts = {"embed": leaf(*lm.embed_weight.shape),
+           "norm": leaf(*lm.norm.shape),
+           "head": leaf(*lm.head_weight.shape),
+           "layers": [{k: leaf(*p.shape) for k, p in lw.items()}
+                      for lw in lm.layers]}
+    spec, = lm._paged_cache()
+    pools = [leaf(blocks, block, *spec["shape"]) for _ in lm.layers]
+    ints = functools.partial(leaf, dtype=jnp.int32)
+    return dict(lm=lm, wts=wts, pools=pools, slots=slots,
+                context=row_blocks * block, lanes=spec["shape"][0],
+                decode=lm._build_paged_step(slots, row_blocks, block),
+                args=(ints(slots, row_blocks), ints(slots), ints(slots)))
+
+
+def test_latent_decode_reads_through_the_table_on_v5e(latent_cell):
+    """The latent cell's decode step as the engine jits it (the pool
+    donated), compiled for the chip: one paged read a layer, one pool
+    for keys and values; no instruction holds a slot's allowed context
+    (64 x 8192 rows of 640 lanes, 671 MB a layer, the parent's
+    gather); every pool comes out in the buffer it went in; the
+    temporaries stay under 0.3 GB (the parent's held the gathered
+    contexts: 1.34 GB of them for these two layers).  Counts of a
+    compile, no times."""
+    lm, pools = latent_cell["lm"], latent_cell["pools"]
+    assert lm._paged_read(16, "tpu")["read"] == "kernel"
+    compiled = jax.jit(latent_cell["decode"], donate_argnums=(1,)).lower(
+        latent_cell["wts"], pools, *latent_cell["args"]).compile()
+    text = compiled.as_text()
+    reads = [line for line in text.splitlines()
+             if "tpu_custom_call" in line
+             and "paged_decode_attention" in line]
+    assert len(reads) == len(pools), len(reads)
+    slots, lanes = latent_cell["slots"], latent_cell["lanes"]
+    context = latent_cell["context"]
+    held = [line.strip()[:120] for line in text.splitlines()
+            if re.search(rf"\[{slots},({context}|{context // 16},16),"
+                         rf"{lanes}\]", line)]
+    assert not held, held
+    aliased = re.search(r"input_output_alias=\{(.*?) \}", text).group(1)
+    assert len(re.findall(r"\{\d+\}: \(\d+, \{\}", aliased)) \
+        == len(pools)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= sum(
+        int(np.prod(p.shape)) * p.dtype.itemsize for p in pools)
+    assert memory.temp_size_in_bytes < 0.3e9, memory.temp_size_in_bytes
+
+
 def test_rtc_example_kernel_compiles_for_v5e(one_chip):
     """examples/custom_pallas_kernel.py's kernel through
     ``rtc.compile_kernel``: the compiled-or-interpreted choice follows
