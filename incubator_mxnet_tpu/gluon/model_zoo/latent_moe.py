@@ -15,6 +15,21 @@ rotated ``k_pe`` that all heads share, ``kv_lora_rank +
 qk_rope_head_dim`` values where per-head keys and values would be
 ``heads * (qk_head_dim + v_head_dim)``.
 
+Where the config sets ``hc_mult`` n > 1 the residual is a
+manifold-constrained hyper-connection (Xing4.0's layer): a token
+carries n streams, entered as n copies of its embedding and summed at
+the exit, and each sublayer F reads and writes them through mixing
+weights that the token's own state sets:
+``h_pre = sigmoid(a_pre * u Phi_pre + b_pre)``, ``h_post = 2
+sigmoid(a_post * u Phi_post + b_post)`` and ``H_res`` the matrix
+``exp(clip(a_res * u Phi_res + b_res))`` after ``hc_sinkhorn_iters``
+rounds of Sinkhorn-Knopp (rows, then columns, each over its sum +
+``hc_eps``), u the streams flattened and RMS-normalised without a
+gain; then ``X[i] <- sum_j H_res[i, j] X[j] + h_post[i] F(sum_k
+h_pre[k] X[k])`` (``_hyper``).  ``rope_scaling`` of type ``yarn`` sets
+the rotary frequencies and the softmax scale as DeepSeek-V3's code
+does (``_yarn``).
+
 The layer's mathematics is written once, :meth:`LatentMoELM.layer`,
 as a function of (weights, rows, positions, cache).  The eager
 ``forward``, the paged prefill program and the paged decode program of
@@ -28,6 +43,10 @@ tape.  The multi-token-prediction module some of these checkpoints
 carry (``num_nextn_predict_layers``) is no part of the forward pass
 that yields a token and is not built.
 """
+import functools
+import math
+import operator
+
 import numpy as np
 
 from ... import ndarray as nd
@@ -38,6 +57,7 @@ __all__ = ["LatentMoELM"]
 QUERY_BLOCK = 256     # rows of queries whose scores are held at once
 KEY_BLOCK = 512       # context rows a pass of the expanded path takes
 LANE = 128            # a pool's row is stored in whole lanes
+SINKHORN_UNROLL = 5   # Sinkhorn rounds in one pass of their loop
 
 
 def _rms(x, gamma, eps):
@@ -68,6 +88,42 @@ def _rope(x, positions, inv_freq):
     a, b = pairs[..., 0], pairs[..., 1]
     return jnp.stack([a * cos - b * sin, a * sin + b * cos],
                      axis=-1).reshape(x.shape).astype(x.dtype)
+
+
+def _yarn(scaling, theta, d_rope, qk_dim):
+    """DeepSeek-V3's ``yarn`` rotary embedding: (inv_freq, softmax
+    scale).  Frequencies below the correction range keep theta's,
+    those above it are divided by ``factor``, a linear ramp between;
+    the softmax scale gains ``mscale(factor, mscale_all_dim) ** 2``.
+    cos and sin would be scaled by ``mscale(factor, mscale) /
+    mscale(factor, mscale_all_dim)``: a ratio other than 1 is not
+    built."""
+    factor = scaling["factor"]
+    orig = scaling["original_max_position_embeddings"]
+
+    def corr(rotations):
+        return d_rope * math.log(orig / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    def mscale(m):
+        return 0.1 * m * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+    low = max(math.floor(corr(scaling.get("beta_fast", 32))), 0)
+    high = min(math.ceil(corr(scaling.get("beta_slow", 1))), d_rope - 1)
+    every = scaling.get("mscale_all_dim", 0)
+    if mscale(scaling.get("mscale", 1)) != mscale(every):
+        raise ValueError(f"rope_scaling={scaling!r}: cos and sin scaled "
+                         "by other than 1 are not built")
+    plain = theta ** (-2.0 * np.arange(d_rope // 2) / d_rope)
+    ramp = np.clip((np.arange(d_rope // 2) - low) / ((high - low) or 1e-3),
+                   0.0, 1.0)
+    inv_freq = plain / factor * ramp + plain * (1.0 - ramp)
+    scale = qk_dim ** -0.5 * (mscale(every) ** 2 if every else 1.0)
+    return inv_freq.astype(np.float32), scale
+
+
+def _total(values):
+    return functools.reduce(operator.add, values)
 
 
 # ------------------------------------------------------------- caches
@@ -122,8 +178,11 @@ class LatentMoELM(Block):
     ``n_shared_experts``, ``first_k_dense_replace``,
     ``routed_scaling_factor``, ``scoring_func``, ``norm_topk_prob``,
     ``rms_norm_eps`` where they differ from 0, 0, 1.0, ``sigmoid``,
-    true, 1e-6).  ``held=(first, count)`` makes this one rank of an
-    expert-parallel deployment: the router keeps all
+    true, 1e-6; ``hc_mult`` with ``hc_sinkhorn_iters``, ``hc_eps``,
+    ``mhc_h_res_clamp_min`` and ``_max`` for hyper-connections;
+    ``rope_scaling`` of type ``yarn``).  ``held=(first, count)``
+    makes this one rank of an expert-parallel deployment: the router
+    keeps all
     ``n_routed_experts`` outputs, the layer holds and computes experts
     ``first .. first + count - 1``, and ``shared_here`` says whether
     the shared expert is added on this rank.
@@ -144,12 +203,16 @@ class LatentMoELM(Block):
         c = self._c = dict(config)
         for key, only in (("hidden_act", "silu"), ("n_group", 1),
                           ("topk_group", 1), ("moe_layer_freq", 1),
-                          ("rope_scaling", None),
                           ("rope_interleave", True),
                           ("attention_bias", False)):
             if c.get(key, only) != only:
                 raise ValueError(f"{key}={c[key]!r} is not built: "
                                  f"{only!r} only")
+        scaling = c.get("rope_scaling")
+        if scaling is not None and scaling.get(
+                "type", scaling.get("rope_type")) != "yarn":
+            raise ValueError(f"rope_scaling={scaling!r} is not built: "
+                             "None or type yarn only")
         self._d = c["hidden_size"]
         self._max_len = c["max_position_embeddings"]
         self.n_layers = c["num_hidden_layers"]
@@ -170,10 +233,22 @@ class LatentMoELM(Block):
         self.d_nope, self.d_rope = (c["qk_nope_head_dim"],
                                     c["qk_rope_head_dim"])
         self.d_v = c["v_head_dim"]
-        self._inv_freq = (c["rope_theta"] ** (
-            -2.0 * np.arange(self.d_rope // 2) / self.d_rope)) \
-            .astype(np.float32)
-        self.softmax_scale = (self.d_nope + self.d_rope) ** -0.5
+        if scaling is None:
+            self._inv_freq = (c["rope_theta"] ** (
+                -2.0 * np.arange(self.d_rope // 2) / self.d_rope)) \
+                .astype(np.float32)
+            self.softmax_scale = (self.d_nope + self.d_rope) ** -0.5
+        else:
+            self._inv_freq, self.softmax_scale = _yarn(
+                scaling, c["rope_theta"], self.d_rope,
+                self.d_nope + self.d_rope)
+        # the residual's streams a token carries (1: a plain residual)
+        self.residual_streams = n = int(c.get("hc_mult", 1))
+        if n > 1:
+            self.hc_iters = int(c["hc_sinkhorn_iters"])
+            self.hc_eps = float(c["hc_eps"])
+            self.hc_clip = (float(c["mhc_h_res_clamp_min"]),
+                            float(c["mhc_h_res_clamp_max"]))
         self._forward_fns = {}
         d, h = self._d, self.n_heads
         q_rank = c["q_lora_rank"]
@@ -233,6 +308,17 @@ class LatentMoELM(Block):
                                           shape=(shared, d)),
                             shared_down=get(p + "shared_down_weight",
                                             shape=(d, shared)))
+                for s in ("attn", "ffn") if n > 1 else ():
+                    # Phi_pre, Phi_post and Phi_res as the rows of one
+                    # matrix (n, n and n * n of them, H_res row-major),
+                    # their biases alike, the gains a_pre, a_post, a_res
+                    q = f"{p}hc_{s}_"
+                    lw[f"hc_{s}"] = get(q + "weight",
+                                        shape=(n * (n + 2), n * d))
+                    lw[f"hc_{s}_bias"] = get(q + "bias", init="zeros",
+                                             shape=(n * (n + 2),))
+                    lw[f"hc_{s}_alpha"] = get(q + "alpha", init="ones",
+                                              shape=(3,))
                 self.layers.append(lw)
             self.norm = get("norm_gamma", shape=(d,), init="ones")
             self.head_weight = get("head_weight",
@@ -361,26 +447,18 @@ class LatentMoELM(Block):
             return _mm(out.astype(h.dtype).reshape(
                 t, heads * self.d_v), lw["o"])
 
-    def layer(self, lw, x, positions, cache, li, valid=None,
-              last=None):
-        """One layer on rows ``x`` (T, d) at ``positions`` (T,):
-        (rows out, the routed layer's statistics or None)."""
-        import jax.numpy as jnp
+    def _ffn(self, lw, h, valid):
+        """The FFN sublayer on normed rows ``h``: (its rows, the
+        routed layer's statistics or None)."""
         from ...ops.moe import gated_ffn, routed_ffn_fn
-        if last is None:
-            last = positions[-1]
-        x = x + self._attend(lw, _rms(x, lw["attn_norm"], self.eps),
-                             positions, cache, li, last)
-        h = _rms(x, lw["ffn_norm"], self.eps)
         if "router" not in lw:
-            return x + gated_ffn(h, lw["gate"], lw["up"],
-                                 lw["down"]).astype(x.dtype), None
+            return gated_ffn(h, lw["gate"], lw["up"], lw["down"]), None
         c = self._c
         shared = None
         if "shared_gate" in lw and self.shared_here:
             shared = (lw["shared_gate"], lw["shared_up"],
                       lw["shared_down"])
-        y, stats = routed_ffn_fn(
+        return routed_ffn_fn(
             h, lw["router"], lw["experts_gate"], lw["experts_up"],
             lw["experts_down"], self.top_k,
             scoring=c.get("scoring_func", "sigmoid"),
@@ -388,29 +466,147 @@ class LatentMoELM(Block):
             normalize=c.get("norm_topk_prob", True),
             scale=float(c.get("routed_scaling_factor", 1.0)),
             shared=shared, held=self.held, valid=valid)
-        return x + y, jnp.stack([stats["routed_rows"],
-                                 stats["padded_rows"],
-                                 stats["experts_touched"],
-                                 jnp.ones((), jnp.int32)])
+
+    def _sinkhorn(self, m):
+        """``hc_iters`` rounds of rows then columns, each divided by
+        its sum + ``hc_eps``, over m (T, n * n) row-major and positive:
+        (H[i][j], each (T,), and the largest |row or column sum - 1|
+        after the last round, (T,)).  Written entry by entry, every
+        operation elementwise on (T,), so that a round compiles to
+        about two fusions and not to a reduction and a division for
+        each row and each column; a loop of passes of
+        ``SINKHORN_UNROLL`` rounds, since all the rounds unrolled
+        (one fusion) cost the compiler seconds for each
+        hyper-connection of every program."""
+        import jax
+        import jax.numpy as jnp
+        n, eps = self.residual_streams, self.hc_eps
+
+        def down(h, j):
+            return [h[i][j] for i in range(n)]
+
+        def one_round(_, h):
+            h = [[v / (_total(row) + eps) for v in row] for row in h]
+            sums = [_total(down(h, j)) + eps for j in range(n)]
+            return [[v / s for v, s in zip(row, sums)] for row in h]
+        cols = m.T
+        h = jax.lax.fori_loop(
+            0, self.hc_iters, one_round,
+            [[cols[i * n + j] for j in range(n)] for i in range(n)],
+            unroll=SINKHORN_UNROLL)
+        err = functools.reduce(jnp.maximum, [
+            jnp.abs(_total(v) - 1.0)
+            for v in h + [down(h, j) for j in range(n)]])
+        return h, err
+
+    def _hyper(self, lw, s, x, sublayer):
+        """Sublayer ``s`` (``attn`` or ``ffn``), ``sublayer(h)`` of
+        rows h (T, d), under its hyper-connection: streams ``x`` (T, n,
+        d) in and out, and each row's largest marginal error of its
+        mixing matrix (T,).  The mapping is one product of the
+        normalised streams (bfloat16 operands where the weights are,
+        float32 sums); gates, the Sinkhorn rounds and both mixes are
+        float32."""
+        import jax
+        import jax.numpy as jnp
+        f32 = jnp.float32
+        t, n, d = x.shape
+        with jax.named_scope("hc_map"):
+            u = x.reshape(t, n * d).astype(f32)
+            u = u * jax.lax.rsqrt(jnp.mean(u * u, -1, keepdims=True)
+                                  + self.eps)
+            z = jnp.dot(u.astype(x.dtype), lw[f"hc_{s}"].T,
+                        preferred_element_type=f32)
+            a = lw[f"hc_{s}_alpha"].astype(f32)
+            b = lw[f"hc_{s}_bias"].astype(f32)
+            pre = jax.nn.sigmoid(a[0] * z[:, :n] + b[:n])
+            post = 2.0 * jax.nn.sigmoid(a[1] * z[:, n:2 * n] + b[n:2 * n])
+            h_res, err = self._sinkhorn(jnp.exp(jnp.clip(
+                a[2] * z[:, 2 * n:] + b[2 * n:], *self.hc_clip)))
+        with jax.named_scope("hc_mix"):
+            wide = x.astype(f32)
+            h = _total([pre[:, i, None] * wide[:, i] for i in range(n)])
+        y = sublayer(h.astype(x.dtype)).astype(f32)
+        with jax.named_scope("hc_mix"):
+            x = jnp.stack([_total([h_res[i][j][:, None] * wide[:, j]
+                                   for j in range(n)])
+                           + post[:, i, None] * y for i in range(n)],
+                          axis=1).astype(x.dtype)
+        return x, err
+
+    def layer(self, lw, x, positions, cache, li, valid=None,
+              last=None):
+        """One layer on rows ``x`` (T, d), or on their streams (T, n,
+        d) where the model has hyper-connections, at ``positions``
+        (T,): (rows or streams out, the routed layer's statistics or
+        None, the largest marginal error of the layer's mixing
+        matrices or None)."""
+        import jax.numpy as jnp
+        if last is None:
+            last = positions[-1]
+        eps = self.eps
+        if self.residual_streams == 1:
+            x = x + self._attend(lw, _rms(x, lw["attn_norm"], eps),
+                                 positions, cache, li, last)
+            y, stats = self._ffn(lw, _rms(x, lw["ffn_norm"], eps),
+                                 valid)
+            x, err = x + y.astype(x.dtype), None
+        else:
+            out = {}
+
+            def ffn(h):
+                y, out["stats"] = self._ffn(
+                    lw, _rms(h, lw["ffn_norm"], eps), valid)
+                return y
+            x, err_a = self._hyper(lw, "attn", x, lambda h: self._attend(
+                lw, _rms(h, lw["attn_norm"], eps), positions, cache, li,
+                last))
+            x, err_f = self._hyper(lw, "ffn", x, ffn)
+            err = jnp.maximum(err_a, err_f)
+            if valid is not None:      # padding rows and idle slots
+                err = jnp.where(valid, err, 0.0)
+            stats, err = out["stats"], jnp.max(err)
+        if stats is None:
+            return x, None, err
+        return x, jnp.stack([stats["routed_rows"], stats["padded_rows"],
+                             stats["experts_touched"],
+                             jnp.ones((), jnp.int32)]), err
 
     def _rows_to_logits(self, wts, x):
         import jax.numpy as jnp
+        if self.residual_streams > 1:
+            # the exit: the streams summed (a mean differs by nothing
+            # after the norm but its epsilon)
+            x = jnp.sum(x.astype(jnp.float32), axis=-2).astype(x.dtype)
         return jnp.dot(_rms(x, wts["norm"], self.eps),
                        wts["head"].T,
                        preferred_element_type=jnp.float32)
 
     def _stack(self, wts, tokens, positions, cache, valid=None,
                last=None):
-        """Embedding and every layer: (rows, the routed layers'
-        statistics summed: rows multiplied, rows of them padding,
-        experts touched, routed layers)."""
+        """Embedding and every layer: (rows or streams, the routed
+        layers' statistics summed: rows multiplied, rows of them
+        padding, experts touched, routed layers; and where the model
+        has hyper-connections, behind them as float32 bits, the largest
+        marginal error of any mixing matrix)."""
+        import jax
         import jax.numpy as jnp
         x = wts["embed"][tokens]
-        stats = jnp.zeros(4, jnp.int32)
+        n = self.residual_streams
+        if n > 1:
+            # the entry: the embedding copied into every stream
+            x = jnp.broadcast_to(x[:, None], (x.shape[0], n, x.shape[1]))
+        stats, worst = jnp.zeros(4, jnp.int32), None
         for li, lw in enumerate(wts["layers"]):
-            x, s = self.layer(lw, x, positions, cache, li, valid, last)
+            x, s, err = self.layer(lw, x, positions, cache, li, valid,
+                                   last)
             if s is not None:
                 stats = stats + s
+            if err is not None:
+                worst = err if worst is None else jnp.maximum(worst, err)
+        if worst is not None:
+            stats = jnp.concatenate([stats, jax.lax.bitcast_convert_type(
+                worst, jnp.int32)[None]])
         return x, stats
 
     # ---------------------------------------------------------- eager
@@ -460,15 +656,17 @@ class LatentMoELM(Block):
         """Which read of the pool the decode step takes where it is
         traced now and lowered for ``platform``, with the shapes that
         decide it (``ops.paged_attention.read_kind``: every head's
-        absorbed query against the one pool's whole row, one kv head):
-        what the engine's ``serve_paged_read`` event carries."""
+        absorbed query against the one pool's whole row, one kv head),
+        and the streams of its residual: what the engine's
+        ``serve_paged_read`` event carries."""
         from ...ops.paged_attention import read_kind
         cache, = self._paged_cache()
         row, = cache["shape"]
         return dict(read=read_kind(self.n_heads, 1, row, int(block_size),
                                    cache["dtype"], platform),
                     heads=self.n_heads, kv_heads=1, head_dim=row,
-                    dtype=cache["dtype"])
+                    dtype=cache["dtype"],
+                    residual_streams=self.residual_streams)
 
     def _decode_weights(self):
         def w(param):
@@ -534,5 +732,7 @@ class LatentMoELM(Block):
         return step
 
     def _decode_workspace_bytes(self, max_batch):
-        """One decode step's logits and residual rows, float32."""
-        return 4.0 * max_batch * (self.vocab + 8 * self._d)
+        """One decode step's logits and residual rows, float32: a
+        token's residual is ``residual_streams`` rows wide."""
+        return 4.0 * max_batch * (
+            self.vocab + 8 * self._d * self.residual_streams)

@@ -190,6 +190,10 @@ def _tile_rows(tokens, top_k, n_experts):
 
 
 GROUP_ROWS = 128      # rows of one tile of the grouped product
+# bytes of one expert's weight tile in the grouped product: held twice
+# (the next tile is fetched while one is used) beside the row tiles,
+# inside the 16 MiB of scoped VMEM a kernel may take
+WEIGHT_TILE_BYTES = 4 << 20
 
 
 def _experts_tiled(x, order, pairs, flat_weight, gate_weight,
@@ -237,9 +241,15 @@ def _experts_grouped(x, order, pairs, flat_weight, gate_weight,
         for w in (gate_weight, up_weight, down_weight))
 
     def product(rows, weight):
+        # a weight's whole rows, or halves of its output columns where
+        # they would not fit (3584 x 1024 of bfloat16 is 7.3 MB)
+        k, cols = rows.shape[1], weight.shape[1]
+        while cols * k * weight.dtype.itemsize > WEIGHT_TILE_BYTES \
+                and cols % 256 == 0:
+            cols //= 2
         return gmm(rows, weight, pairs, jnp.float32,
-                   (GROUP_ROWS, rows.shape[1], weight.shape[1]),
-                   transpose_rhs=True, interpret=interpret)
+                   (GROUP_ROWS, k, cols), transpose_rhs=True,
+                   interpret=interpret)
 
     rows = x[order // top_k]
     hidden = jax.nn.silu(product(rows, gate_weight)) \

@@ -340,6 +340,9 @@ class ServingEngine:
         self._m_moe = [telemetry.counter(f"serving_moe_{n}_total")
                        for n in ("routed_rows", "padded_rows",
                                  "experts_touched", "layer_steps")]
+        # and behind them a hyper-connected model's largest marginal
+        # error of its mixing matrices: made where one comes back
+        self._m_hc = None
 
     # ---------------------------------------------------------- setup
     def _auto_num_blocks(self):
@@ -668,17 +671,25 @@ class ServingEngine:
         """What follows the tokens in a program's ``next``: the
         routed layers' statistics summed over the layers (rows the
         grouped product multiplied, rows of them padding, distinct
-        experts read, routed layers).  Rows count for every program;
-        experts touched a layer is a decode step's number.  Returns
-        the experts touched."""
+        experts read, routed layers; then, where the model has
+        hyper-connections, the largest |row or column sum - 1| of its
+        mixing matrices as float32 bits).  Rows count for every
+        program; experts touched a layer and the marginal error are a
+        decode step's numbers.  Returns the experts touched."""
         if not len(stats):
             return None
-        rows, padded, touched, layers = (int(v) for v in stats)
+        rows, padded, touched, layers = (int(v) for v in stats[:4])
         self._m_moe[0].inc(rows)
         self._m_moe[1].inc(padded)
         if decode:
             self._m_moe[2].inc(touched)
             self._m_moe[3].inc(layers)
+            if len(stats) > 4:
+                if self._m_hc is None:
+                    self._m_hc = telemetry.gauge(
+                        "serving_hc_marginal_error")
+                self._m_hc.set(float(np.asarray(
+                    stats[4:5], np.int32).view(np.float32)[0]))
         return touched
 
     def stream(self):

@@ -316,6 +316,77 @@ def test_latent_decode_reads_through_the_table_on_v5e(latent_cell):
     assert memory.alias_size_in_bytes >= sum(
         int(np.prod(p.shape)) * p.dtype.itemsize for p in pools)
     assert memory.temp_size_in_bytes < 0.3e9, memory.temp_size_in_bytes
+    # no hyper-connection is built without ``hc_mult``: the parent's
+    # kernels (a paged read a layer, the routed layer's three grouped
+    # products) and no operation under an ``hc_`` scope
+    assert text.count("tpu_custom_call") == len(pools) + 3
+    assert "hc_" not in text
+
+
+def _abstract_latent(lm, leaf, blocks):
+    """A ``LatentMoELM``'s weights and pools as abstract leaves."""
+    wts = {"embed": leaf(*lm.embed_weight.shape),
+           "norm": leaf(*lm.norm.shape),
+           "head": leaf(*lm.head_weight.shape),
+           "layers": [{k: leaf(*p.shape) for k, p in lw.items()}
+                      for lw in lm.layers]}
+    spec, = lm._paged_cache()
+    return wts, [leaf(blocks, 16, *spec["shape"]) for _ in lm.layers]
+
+
+def test_hyper_connected_decode_compiles_for_v5e(one_chip):
+    """``LatentMoELM`` at ``benchmark/configs/xing4.0-29b-a4b.json``:
+    its 6-layer decode step at the cell's shapes (64 slots, 512 blocks
+    of 16 a row, 19,521 blocks of 640 lanes, bfloat16) as the engine
+    jits it, for the chip: one paged read a layer, every pool out in
+    the buffer it came in, temporaries under 0.1 GB (the streams of 64
+    tokens are 7 MB); 12 hyper-connections, each with the same count
+    of operations under ``hc_map`` (the mapping and gates in the
+    program's body, the Sinkhorn rounds in a loop of 4 passes of 5)
+    and under ``hc_mix``.  Counts of a compile, no times."""
+    import json
+
+    from incubator_mxnet_tpu.gluon.model_zoo.latent_moe import \
+        LatentMoELM
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "xing4.0-29b-a4b.json")) as f:
+        lm = LatentMoELM(json.load(f))
+
+    def leaf(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    wts, pools = _abstract_latent(lm, leaf, 19521)
+    ints = functools.partial(leaf, dtype=jnp.int32)
+    compiled = jax.jit(lm._build_paged_step(64, 512, 16),
+                       donate_argnums=(1,)).lower(
+        wts, pools, ints(64, 512), ints(64), ints(64)).compile()
+    text = compiled.as_text()
+    reads = [line for line in text.splitlines()
+             if "tpu_custom_call" in line
+             and "paged_decode_attention" in line]
+    assert len(reads) == len(pools) == 6
+    aliased = re.search(r"input_output_alias=\{(.*?) \}", text).group(1)
+    assert len(re.findall(r"\{\d+\}: \(\d+, \{\}", aliased)) == 6
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 0.1e9, memory.temp_size_in_bytes
+    entry = text[text.index("\nENTRY"):]
+    ops = [line for line in entry.splitlines() if " = " in line
+           and not re.search(r" (parameter|get-tuple-element|tuple|"
+                             r"constant|bitcast)\(", line)]
+    loops = [line for line in ops if " while(" in line
+             and "/hc_map/" in line]
+    assert len(loops) == 12
+    # a pass of 5 rounds is some two fusions a round (10 when this was
+    # written); around the loop, some 11 operations a hyper-connection
+    # map and gate (133 for 12) and 3 or 4 mix (44)
+    body = re.search(r"body=%?([\w.\-]+)", loops[0]).group(1)
+    body = re.search(r"\n%?" + re.escape(body) + r" .*?\n}", text,
+                     re.S).group(0)
+    assert len([line for line in body.splitlines()[1:] if " = " in line
+                and not re.search(r" (parameter|get-tuple-element|"
+                                  r"tuple|constant|bitcast)\(", line)]) \
+        <= 12
+    assert 0 < sum(1 for line in ops if "/hc_map/" in line) <= 12 * 12
+    assert 0 < sum(1 for line in ops if "/hc_mix/" in line) <= 12 * 4
 
 
 def test_rtc_example_kernel_compiles_for_v5e(one_chip):
