@@ -34,7 +34,11 @@ new path is injectable: ``MXTPU_FAULT_SPEC`` scopes ``serve:step`` /
 ``serve:deadline`` / ``serve:queue`` next to ``serve:request``.
 
 The decode loop's only device->host sync is the per-iteration token
-read (enforced by ci/lint.py's host-sync rule over this module).
+read (enforced by ci/lint.py's host-sync rule over this module).  The
+loop is chained: a decode step takes its tokens from the step before
+on the device, and the host reads, emits and retires a step's tokens
+while the next step computes, so ``step()``'s events trail the device
+by one step (docs/serving.md, "The chained decode loop").
 Telemetry rides the process registry: request/ token counters,
 queue-wait / TTFT / per-token histograms, occupancy and
 pool-utilization gauges.  ``MXTPU_FAULT_SPEC`` scope
@@ -78,6 +82,57 @@ def _next_pow2(n):
 PAGED_PROTOCOL = ("_max_len", "n_layers", "_check_paged", "_paged_cache",
                   "_decode_weights", "_build_paged_prefill",
                   "_build_paged_step", "_decode_workspace_bytes")
+
+
+def program(name, fn, n_pools):
+    """The jitted form of one of the engine's programs: named
+    ``serve_<name>`` (``jit_serve_decode``, ``jit_serve_prefill_<bucket>``
+    on a device trace's "XLA Modules" line), its pools donated (right
+    after the weights in every signature): the compiled call updates
+    the cache IN PLACE instead of copying every pool array out per
+    token — the engine always rebinds its pools from the outputs, so
+    the consumed buffers are never reused."""
+    import jax
+
+    def run(*args):
+        return fn(*args)
+
+    run.__name__ = run.__qualname__ = f"serve_{name}"
+    return jax.jit(run, donate_argnums=tuple(range(1, 1 + n_pools)))
+
+
+def chained(step, max_batch):
+    """The decode program the engine runs: the model's own ``step``,
+    each slot's token taken from the previous decode program's
+    ``next`` (tokens, then whatever statistics ride behind them) on
+    the device, or from ``tokens`` where ``fresh`` says the host holds
+    it (a slot admitted since, or one whose last token has landed).
+    One select at the entry; the model's step is called as it is, so
+    the device runs the step it would run alone
+    (tests/test_tpu_compile.py).
+
+    ``decode(wts, *pools, tables, n_past, tokens, fresh, prev) ->
+    (*pools, next, logits)``."""
+    from jax import lax
+
+    def decode(wts, *args):
+        *pools, tables, n_past, tokens, fresh, prev = args
+        tokens = lax.select(fresh, tokens,
+                            lax.slice_in_dim(prev, 0, max_batch))
+        return step(wts, *pools, tables, n_past, tokens)
+
+    return decode
+
+
+class _Flight:
+    """A decode step dispatched and not landed: its ``next`` and
+    ``logits`` on the device, and the request each slot's row was
+    computed for (None where the slot had no row)."""
+
+    __slots__ = ("nxt", "logits", "reqs")
+
+    def __init__(self, nxt, logits, reqs):
+        self.nxt, self.logits, self.reqs = nxt, logits, reqs
 
 
 class ServingEngine:
@@ -234,6 +289,18 @@ class ServingEngine:
             for c in self.cache_spec)
 
         self._step_fn = None
+        self._nxt0 = None
+        # the chained decode loop (docs/serving.md): the step whose
+        # tokens the host has not read yet; the running slots' block
+        # tables, a row written on admission, growth and release; the
+        # next step's inputs, uploaded while the device computes
+        # (None: something changed since, build them again); and
+        # whether a runner lacks the block its next row writes into
+        self._flight = None
+        self._tables = np.zeros((self.max_batch, self.max_blocks),
+                                np.int32)
+        self._staged = None
+        self._grow_due = False
         self._prefill_fns = {}
         self.trace_counts = {}
         self._next_id = 0
@@ -313,6 +380,10 @@ class ServingEngine:
             "serving_decode_blocks_live_total")
         self._m_allowed_blocks = telemetry.counter(
             "serving_decode_blocks_allowed_total")
+        self._m_decode_steps = telemetry.counter(
+            "serving_decode_steps_total")
+        self._m_chained = telemetry.counter(
+            "serving_decode_chained_total")
         self._m_occ = telemetry.gauge("serving_batch_occupancy")
         self._m_util = telemetry.gauge(
             "serving_block_pool_utilization")
@@ -406,8 +477,6 @@ class ServingEngine:
             return model._decode_weights()
 
     def _counted_jit(self, name, fn, signature):
-        import jax
-
         def traced(*args):
             # runs at TRACE time only: the regression tests assert
             # admission/retirement replay the compiled step
@@ -415,17 +484,7 @@ class ServingEngine:
                 self.trace_counts.get(name, 0) + 1
             return fn(*args)
 
-        # the program's name on a device trace's "XLA Modules" line
-        # (jit_serve_decode, jit_serve_prefill_<bucket>)
-        traced.__name__ = traced.__qualname__ = f"serve_{name}"
-
-        # donate the pools (right after the weights in both the
-        # prefill and the step signature): the compiled call updates
-        # the cache IN PLACE instead of copying every pool array out
-        # per token — the engine always rebinds self._pools from the
-        # outputs, so the consumed buffers are never reused
-        jfn = jax.jit(traced, donate_argnums=tuple(
-            range(1, 1 + len(self._pools))))
+        jfn = program(name, traced, len(self._pools))
 
         def called(*args):
             # a call that ran the Python trace just compiled: record
@@ -443,25 +502,42 @@ class ServingEngine:
 
     def _get_step_fn(self):
         if self._step_fn is None:
+            import jax
+            import jax.numpy as jnp
+            B, MB = self.max_batch, self.max_blocks
             describe = getattr(self.model, "_paged_read", None)
             if describe is not None:
                 # built where it is first called, so under the matmul
                 # precision its trace will see
-                import jax
                 platform = jax.default_backend()
                 tracing.trace_event(
                     "serve_paged_read", engine=self.engine_id,
-                    platform=platform, max_batch=self.max_batch,
-                    max_blocks=self.max_blocks,
+                    platform=platform, max_batch=B, max_blocks=MB,
                     block_size=self.block_size,
                     **describe(self.block_size, platform))
+            step = jax.jit(self.model._build_paged_step(
+                B, MB, self.block_size))
+            # the shape of the step's ``next``, for the program's
+            # ``prev`` when no step is in flight: this traces the
+            # model's step, and the decode program's trace reuses it
+            ints = (jax.ShapeDtypeStruct((B, MB), jnp.int32),
+                    jax.ShapeDtypeStruct((B,), jnp.int32),
+                    jax.ShapeDtypeStruct((B,), jnp.int32))
+            nxt = step.eval_shape(self._wts, *self._pools, *ints)[-2]
+            # placed as the step's own ``next`` will be: committed to
+            # the pools' device where the programs' outputs are (the
+            # pools are a prefill's by now), else not.  Otherwise the
+            # first step that chains compiles the decode program
+            # again, and a committed ``next`` would commit every
+            # program's outputs after it and compile each once more
+            pool = self._pools[0][0]
+            self._nxt0 = jax.device_put(
+                np.zeros(nxt.shape, nxt.dtype),
+                pool.sharding if pool.committed else None)
             self._step_fn = self._counted_jit(
-                "decode", self.model._build_paged_step(
-                    self.max_batch, self.max_blocks,
-                    self.block_size),
+                "decode", chained(step, B),
                 {"builder": "decode",
-                 "static_arg": (self.max_batch, self.max_blocks,
-                                self.block_size)})
+                 "static_arg": (B, MB, self.block_size)})
         return self._step_fn
 
     def _get_prefill_fn(self, suffix_len):
@@ -628,17 +704,20 @@ class ServingEngine:
 
     def has_work(self):
         """Whether driving the engine can still make progress: any
-        request queued/running — or, while draining, only the
-        RUNNING batch.  Queued requests are frozen for
-        :meth:`snapshot` once drain latches; reporting them here
-        would spin a ``while engine.has_work(): engine.step()``
-        driver forever on work admission will never start."""
+        request queued/running or a decode step whose tokens have not
+        landed — or, while draining, only the RUNNING batch and that
+        step.  Queued requests are frozen for :meth:`snapshot` once
+        drain latches; reporting them here would spin a ``while
+        engine.has_work(): engine.step()`` loop forever on work
+        admission will never start."""
         return self._has_loop_work()
 
     def _has_loop_work(self):
         """What drives stream()/run(): everything, or — while
         draining — only the running batch (queued requests are
         deliberately left for snapshot(), never admitted)."""
+        if self._flight is not None:
+            return True
         if self._draining:
             return self._sched.any_running()
         return self._sched.has_work()
@@ -646,8 +725,12 @@ class ServingEngine:
     def step(self):
         """One continuous-batching iteration: reap (cancellations +
         expired deadlines, blocks freed same-iteration) -> admit ->
-        grow -> decode -> retire.  Returns the ``(request,
-        token_id)`` events emitted this iteration."""
+        dispatch the next decode step -> land the step before it
+        (token read, emit, retire) -> grow -> stage the next step's
+        inputs -> wait for the device.  Returns the ``(request,
+        token_id)`` events emitted this iteration: admissions' first
+        tokens and the tokens of the decode step dispatched by the
+        previous iteration."""
         events = []
         with telemetry.span("serve_step",
                             running=self._sched.n_running(),
@@ -655,10 +738,7 @@ class ServingEngine:
             with telemetry.span("serve_reap"):
                 self._reap()
             self._admit(events)
-            if self._sched.any_running():
-                with telemetry.span("serve_grow"):
-                    self._grow()
-            if self._sched.any_running():
+            if self._sched.any_running() or self._flight is not None:
                 self._decode_once(events)
             self._m_occ.set(self._sched.n_running() / self.max_batch)
             self._m_util.set(self.pool.utilization())
@@ -768,7 +848,7 @@ class ServingEngine:
         ``run()``/``drain()`` as ``{id: tokens}``.  Idempotent."""
         self._latch_drain()
         if run:
-            while self._sched.any_running():
+            while self._sched.any_running() or self._flight is not None:
                 self.step()
         done, self._completed = self._completed, []
         return {req.id: req.tokens for req in done}
@@ -826,7 +906,10 @@ class ServingEngine:
         engine can :meth:`restore` them.  A request is fully
         reconstructible from prompt + generated tokens: greedy
         recompute (the same property preemption relies on) makes the
-        restored continuation token-identical.
+        restored continuation token-identical.  A request is taken as
+        of its last EMITTED token: a token of the decode step in
+        flight has not landed and is recomputed after restore, so the
+        snapshot reads no device array and waits for nothing.
 
         Returns the snapshot dict; with ``path`` it is also written
         via ``resilience.atomic_save`` (+ CRC32 sidecar), so a
@@ -1182,6 +1265,11 @@ class ServingEngine:
         req.block_ids = matched + fresh
         self._sched.place(req, slot)
         self._in_transit = None
+        row = self._tables[slot]
+        row[:] = 0
+        row[:len(req.block_ids)] = req.block_ids
+        # its first decode row may write past its last block
+        self._staged, self._grow_due = None, True
         sp_admit.set(cached_tokens=n_cached)
         tracing.trace_event(
             "serve_admit", rid=req.id, engine=self.engine_id,
@@ -1196,9 +1284,7 @@ class ServingEngine:
         bucket, fn = self._get_prefill_fn(len(suffix))
         suf = np.zeros(bucket, np.int32)
         suf[:len(suffix)] = suffix
-        row = np.zeros(self.max_blocks, np.int32)
-        row[:len(req.block_ids)] = req.block_ids
-        row, suf = jnp.asarray(row), jnp.asarray(suf)
+        row, suf = jnp.asarray(row.copy()), jnp.asarray(suf)
         with telemetry.span("serve_prefill", rid=req.id,
                             tokens=len(suffix),
                             bucket=bucket) as sp_pre:
@@ -1232,22 +1318,31 @@ class ServingEngine:
         self._append_token(req, int(nxt[0]), events)
         return True
 
-    def _grow(self):
-        """Ensure every runner owns the block its next position
-        writes into; preempt the latest-admitted runner on
-        exhaustion."""
+    def _grow(self, preempt=True):
+        """Ensure every runner with a row in the next decode step owns
+        the block that row writes into; on exhaustion preempt the
+        latest-admitted runner.  With ``preempt`` False stop there
+        instead and return False: a runner is freed only once the
+        step in flight has landed (its token kept, not recomputed).
+        True when every such runner has its block."""
         bs = self.block_size
         for req in sorted(self._sched.running(),
                           key=lambda r: r.admit_seq):
             if req.done or req.slot is None:
                 continue        # preempted earlier in this pass
-            if req.n_past // bs < len(req.block_ids):
+            if req.n_past // bs < len(req.block_ids) \
+                    or not self._next_row(req):
                 continue
             while True:
                 try:
                     req.block_ids += self._alloc(1)
+                    self._tables[req.slot, len(req.block_ids) - 1] = \
+                        req.block_ids[-1]
+                    self._staged = None
                     break
                 except BlockPoolExhausted:
+                    if not preempt:
+                        return False
                     victim = self._sched.latest_running()
                     if victim is req and self._sched.n_running() == 1:
                         # the pool cannot hold this one sequence:
@@ -1263,15 +1358,26 @@ class ServingEngine:
                     self._preempt(victim)
                     if victim is req:
                         break               # we preempted ourselves
+        return True
+
+    def _vacate(self, req):
+        """Take a runner out of its slot: its table row goes back to
+        the scratch block, and the next step's inputs are built
+        again."""
+        if req.slot is not None:
+            self._tables[req.slot] = 0
+            self._staged = None
+        self._sched.clear(req)
 
     def _preempt(self, req):
         """Free a runner's blocks and re-queue it (front).  Its
         generated tokens survive; re-admission re-prefills
         prompt+generated (cheap again once the prefix cache holds
-        the shared blocks)."""
+        the shared blocks).  Only ever called with no decode step in
+        flight (``_grow``)."""
         freed = len(req.block_ids)
         self._in_transit = req      # out of the slot, not yet queued
-        self._sched.clear(req)
+        self._vacate(req)
         if req.block_ids:
             self.pool.free(req.block_ids)
         req.block_ids = []
@@ -1294,8 +1400,68 @@ class ServingEngine:
                             queue_depth=len(self._sched.waiting))
         self._prof_async("b", "queue_wait", req)
 
+    def _next_row(self, req):
+        """Whether a runner has a row in the next decode step: not
+        where its budget ends with its token in the step in flight
+        (it retires when that lands; its slot idles one step)."""
+        f = self._flight
+        pending = f is not None and f.reqs[req.slot] is req
+        return len(req.generated) + pending < req.max_new_tokens
+
+    def _stage(self):
+        """The next decode step's inputs, built on the host and put on
+        the device (while the step in flight computes, where nothing
+        changes before the dispatch): a row for every runner that has
+        one, at its own position, through its table row; its token
+        from the step in flight's ``next`` on the device, or the host's
+        (``fresh``) where the runner has none there.  A slot without a
+        row is idle: position 0, the scratch block's row, no expert.
+
+        Returns (the request of each row or None, the device arrays,
+        the blocks the rows hold up to their positions, whether any
+        row's token is taken on the device)."""
+        import jax
+        flight = self._flight
+        B, bs = self.max_batch, self.block_size
+        rows = [None] * B
+        npast = np.zeros(B, np.int32)
+        tokens = np.zeros(B, np.int32)
+        fresh = np.zeros(B, np.bool_)
+        tables = self._tables.copy()
+        live = 0
+        chained = False
+        for s, req in enumerate(self._sched.slots):
+            if req is None:
+                continue
+            if not self._next_row(req):
+                tables[s] = 0
+                continue
+            rows[s] = req
+            npast[s] = req.n_past
+            # the blocks that hold positions 0 .. n_past: what a read
+            # through the table has to touch for this slot
+            live += req.n_past // bs + 1
+            if flight is not None and flight.reqs[s] is req:
+                chained = True
+            else:
+                fresh[s] = True
+                tokens[s] = req.generated[-1]
+        put = jax.device_put
+        return (rows, (put(tables), put(npast), put(tokens),
+                       put(fresh)), live, chained)
+
     def _decode_once(self, events):
-        """One batched decode step + the per-iteration token read.
+        """The decode part of one iteration: dispatch the next decode
+        step, its tokens taken from the step in flight on the device;
+        land that step (the per-iteration token read, emit, retire)
+        while the new one computes; grow and stage for the step after;
+        wait for the new step's pools.  With no row to run it only
+        lands the step in flight.
+
+        Where a runner lacks its next block and the pool has none
+        left, the step in flight lands BEFORE the dispatch, so that a
+        preempted runner keeps its token; the next step then takes
+        every token from the host.
 
         Watchdog: with ``MXTPU_SERVE_STEP_TIMEOUT`` > 0, an
         iteration whose decode (injection included — serve:step:N:
@@ -1306,55 +1472,87 @@ class ServingEngine:
         the overrun into a post-mortem is this layer's job, killing
         the process is the heartbeat monitor's."""
         import jax
-        import jax.numpy as jnp
-        B, MB, bs = self.max_batch, self.max_blocks, self.block_size
-        slots = self._sched.slots
-        running = self._sched.n_running()
-        with telemetry.span("serve_decode_prep",
-                            running=running) as sp_prep:
-            # inside the span: the watchdog below counts an injected
-            # hang as part of the step
-            resilience.inject("serve", "step")
-            tokens = np.zeros(B, np.int32)
-            npast = np.zeros(B, np.int32)
-            tables = np.zeros((B, MB), np.int32)
-            live_blocks = 0
-            for i, req in enumerate(slots):
-                if req is None:
-                    continue
-                tokens[i] = req.generated[-1]
-                npast[i] = req.n_past
-                tables[i, :len(req.block_ids)] = req.block_ids
-                # the blocks that hold positions 0 .. n_past: what a
-                # read through the table has to touch for this slot
-                live_blocks += req.n_past // bs + 1
-            tables, npast, tokens = (jnp.asarray(tables),
-                                     jnp.asarray(npast),
-                                     jnp.asarray(tokens))
+        B = self.max_batch
+
+        # nested, so that the iteration's one read stays inside this
+        # function, where ci/lint.py's host-sync rule looks
+        def land(flight):
+            """The step's tokens read, emitted and retired; returns
+            the experts it touched (a routed model)."""
+            with telemetry.span("serve_token_fetch"):
+                toks = np.asarray(flight.nxt)  # sync-ok: the per-iteration token read
+            # a routed model's statistics ride behind the tokens
+            touched = self._count_moe(toks[B:], decode=True)
+            with telemetry.span("serve_emit") as sp_emit:
+                before = len(events)
+                for s, req in enumerate(flight.reqs):
+                    # a row whose request left its slot since (EOS,
+                    # cancel, deadline) was computed and is dropped
+                    if req is None or req.slot != s:
+                        continue
+                    if self.keep_logits:
+                        req.logits = flight.logits[s]
+                    self._append_token(req, int(toks[s]), events)
+                sp_emit.set(emitted=len(events) - before)
+            return touched
+
+        if self._grow_due:
+            with telemetry.span("serve_grow"):
+                short = not self._grow(preempt=False)
+            if short and self._flight is not None:
+                flight, self._flight = self._flight, None
+                land(flight)
+                self._staged = None
+            if short:
+                with telemetry.span("serve_grow"):
+                    self._grow()
+            self._grow_due = False
+        t0 = time.monotonic()
+        if self._staged is None:
+            with telemetry.span("serve_decode_prep"):
+                self._staged = self._stage()
+        (rows, args, live_blocks, chained), self._staged = \
+            self._staged, None
+        running = sum(r is not None for r in rows)
+        if not running:
+            flight, self._flight = self._flight, None
+            if flight is not None:
+                land(flight)
+            return
+        # the watchdog below counts an injected hang as the step's
+        resilience.inject("serve", "step")
+        flight, self._flight = self._flight, None
         # live over allowed: the share of the allowed context
         # (running x max_blocks, what a gather at max_len reads) that
         # this step's slots hold
         self._m_live_blocks.inc(live_blocks)
-        self._m_allowed_blocks.inc(running * MB)
+        self._m_allowed_blocks.inc(running * self.max_blocks)
+        self._m_decode_steps.inc()
+        if chained:
+            self._m_chained.inc()
         fn = self._get_step_fn()
+        prev = flight.nxt if flight is not None else self._nxt0
         with telemetry.span("serve_decode", running=running,
                             live_blocks=live_blocks) as sp_dec:
-            *pools, nxt, logits = fn(
-                self._wts, *self._pools, tables, npast, tokens)
+            *pools, nxt, logits = fn(self._wts, *self._pools, *args,
+                                     prev)
             self._pools = tuple(pools)
-            # completion barrier (see _admit_one): the token read
-            # below already serializes the loop; waiting on the
-            # donated pools too keeps the NEXT dispatch off the slow
-            # path
-            jax.block_until_ready(self._pools[0])
-            if nxt.shape[0] > B:
-                # a routed model's statistics ride behind the tokens.
-                # Read here they are the step's one transfer (the
-                # token fetch below finds the host copy made), and
-                # the span can say how many experts the step read
-                sp_dec.set(experts_touched=self._count_moe(
-                    np.asarray(nxt)[B:], decode=True))  # sync-ok: the per-iteration token read
-        dt_step = sp_prep.elapsed + sp_dec.elapsed
+            self._flight = _Flight(nxt, logits, rows)
+            for req in rows:
+                if req is not None:
+                    req.n_past += 1
+            # the host's part, while the device computes
+            if flight is not None:
+                sp_dec.set(experts_touched=land(flight))
+            with telemetry.span("serve_grow"):
+                self._grow_due = not self._grow(preempt=False)
+            with telemetry.span("serve_decode_prep"):
+                self._staged = self._stage()
+            # completion barrier (see _admit_one): the next dispatch
+            # goes onto pools that are ready, off the slow path
+            with telemetry.span("serve_decode_wait"):
+                jax.block_until_ready(self._pools[0])
+        dt_step = time.monotonic() - t0
         if self.step_timeout > 0 and dt_step > self.step_timeout:
             tracing.trace_event(
                 "serve_step_overrun", engine=self.engine_id,
@@ -1366,18 +1564,6 @@ class ServingEngine:
                 "post-mortem follows when MXTPU_TRACE_DUMP is set",
                 dt_step, self.step_timeout)
             tracing.dump_on_fault("serve_step_overrun")
-        with telemetry.span("serve_token_fetch"):
-            toks = np.asarray(nxt)  # sync-ok: the per-iteration token read
-        with telemetry.span("serve_emit") as sp_emit:
-            before = len(events)
-            for i, req in enumerate(list(slots)):
-                if req is None:
-                    continue
-                req.n_past += 1
-                if self.keep_logits:
-                    req.logits = logits[i]
-                self._append_token(req, int(toks[i]), events)
-            sp_emit.set(emitted=len(events) - before)
 
     def _append_token(self, req, tok, events):
         """Record one emitted token; retire the request when its
@@ -1426,7 +1612,7 @@ class ServingEngine:
         block freed in the SAME iteration the terminal was decided,
         so the next admission sees the memory."""
         open_phase = self._close_wait(req, now)
-        self._sched.clear(req)
+        self._vacate(req)
         if req.block_ids:
             self.pool.free(req.block_ids)
         req.block_ids = []

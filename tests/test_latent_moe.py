@@ -95,10 +95,10 @@ def _gap(got, want, over=np.max):
 
 
 def _served_logits(eng, prompts, new):
-    """Each request's logits at every position it decided after the
-    first, by driving ``step()`` one at a time (``keep_logits`` keeps
-    the last; the step that admits a request also decodes it once,
-    so the prefill's own logits are overwritten there)."""
+    """Each request's logits at every position it decided, by driving
+    ``step()`` one at a time (``keep_logits`` keeps the last; a step
+    lands at most one token a request: the admission's from its
+    prefill, each later one from the decode step before)."""
     reqs = [eng.submit(p, new) for p in prompts]
     got = {r.id: [] for r in reqs}
     while eng.has_work():
@@ -212,6 +212,39 @@ def test_absorbed_decode_is_expanded_attention(f32):
         full = block.forward(mx.nd.array(seq, dtype="int32")).asnumpy()
         at = len(prompt) + np.arange(4)
         assert _gap(np.stack(got[r.id][-4:]), full[0, at]) < 2e-5
+
+
+def _chained_against_the_forward(block):
+    """Serve three prompts through the chained decode loop, the later
+    two admitted while a decode step is in flight, with budgets that
+    end at different steps, and hold every served token to the eager
+    forward's greedy choice over the prompt and the tokens before it.
+    Prompts and budgets add up to one length and one prefill bucket:
+    one forward and one prefill program to compile."""
+    prompts = [t[:n] for t, n in zip(_tokens(3, 13, 5), (13, 11, 9))]
+    budgets = (5, 7, 9)
+    eng = ServingEngine(block, max_batch=3, block_size=4, num_blocks=80,
+                        max_len=64)
+    reqs = [eng.submit(prompts[0], budgets[0])]
+    for p, n in zip(prompts[1:], budgets[1:]):
+        eng.step()
+        assert eng._flight is not None      # admitted mid-flight
+        reqs.append(eng.submit(p, n))
+    eng.run()
+    assert telemetry.counter("serving_decode_chained_total").value > 0
+    assert all(r.state == "finished" for r in reqs)
+    seqs = np.stack([np.concatenate([p, r.generated])
+                     for r, p in zip(reqs, prompts)])
+    full = block.forward(mx.nd.array(seqs, dtype="int32")).asnumpy()
+    for row, r, p in zip(full, reqs, prompts):
+        assert list(np.argmax(row[len(p) - 1:-1], -1)) == r.generated
+
+
+def test_the_chained_loop_serves_the_forwards_tokens(f32):
+    """Decode steps whose tokens come from the step before on the
+    device, admissions while a step is in flight, budgets that end in
+    flight: float32, every served token is the eager forward's."""
+    _chained_against_the_forward(f32[0])
 
 
 def test_a_long_context_is_read_in_passes_of_keys(f32):

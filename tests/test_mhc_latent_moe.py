@@ -29,8 +29,8 @@ from incubator_mxnet_tpu.gluon.model_zoo.latent_moe import \
     LatentMoELM  # noqa: E402
 from incubator_mxnet_tpu.serving import ServingEngine  # noqa: E402
 
-from test_latent_moe import CFG as LATENT, _gap, \
-    _served_logits  # noqa: E402
+from test_latent_moe import CFG as LATENT, _chained_against_the_forward, \
+    _gap, _served_logits  # noqa: E402
 
 # hidden 64, 4 streams, 4 heads, latent 16 + rope 8, 4 experts top 2,
 # one dense and one expert layer; YaRN as the configuration has it
@@ -122,6 +122,13 @@ def test_prefill_then_eight_decode_steps_through_the_latent_pool(built):
             low = np.asarray(ref.logits(params, seq, CFG, "fp8"))[0]
             assert _gap(low[at[1:]], want[at[1:]],
                         np.median) > 2 * TOL[dtype]
+
+
+def test_the_chained_loop_serves_the_forwards_tokens(f32):
+    """The four-stream residual through the chained decode loop:
+    admissions while a step is in flight, budgets that end in flight;
+    float32, every served token is the eager forward's."""
+    _chained_against_the_forward(f32[0])
 
 
 @pytest.mark.parametrize("spread,rows", [(0.5, 3e-6), (2.4, 0.1),

@@ -176,6 +176,279 @@ def test_eos_stops_early_and_frees_blocks():
     assert eng.pool.num_allocated == 0
 
 
+# ------------------------------------------------- the chained loop
+def _in_flight(eng):
+    """The requests with a row in the decode step not landed yet."""
+    f = eng._flight
+    return [] if f is None else [r for r in f.reqs if r is not None]
+
+
+def test_chained_loop_matches_generate_with_admissions_mid_flight():
+    """A decode step takes its tokens from the step before on the
+    device; a request admitted while a step is in flight takes its
+    first one from the host.  Over staggered admissions, budgets that
+    end at different steps and a shared prefix, every request's
+    tokens are sequential generate()'s."""
+    net = _tiny()
+    rs = np.random.RandomState(59)
+    system = list(rs.randint(0, VOCAB, 8))     # 2 full blocks @ bs=4
+    prompts = [list(rs.randint(0, VOCAB, n)) for n in (5, 9, 3)] + \
+        [system + list(rs.randint(0, VOCAB, n)) for n in (2, 6)]
+    budgets = [7, 3, 10, 5, 8]
+    refs = [_gen_ref(net, p, n) for p, n in zip(prompts, budgets)]
+    eng = ServingEngine(net, max_batch=3, block_size=4, num_blocks=64)
+    reqs = [eng.submit(prompts[0], budgets[0])]
+    for p, n in zip(prompts[1:], budgets[1:]):
+        eng.step()
+        assert _in_flight(eng)              # admitted mid-flight
+        reqs.append(eng.submit(p, n))
+    eng.run()
+    for req, ref in zip(reqs, refs):
+        assert req.state == FINISHED
+        assert [int(t) for t in req.tokens] == ref
+    assert eng._flight is None and not eng.has_work()
+    assert eng.pool.live() == eng.cache.block_refs()
+
+
+def test_a_budget_that_ends_in_flight_idles_the_slots_next_row():
+    """A request whose last token is in the step in flight has no row
+    in the next step: position 0, the scratch block's table row.  It
+    retires when that token lands, one step later."""
+    net = _tiny()
+    rs = np.random.RandomState(61)
+    prompts = [list(rs.randint(0, VOCAB, n)) for n in (6, 7)]
+    eng = ServingEngine(net, max_batch=2, block_size=4, num_blocks=64,
+                        prefix_cache=False)
+    short, long_ = eng.submit(prompts[0], 2), eng.submit(prompts[1], 6)
+    eng.step()              # both admitted (a token each), a step out
+    assert eng._flight.reqs == [short, long_]
+    rows, (tables, npast, tokens, fresh), live, chained = eng._staged
+    assert rows == [None, long_] and chained
+    tables, npast, fresh = (np.asarray(a) for a in (tables, npast, fresh))
+    assert int(npast[0]) == 0 and not tables[0].any()
+    assert int(npast[1]) == long_.n_past and tables[1].any()
+    assert not fresh.any()          # long's token comes from the device
+    eng.step()              # short's second token lands: it retires
+    assert eng._flight.reqs == [None, long_]
+    assert short.state == FINISHED and short.block_ids == []
+    eng.run()
+    for req, prompt in zip((short, long_), prompts):
+        assert [int(t) for t in req.tokens] == \
+            _gen_ref(net, prompt, req.max_new_tokens)
+
+
+def test_an_eos_retirement_drops_one_row_in_its_own_block():
+    """A request whose EOS lands while the next step is in flight:
+    that step's row for it is computed and dropped.  The row lands in
+    the request's own block, which the prefix cache does not hold as
+    full, and touches no cached block; has_work() stays true until it
+    has landed; a later prefill that takes the freed blocks is not
+    corrupted."""
+    net = _tiny()
+    rs = np.random.RandomState(67)
+    prompt = list(rs.randint(0, VOCAB, 9))     # 2 full blocks @ bs=4
+    new = _gen_ref(net, prompt, 12)[len(prompt):]
+    # the first new token after the second whose value is new: EOS
+    # lands from a decode step, not from the prefill
+    at = next(i for i in range(2, 12) if new[i] not in new[:i])
+    eng = ServingEngine(net, max_batch=1, block_size=4, num_blocks=16)
+    req = eng.submit(prompt, 12, eos_id=new[at])
+    while not req.done:
+        pools = [np.asarray(a) for pool in eng._pools for a in pool]
+        pos = req.n_past            # where this iteration's step writes
+        blk = req.block_ids[pos // 4] if req.block_ids else None
+        cached = eng.cache.block_refs()
+        eng.step()
+    assert req.generated == new[:at + 1]
+    # the step dispatched before the EOS landed had a row for it
+    assert eng._flight.reqs == [req] and not eng._sched.any_running()
+    assert eng.has_work()
+    after = [np.asarray(a) for pool in eng._pools for a in pool]
+    assert blk is not None and blk not in cached
+    assert all(not np.array_equal(a[blk, pos % 4], b[blk, pos % 4])
+               for a, b in zip(after, pools))
+    for b in cached:
+        assert all(np.array_equal(x[b], y[b]) for x, y in zip(after, pools))
+    assert eng.step() == []         # the dropped row lands: nothing
+    assert not eng.has_work() and eng._flight is None
+    assert eng.pool.live() == eng.cache.block_refs()
+    # a prefill into the freed blocks
+    other = list(rs.randint(0, VOCAB, 13))
+    r2 = eng.submit(other, 6)
+    eng.step()
+    assert blk in r2.block_ids
+    eng.run()
+    assert [int(t) for t in r2.tokens] == _gen_ref(net, other, 6)
+
+
+@pytest.mark.parametrize("how", ["cancel", "deadline"])
+def test_a_reap_with_a_step_in_flight_drops_its_row(how):
+    """A cancel or a blown deadline reaped while the request has a row
+    in flight: the request keeps what it emitted (the row in flight is
+    dropped), its blocks are freed, and its batchmate is exact."""
+    from incubator_mxnet_tpu.serving import CANCELLED, EXPIRED
+    import time
+    net = _tiny()
+    rs = np.random.RandomState(71)
+    prompts = [list(rs.randint(0, VOCAB, n)) for n in (7, 5)]
+    eng = ServingEngine(net, max_batch=2, block_size=4, num_blocks=64,
+                        prefix_cache=False)
+    gone = eng.submit(prompts[0], 10, deadline=600.0)
+    kept = eng.submit(prompts[1], 10)
+    for _ in range(3):
+        eng.step()
+    assert gone in _in_flight(eng)
+    emitted = list(gone.generated)
+    if how == "cancel":
+        assert eng.cancel(gone.id)
+    else:
+        gone.deadline_ts = time.monotonic() - 1.0
+        eng._deadline_next = 0.0
+    eng.step()
+    assert gone.state == (CANCELLED if how == "cancel" else EXPIRED)
+    assert gone.generated == emitted and gone.block_ids == []
+    assert [int(t) for t in gone.tokens] == \
+        _gen_ref(net, prompts[0], len(emitted))
+    eng.run()
+    assert [int(t) for t in kept.tokens] == _gen_ref(net, prompts[1], 10)
+    assert eng.pool.num_allocated == 0
+
+
+def test_a_preemption_lands_the_step_in_flight_first():
+    """A runner that needs a block the pool cannot give, while a step
+    is in flight: that step lands before any runner is freed, so the
+    victim keeps its token, and the next step takes every token from
+    the host.  Outputs are exact."""
+    net = _tiny()
+    rs = np.random.RandomState(19)
+    prompts = [list(rs.randint(0, VOCAB, n)) for n in (9, 10)]
+    refs = [_gen_ref(net, p, 14) for p in prompts]
+    eng = ServingEngine(net, max_batch=2, block_size=4, num_blocks=12,
+                        prefix_cache=False)
+    seen, preempt = [], eng._preempt
+
+    def watched(req):
+        seen.append(eng._flight)
+        preempt(req)
+    eng._preempt = watched
+    reqs = [eng.submit(p, 14) for p in prompts]
+    while eng.has_work():
+        flying = eng._flight is not None
+        n = len(seen)
+        chained = _counter("serving_decode_chained_total")
+        steps = _counter("serving_decode_steps_total")
+        eng.step()
+        if len(seen) > n:
+            # a step was in flight before, and the step dispatched
+            # after the preemption took every token from the host
+            assert flying
+            assert _counter("serving_decode_steps_total") == steps + 1
+            assert _counter("serving_decode_chained_total") == chained
+    assert seen and all(f is None for f in seen)
+    for req, ref in zip(reqs, refs):
+        assert req.state == FINISHED
+        assert [int(t) for t in req.tokens] == ref
+    assert eng.pool.num_allocated == 0
+
+
+class _Untouchable:
+    """Stands in for a device array that must not be read."""
+
+    def __getattr__(self, name):
+        raise AssertionError(f"a device array was touched: .{name}")
+
+    def __array__(self, *args, **kwargs):
+        raise AssertionError("a device array was read")
+
+
+def test_snapshot_cancel_drain_with_a_step_in_flight_read_no_device():
+    """snapshot(), cancel() and drain() with a step in flight read
+    nothing from the device.  The snapshot takes every request as of
+    its last emitted token; restored, the tokens in flight are
+    recomputed, token-exact under greedy decoding, and a drain
+    finishes the running batch exactly."""
+    net = _tiny()
+    rs = np.random.RandomState(73)
+    prompts = [list(rs.randint(0, VOCAB, n)) for n in (4, 11, 7)]
+    refs = [_gen_ref(net, p, 9) for p in prompts]
+    eng = ServingEngine(net, max_batch=2, block_size=4, num_blocks=64,
+                        prefix_cache=False)
+    reqs = [eng.submit(p, 9) for p in prompts]
+    for _ in range(4):
+        eng.step()
+    flying = _in_flight(eng)
+    assert len(flying) == 2
+    # the loop's device arrays, out of reach while they run
+    flight, pools = eng._flight, eng._pools
+    kept = flight.nxt, flight.logits
+    flight.nxt = flight.logits = _Untouchable()
+    eng._pools = tuple([_Untouchable()] * len(p) for p in pools)
+    with pytest.raises(AssertionError, match="device array"):
+        np.asarray(eng._flight.nxt)
+    snap = eng.snapshot()
+    assert eng.cancel(12345) is False
+    assert eng.drain(run=False) == {}
+    (flight.nxt, flight.logits), eng._pools = kept, pools
+    assert {e["id"]: e["generated"] for e in snap["requests"]} == \
+        {r.id: r.generated for r in reqs}
+    assert eng.has_work()           # the step in flight, draining
+    out = ServingEngine.restore(net, snap).run()
+    for req, ref in zip(reqs, refs):
+        assert out[req.id] == ref
+    done = eng.drain()
+    assert sorted(done) == sorted(r.id for r in flying)
+    for req in flying:
+        assert done[req.id] == refs[reqs.index(req)]
+    assert not eng.has_work() and eng._flight is None
+
+
+def test_decode_steps_chain_but_the_first_after_an_idle_engine():
+    """``serving_decode_chained_total`` beside
+    ``serving_decode_steps_total``: every decode step takes its tokens
+    from the step before on the device, but the first after an idle
+    engine."""
+    net = _tiny()
+    eng = ServingEngine(net, max_batch=2, block_size=4, num_blocks=64)
+    steps0 = _counter("serving_decode_steps_total")
+    chained0 = _counter("serving_decode_chained_total")
+    for wave in (1, 2):
+        eng.submit([1, 2, 3, 4, 5], 6)
+        eng.submit([7, 8, 9], 4)
+        eng.run()
+        steps = _counter("serving_decode_steps_total") - steps0
+        chained = _counter("serving_decode_chained_total") - chained0
+        assert steps - chained == wave and chained >= 4 * wave
+
+
+@pytest.mark.parametrize("committed", [True, False])
+def test_the_chained_program_compiles_in_a_warm_up_that_never_chains(
+        committed):
+    """The benchmark warms the engine with requests of two tokens: the
+    decode program runs once a request, every token from the host.
+    Later steps take theirs on the device from the same compiled
+    program: nothing compiles again, whether the weights are committed
+    to a device (then so is every program's output) or not (then no
+    output is, and the first ``prev`` must not be either)."""
+    import jax
+    net = _tiny()
+    eng = ServingEngine(net, max_batch=2, block_size=4, num_blocks=64,
+                        prefix_cache=False)
+    if not committed:
+        eng._wts = jax.tree.map(lambda a: jax.device_put(np.asarray(a)),
+                                eng._wts)
+    for prompt in ([1, 2, 3], [3, 2, 1]):  # the second on its pools
+        eng.submit(prompt, 2)
+        eng.run()
+    assert eng._nxt0.committed == committed
+    compiles = _counter("xla_compiles_total")
+    eng.submit([4, 5, 6], 7)
+    eng.step()
+    eng.submit([6, 5], 5)                   # admitted mid-flight
+    eng.run()
+    assert _counter("xla_compiles_total") == compiles
+    assert eng.trace_counts == {"prefill_4": 1, "decode": 1}
+
+
 # --------------------------------------------------- trace-count guards
 def test_admission_retirement_never_retrace():
     net = _tiny()

@@ -28,6 +28,7 @@ from incubator_mxnet_tpu.gluon.model_zoo.transformer import \
     _flash_on_mesh
 from incubator_mxnet_tpu.ops.flash import flash_attention
 from incubator_mxnet_tpu.perf import memory_planner as mp
+from incubator_mxnet_tpu.serving import engine as serving
 
 import _graphs
 from test_memory_planner import GRAPH_INPUTS, _train_compiled
@@ -220,19 +221,39 @@ def opt_cell(one_chip):
                  (ints(row_blocks), ints(), ints(1024), ints())))
 
 
+def _engine_decode(step, slots, wts, groups, ints):
+    """The decode program as the engine jits it (``serving.engine``):
+    the model's step under the chained loop's entry select, which takes
+    each slot's token from the host or from the previous step's
+    ``next`` (its shape the step's own), the pools donated; lowered."""
+    inner = jax.jit(step)
+    nxt = inner.eval_shape(wts, *groups, *ints)[-2]
+    on = ints[0].sharding
+    return serving.program("decode", serving.chained(inner, slots),
+                           len(groups)).lower(
+        wts, *groups, *ints,
+        jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=on),
+        jax.ShapeDtypeStruct(nxt.shape, nxt.dtype, sharding=on))
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_paged_programs_copy_no_pool_on_v5e(opt_cell, program):
     """The serve cell's programs as the engine jits them (pools
-    donated), compiled for the chip: the decode step reads through the
-    kernel, one a layer; no instruction copies an array of a pool's
-    shape (the parent's decode step held 32 such copies of 268.6 MB,
+    donated; the decode step under the chained loop's entry select),
+    compiled for the chip: the decode step reads through the kernel,
+    one a layer; no instruction copies an array of a pool's shape (a
+    decode step that copied its pools held 32 such copies of 268.6 MB,
     its temporaries 5.39 GB); every pool that goes in comes out in the
     same buffer; the decode program's temporaries stay under 0.5 GB.
     Counts of a compile, no times."""
     fn, rest = opt_cell[program]
     pools, layers = opt_cell["pools"], opt_cell["layers"]
-    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
-        opt_cell["wts"], *pools, *rest).compile()
+    if program == "decode":
+        lowered = _engine_decode(fn, 16, opt_cell["wts"], pools, rest)
+    else:
+        lowered = serving.program("prefill_1024", fn, 2).lower(
+            opt_cell["wts"], *pools, *rest)
+    compiled = lowered.compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == \
         (layers if program == "decode" else 0)
@@ -287,7 +308,7 @@ def latent_cell(one_chip):
 
 def test_latent_decode_reads_through_the_table_on_v5e(latent_cell):
     """The latent cell's decode step as the engine jits it (the pool
-    donated), compiled for the chip: one paged read a layer, one pool
+    donated, the chained loop's entry select), compiled for the chip: one paged read a layer, one pool
     for keys and values; no instruction holds a slot's allowed context
     (64 x 8192 rows of 640 lanes, 671 MB a layer, the parent's
     gather); every pool comes out in the buffer it went in; the
@@ -296,8 +317,9 @@ def test_latent_decode_reads_through_the_table_on_v5e(latent_cell):
     compile, no times."""
     lm, pools = latent_cell["lm"], latent_cell["pools"]
     assert lm._paged_read(16, "tpu")["read"] == "kernel"
-    compiled = jax.jit(latent_cell["decode"], donate_argnums=(1,)).lower(
-        latent_cell["wts"], pools, *latent_cell["args"]).compile()
+    compiled = _engine_decode(latent_cell["decode"], latent_cell["slots"],
+                              latent_cell["wts"], (pools,),
+                              latent_cell["args"]).compile()
     text = compiled.as_text()
     reads = [line for line in text.splitlines()
              if "tpu_custom_call" in line
@@ -323,6 +345,58 @@ def test_latent_decode_reads_through_the_table_on_v5e(latent_cell):
     assert "hc_" not in text
 
 
+def _functions(text):
+    """name -> bodies of the functions of a lowered module, with the
+    numbers that tell repeated private names apart taken off (two
+    lowerings number them differently)."""
+    out = {}
+    for m in re.finditer(r"^  func\.func \w+ @([\w.$-]+)\(.*?\{\n(.*?)^  \}",
+                         text, re.M | re.S):
+        out.setdefault(re.sub(r"_\d+$", "", m.group(1)), []).append(
+            re.sub(r"(@[A-Za-z_]\w*?)_\d+\b", r"\1", m.group(2)))
+    return {name: sorted(bodies) for name, bodies in out.items()}
+
+
+@pytest.mark.parametrize("cell", ["opt_cell", "latent_cell"])
+def test_the_engine_runs_the_models_own_programs_on_v5e(request, cell):
+    """What the chained decode loop changes in the device's work, at
+    the serve cells' shapes lowered for the chip: the decode program
+    is the model's own step, called as it is (its body the text of the
+    step lowered alone, every function under it the same) behind one
+    select at the entry; a prefill program is the text of the model's
+    own prefill lowered alone.  Both are lowered in one process, so
+    the kernels' bodies carry the same source lines."""
+    c = request.getfixturevalue(cell)
+    wts = c["wts"]
+    if cell == "opt_cell":
+        (step, ints), (prefill, rest) = c["decode"], c["prefill"]
+        groups, slots = tuple(c["pools"]), 16
+    else:
+        step, ints, groups, slots = (c["decode"], c["args"],
+                                     (c["pools"],), c["slots"])
+        prefill = c["lm"]._build_paged_prefill(1024, 512, 16)
+        leaf = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                                 sharding=ints[0].sharding)
+        rest = (leaf((512,)), leaf(()), leaf((1024,)), leaf(()))
+    donate = tuple(range(1, 1 + len(groups)))
+    alone = _functions(jax.jit(step, donate_argnums=donate).lower(
+        wts, *groups, *ints).as_text())
+    served = _functions(_engine_decode(step, slots, wts, groups,
+                                       ints).as_text())
+    assert served.pop("step") == alone.pop("main")
+    entry, = served.pop("main")
+    assert served == alone
+    ops = [re.search(r"= (\S+)", line).group(1)
+           for line in entry.splitlines() if " = " in line]
+    assert ops == ["stablehlo.slice", "stablehlo.select", "call"], ops
+    assert "call @step(" in entry
+    alone = jax.jit(prefill, donate_argnums=donate).lower(wts, *groups,
+                                                          *rest)
+    served = serving.program("prefill_1024", prefill,
+                             len(groups)).lower(wts, *groups, *rest)
+    assert _functions(served.as_text()) == _functions(alone.as_text())
+
+
 def _abstract_latent(lm, leaf, blocks):
     """A ``LatentMoELM``'s weights and pools as abstract leaves."""
     wts = {"embed": leaf(*lm.embed_weight.shape),
@@ -338,7 +412,7 @@ def test_hyper_connected_decode_compiles_for_v5e(one_chip):
     """``LatentMoELM`` at ``benchmark/configs/xing4.0-29b-a4b.json``:
     its 6-layer decode step at the cell's shapes (64 slots, 512 blocks
     of 16 a row, 19,521 blocks of 640 lanes, bfloat16) as the engine
-    jits it, for the chip: one paged read a layer, every pool out in
+    jits it (the chained loop's entry select), for the chip: one paged read a layer, every pool out in
     the buffer it came in, temporaries under 0.1 GB (the streams of 64
     tokens are 7 MB); 12 hyper-connections, each with the same count
     of operations under ``hc_map`` (the mapping and gates in the
@@ -356,9 +430,9 @@ def test_hyper_connected_decode_compiles_for_v5e(one_chip):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     wts, pools = _abstract_latent(lm, leaf, 19521)
     ints = functools.partial(leaf, dtype=jnp.int32)
-    compiled = jax.jit(lm._build_paged_step(64, 512, 16),
-                       donate_argnums=(1,)).lower(
-        wts, pools, ints(64, 512), ints(64), ints(64)).compile()
+    compiled = _engine_decode(lm._build_paged_step(64, 512, 16), 64, wts,
+                              (pools,), (ints(64, 512), ints(64),
+                                         ints(64))).compile()
     text = compiled.as_text()
     reads = [line for line in text.splitlines()
              if "tpu_custom_call" in line

@@ -618,27 +618,35 @@ def test_engine_step_spans_form_the_tree_with_request_ids(
     assert tracing.events("span") == []     # no session: no event
     req = eng.submit([5, 6, 7, 8, 9], 3)    # suffix 5 -> bucket 8
     with profiler_session() as rec:
-        eng.step()                          # admission + one decode
-        eng.step()                          # decode only
+        eng.step()                          # admission + a dispatch
+        eng.step()                          # a dispatch + a landing
     eng.run()
     evs = newest_spans()
     by_id, kids = _tree(evs)
     first, second = kids[None]
     assert first["name"] == second["name"] == "serve_step"
     assert first["waiting"] == 1 and first["running"] == 0
-    assert first["emitted"] == 2 and second["emitted"] == 1
+    # the chained loop: a step's tokens land in the next iteration
+    assert first["emitted"] == 1 and second["emitted"] == 1
     assert [c["name"] for c in kids[first["id"]]] == [
         "serve_reap", "serve_admit", "serve_grow",
-        "serve_decode_prep", "serve_decode", "serve_token_fetch",
-        "serve_emit"]
+        "serve_decode_prep", "serve_decode"]
     assert [c["name"] for c in kids[second["id"]]] == [
-        "serve_reap", "serve_grow", "serve_decode_prep",
-        "serve_decode", "serve_token_fetch", "serve_emit"]
-    # a routed model's decode says how many experts the step read:
-    # one slot, 2 routed layers, 2 experts a token and layer
-    for step in (first, second):
-        decode = kids[step["id"]][-3]
-        assert decode.get("experts_touched") == (4 if routed else None)
+        "serve_reap", "serve_decode"]
+    # the host's part lies inside the decode span, while the device
+    # computes: the step before lands (none before the first), the
+    # growth and the inputs for the step after, then the wait
+    first_dec, second_dec = (kids[s["id"]][-1] for s in (first, second))
+    assert [c["name"] for c in kids[first_dec["id"]]] == [
+        "serve_grow", "serve_decode_prep", "serve_decode_wait"]
+    assert [c["name"] for c in kids[second_dec["id"]]] == [
+        "serve_token_fetch", "serve_emit", "serve_grow",
+        "serve_decode_prep", "serve_decode_wait"]
+    # a routed model's decode says how many experts the step that
+    # landed in it read: one slot, 2 routed layers, 2 experts a token
+    # and layer
+    assert first_dec.get("experts_touched") is None
+    assert second_dec.get("experts_touched") == (4 if routed else None)
     admit = kids[first["id"]][1]
     assert admit["rid"] == req.id and admit["cached_tokens"] == 0
     assert admit["slot"] == req.last_slot
