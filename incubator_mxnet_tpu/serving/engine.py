@@ -301,6 +301,13 @@ class ServingEngine:
                                 np.int32)
         self._staged = None
         self._grow_due = False
+        # the host's gap before the next decode launch, in which the
+        # device has nothing queued but the prefills: when the last
+        # decode barrier returned (None: the engine has idled since),
+        # the device seconds of the prefills run in it, the admissions
+        self._gap_t0 = None
+        self._gap_busy = 0.0
+        self._gap_admitted = 0
         self._prefill_fns = {}
         self.trace_counts = {}
         self._next_id = 0
@@ -1288,9 +1295,10 @@ class ServingEngine:
         with telemetry.span("serve_prefill", rid=req.id,
                             tokens=len(suffix),
                             bucket=bucket) as sp_pre:
-            *pools, nxt, logits = fn(
-                self._wts, *self._pools,
-                row, np.int32(n_cached), suf, np.int32(len(suffix)))
+            with telemetry.span("serve_prefill_dispatch") as sp_launch:
+                *pools, nxt, logits = fn(
+                    self._wts, *self._pools,
+                    row, np.int32(n_cached), suf, np.int32(len(suffix)))
             self._pools = tuple(pools)
             # completion barrier, not a transfer: dispatching the
             # next call while its DONATED pool buffers are still
@@ -1298,6 +1306,9 @@ class ServingEngine:
             # runtime's donation bookkeeping
             jax.block_until_ready(self._pools[0])
         req.prefill_s += sp_pre.elapsed
+        # the device computes through a prefill, not through its launch
+        self._gap_busy += sp_pre.elapsed - sp_launch.elapsed
+        self._gap_admitted += 1
         tracing.trace_event(
             "serve_prefill", rid=req.id, engine=self.engine_id,
             slot=slot,
@@ -1450,6 +1461,20 @@ class ServingEngine:
         return (rows, (put(tables), put(npast), put(tokens),
                        put(fresh)), live, chained)
 
+    def _gap_fields(self):
+        """The fields of a decode launch's span: ``gap_s`` since the
+        last decode barrier returned, ``busy_s`` of it the device spent
+        on prefills, and the ``admitted`` prompts; none after an idle
+        engine, and none while no session records them.  Starts the
+        next gap's sums."""
+        fields = {}
+        if self._gap_t0 is not None and telemetry.recording():
+            fields = {"gap_s": time.perf_counter() - self._gap_t0,
+                      "busy_s": self._gap_busy,
+                      "admitted": self._gap_admitted}
+        self._gap_busy, self._gap_admitted = 0.0, 0
+        return fields
+
     def _decode_once(self, events):
         """The decode part of one iteration: dispatch the next decode
         step, its tokens taken from the step in flight on the device;
@@ -1518,6 +1543,7 @@ class ServingEngine:
             flight, self._flight = self._flight, None
             if flight is not None:
                 land(flight)
+            self._gap_t0 = None
             return
         # the watchdog below counts an injected hang as the step's
         resilience.inject("serve", "step")
@@ -1534,8 +1560,10 @@ class ServingEngine:
         prev = flight.nxt if flight is not None else self._nxt0
         with telemetry.span("serve_decode", running=running,
                             live_blocks=live_blocks) as sp_dec:
-            *pools, nxt, logits = fn(self._wts, *self._pools, *args,
-                                     prev)
+            with telemetry.span("serve_decode_dispatch",
+                                **self._gap_fields()):
+                *pools, nxt, logits = fn(self._wts, *self._pools,
+                                         *args, prev)
             self._pools = tuple(pools)
             self._flight = _Flight(nxt, logits, rows)
             for req in rows:
@@ -1552,6 +1580,7 @@ class ServingEngine:
             # goes onto pools that are ready, off the slow path
             with telemetry.span("serve_decode_wait"):
                 jax.block_until_ready(self._pools[0])
+            self._gap_t0 = time.perf_counter()  # nothing queued now
         dt_step = time.monotonic() - t0
         if self.step_timeout > 0 and dt_step > self.step_timeout:
             tracing.trace_event(

@@ -59,7 +59,7 @@ from .utils.env import get_env
 __all__ = ["Counter", "Gauge", "Histogram", "MetricRegistry",
            "TelemetryEmitter", "AnomalyWatch", "enabled",
            "get_registry", "counter", "gauge", "histogram", "span",
-           "snapshot", "prometheus_text", "heartbeat_payload",
+           "recording", "snapshot", "prometheus_text", "heartbeat_payload",
            "start_emitter", "maybe_start_emitter", "stop_emitter",
            "anomaly_watch", "anomaly_verdicts"]
 
@@ -420,7 +420,11 @@ def _armed():
     """The TraceAnnotation class while a ``jax.profiler`` session is
     recording, else None.  One flag read.  ``session`` counts the
     changes from off to on that spans have seen: two sessions with no
-    span between them count as one."""
+    span between them count as one.  Each such change leaves one
+    ``span_clock`` event: ``perf_counter`` and the wall clock's
+    ``time_ns`` read back to back, so that a reader can place the
+    recorder's ``t0``/``t1`` on the trace, whose host events are
+    stamped in wall-clock nanoseconds."""
     cls = _TRACE_ANNOTATION or _hook_jax()
     if cls is None or not cls.is_enabled():
         _SESSION["on"] = False
@@ -430,7 +434,18 @@ def _armed():
             if not _SESSION["on"]:
                 _SESSION["n"] += 1
                 _SESSION["on"] = True
+                from . import tracing
+                pc, ns = time.perf_counter(), time.time_ns()
+                tracing.trace_event("span_clock", session=_SESSION["n"],
+                                    perf_counter=pc, time_ns=ns)
     return cls
+
+
+def recording():
+    """Whether a ``jax.profiler`` session records, so that a span's
+    fields reach the trace and the flight recorder: fields that cost
+    work to build are built only then."""
+    return enabled() and _armed() is not None
 
 
 def _record_span(sid, parent, name, t0, t1, fields):

@@ -314,7 +314,7 @@ register_env("MXTPU_ANOMALY_COOLDOWN", int, 8,
              "regression is one episode, not a flapping stream)")
 
 # Flight recorder / tracing (tracing.py; docs/observability.md).
-register_env("MXTPU_TRACE_BUFFER", int, 4096,
+register_env("MXTPU_TRACE_BUFFER", int, 16384,
              "flight-recorder ring-buffer capacity (structured "
              "events retained for post-mortem dumps); older events "
              "are evicted and counted as dropped")

@@ -26,6 +26,7 @@ import signal  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
 import threading  # noqa: E402
+import warnings  # noqa: E402
 
 import pytest  # noqa: E402
 
@@ -74,17 +75,29 @@ class _Session:
     def __init__(self, logdir):
         self.logdir = logdir
 
-    def host_events(self, prefix="mx."):
-        """[(name, start_ns, duration_ns)] of the ``/host:CPU`` plane's
-        events whose names start with ``prefix``."""
+    def _data(self):
         from jax.profiler import ProfileData
         path, = glob.glob(os.path.join(
             self.logdir, "plugins", "profile", "*", "*.xplane.pb"))
+        return ProfileData.from_file(path)
+
+    def host_events(self, prefix="mx."):
+        """[(name, start_ns, duration_ns)] of the ``/host:CPU`` plane's
+        events whose names start with ``prefix``."""
         return [(e.name, e.start_ns, e.duration_ns)
-                for plane in ProfileData.from_file(path).planes
+                for plane in self._data().planes
                 if plane.name == "/host:CPU"
                 for line in plane.lines for e in line.events
                 if e.name.startswith(prefix)]
+
+    def start_time_ns(self):
+        """The session's ``profile_start_time``: the wall-clock
+        nanoseconds from which every event's ``start_ns`` counts."""
+        with warnings.catch_warnings():     # jaxlib's stats type
+            warnings.simplefilter("ignore", DeprecationWarning)
+            return next(value for plane in self._data().planes
+                        for key, value in plane.stats
+                        if key == "profile_start_time")
 
 
 @pytest.fixture

@@ -449,6 +449,62 @@ def test_the_chained_program_compiles_in_a_warm_up_that_never_chains(
     assert eng.trace_counts == {"prefill_4": 1, "decode": 1}
 
 
+def test_each_decode_launch_accounts_for_the_gap_before_it(
+        profiler_session, newest_spans):
+    """In a traced window every decode step's launch is a
+    ``serve_decode_dispatch`` span inside its ``serve_decode``.  All
+    but the first after an idle engine carry the gap since the last
+    decode barrier: the admissions in it, and the device seconds of
+    their prefills (``serve_prefill`` less its launch)."""
+    from incubator_mxnet_tpu import tracing
+    net = _tiny()
+    eng = ServingEngine(net, max_batch=2, block_size=4, num_blocks=64,
+                        prefix_cache=False)
+    waves = ([1, 2, 3, 4, 5], 6), ([7, 8, 9], 4), ([2, 4, 6, 8], 5)
+    for prompt, n in waves:                 # both buckets compiled
+        eng.submit(prompt, n)
+    eng.run()
+    tracing.reset_for_tests()
+    steps0 = _counter("serving_decode_steps_total")
+    with profiler_session():
+        for _ in range(2):
+            for prompt, n in waves:         # the third waits for a slot
+                eng.submit(prompt, n)
+            eng.run()
+    evs = newest_spans()
+    by_id = {e["id"]: e for e in evs}
+    launches = [e for e in evs if e["name"] == "serve_decode_dispatch"]
+    assert len(launches) == \
+        _counter("serving_decode_steps_total") - steps0
+    assert all(by_id[e["parent"]]["name"] == "serve_decode"
+               for e in launches)
+    for e in evs:
+        if e["name"] == "serve_prefill":
+            assert [c["name"] for c in evs if c["parent"] == e["id"]] \
+                == ["serve_prefill_dispatch"]
+    assert ["gap_s" in e for e in launches].count(False) == 2
+    admits = [e for e in evs if e["name"] == "serve_admit"]
+    launch_s = {c["parent"]: c["t1"] - c["t0"] for c in evs
+                if c["name"] == "serve_prefill_dispatch"}
+    held = 0
+    for before, e in zip(launches, launches[1:]):
+        if "gap_s" not in e:
+            assert set(e) & {"busy_s", "admitted"} == set()
+            continue
+        assert 0 <= e["busy_s"] <= e["gap_s"]
+        mine = [a for a in admits if before["t0"] < a["t0"] < e["t0"]]
+        assert e["admitted"] == len(mine)
+        prefills = [p for p in evs if p["name"] == "serve_prefill"
+                    and p["parent"] in {a["id"] for a in mine}]
+        assert abs(e["busy_s"] - sum(p["t1"] - p["t0"] - launch_s[p["id"]]
+                                     for p in prefills)) < 1e-9
+        held += e["admitted"]
+    assert held == 2                        # the third of each wave
+    session = launches[0]["session"]
+    assert [c["session"] for c in tracing.events("span_clock")] \
+        == [session]
+
+
 # --------------------------------------------------- trace-count guards
 def test_admission_retirement_never_retrace():
     net = _tiny()

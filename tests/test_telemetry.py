@@ -203,6 +203,45 @@ def test_sessions_are_counted_so_the_newest_can_be_taken_alone(
     assert second["id"] > first["id"]
 
 
+def test_recording_is_true_only_while_a_session_records(
+        fresh_ring, profiler_session, monkeypatch):
+    """``recording()`` says when a span's fields would be kept, so that
+    a caller builds costly ones only then."""
+    assert not tel.recording()
+    with profiler_session():
+        assert tel.recording()
+        monkeypatch.setenv("MXTPU_TELEMETRY", "0")
+        assert not tel.recording()
+        monkeypatch.delenv("MXTPU_TELEMETRY")
+    assert not tel.recording()
+
+
+def test_a_session_leaves_one_clock_pair_that_puts_spans_on_the_trace(
+        fresh_ring, profiler_session, newest_spans):
+    """``span_clock`` (``perf_counter`` and ``time_ns``, back to back)
+    once a session: with it a span event's ``t0`` lands on its
+    ``mx.<name>`` annotation in the trace, whose host events count
+    wall-clock nanoseconds from the session's start."""
+    for _ in range(2):
+        with profiler_session() as rec:
+            for name in ("data_wait", "host_sync", "optimizer"):
+                with tel.span(name):
+                    time.sleep(0.002)
+        with tel.span("data_wait"):     # a span sees the session end
+            pass
+    clocks = fresh_ring.events("span_clock")
+    sessions = sorted({e["session"] for e in fresh_ring.events("span")})
+    assert [c["session"] for c in clocks] == sessions
+    clock = clocks[-1]
+    start = rec.start_time_ns()
+    traced = {n: s for n, s, _ in rec.host_events()}
+    evs = newest_spans()
+    assert len(evs) == 3
+    for e in evs:
+        at = clock["time_ns"] + 1e9 * (e["t0"] - clock["perf_counter"])
+        assert abs(at - start - traced["mx." + e["name"]]) < 1e6
+
+
 def test_a_retrace_inside_a_span_gives_a_compile_span_under_it(
         fresh_ring, profiler_session, newest_spans):
     import jax

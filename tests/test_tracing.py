@@ -633,15 +633,23 @@ def test_engine_step_spans_form_the_tree_with_request_ids(
         "serve_decode_prep", "serve_decode"]
     assert [c["name"] for c in kids[second["id"]]] == [
         "serve_reap", "serve_decode"]
-    # the host's part lies inside the decode span, while the device
-    # computes: the step before lands (none before the first), the
-    # growth and the inputs for the step after, then the wait
+    # the launch, then the host's part inside the decode span, while
+    # the device computes: the step before lands (none before the
+    # first), the growth and the inputs for the step after, the wait
     first_dec, second_dec = (kids[s["id"]][-1] for s in (first, second))
     assert [c["name"] for c in kids[first_dec["id"]]] == [
-        "serve_grow", "serve_decode_prep", "serve_decode_wait"]
+        "serve_decode_dispatch", "serve_grow", "serve_decode_prep",
+        "serve_decode_wait"]
     assert [c["name"] for c in kids[second_dec["id"]]] == [
-        "serve_token_fetch", "serve_emit", "serve_grow",
-        "serve_decode_prep", "serve_decode_wait"]
+        "serve_decode_dispatch", "serve_token_fetch", "serve_emit",
+        "serve_grow", "serve_decode_prep", "serve_decode_wait"]
+    # the first launch after an idle engine has no gap before it; the
+    # next one's gap took no prompt in
+    first_launch, second_launch = (kids[d["id"]][0]
+                                   for d in (first_dec, second_dec))
+    assert "gap_s" not in first_launch
+    assert second_launch["admitted"] == 0
+    assert second_launch["busy_s"] == 0.0 < second_launch["gap_s"]
     # a routed model's decode says how many experts the step that
     # landed in it read: one slot, 2 routed layers, 2 experts a token
     # and layer
@@ -656,11 +664,14 @@ def test_engine_step_spans_form_the_tree_with_request_ids(
         "serve_prefill", "serve_token_fetch")
     assert (prefill["rid"], prefill["tokens"], prefill["bucket"]) \
         == (req.id, 5, 8)
+    launch, = (c for c in kids[prefill["id"]] if c["name"] != "compile")
+    assert launch["name"] == "serve_prefill_dispatch"
     # the new bucket compiled inside the traced step: the compile is
-    # found under the prefill, by the name the program is counted under
+    # found under the prefill's launch, by the name the program is
+    # counted under
     named = [e for e in evs if e["name"] == "compile"
              and "serve_prefill_8" in e["fun_name"]]
-    assert [e["parent"] for e in named] == [prefill["id"]]
+    assert [e["parent"] for e in named] == [launch["id"]]
     _assert_children_lie_inside_in_order(
         [e for e in evs if e["name"] != "compile"])
     # a step's children cover it but for its own self time
@@ -753,8 +764,8 @@ def test_engine_programs_are_named_as_they_are_counted(
     under = {e["fun_name"]: by_id[e["parent"]]["name"]
              for e in by_id.values() if e["name"] == "compile"
              and "serve_" in e["fun_name"]}
-    assert under == {"jit(serve_prefill_4)": "serve_prefill",
-                     "jit(serve_decode)": "serve_decode"}
+    assert under == {"jit(serve_prefill_4)": "serve_prefill_dispatch",
+                     "jit(serve_decode)": "serve_decode_dispatch"}
     assert set(eng.trace_counts) == {"decode", "prefill_4"}
 
 
