@@ -38,7 +38,9 @@ read (enforced by ci/lint.py's host-sync rule over this module).  The
 loop is chained: a decode step takes its tokens from the step before
 on the device, and the host reads, emits and retires a step's tokens
 while the next step computes, so ``step()``'s events trail the device
-by one step (docs/serving.md, "The chained decode loop").
+by one step; nothing waits for a step's pools, so the next step is
+launched before the one in flight ends (docs/serving.md, "The chained
+decode loop").
 Telemetry rides the process registry: request/ token counters,
 queue-wait / TTFT / per-token histograms, occupancy and
 pool-utilization gauges.  ``MXTPU_FAULT_SPEC`` scope
@@ -302,9 +304,11 @@ class ServingEngine:
         self._staged = None
         self._grow_due = False
         # the host's gap before the next decode launch, in which the
-        # device has nothing queued but the prefills: when the last
-        # decode barrier returned (None: the engine has idled since),
-        # the device seconds of the prefills run in it, the admissions
+        # device has nothing queued but the prefills: when the host
+        # learned that the last decode step launched had ended (a
+        # prefill's barrier, a landing before the launch; None: not
+        # yet, or the engine has idled since), the device seconds of
+        # the prefills run in it, the admissions since that launch
         self._gap_t0 = None
         self._gap_busy = 0.0
         self._gap_admitted = 0
@@ -391,6 +395,8 @@ class ServingEngine:
             "serving_decode_steps_total")
         self._m_chained = telemetry.counter(
             "serving_decode_chained_total")
+        self._m_queued = telemetry.counter(
+            "serving_decode_queued_total")
         self._m_occ = telemetry.gauge("serving_batch_occupancy")
         self._m_util = telemetry.gauge(
             "serving_block_pool_utilization")
@@ -734,10 +740,10 @@ class ServingEngine:
         expired deadlines, blocks freed same-iteration) -> admit ->
         dispatch the next decode step -> land the step before it
         (token read, emit, retire) -> grow -> stage the next step's
-        inputs -> wait for the device.  Returns the ``(request,
-        token_id)`` events emitted this iteration: admissions' first
-        tokens and the tokens of the decode step dispatched by the
-        previous iteration."""
+        inputs; the step dispatched is left running.  Returns the
+        ``(request, token_id)`` events emitted this iteration:
+        admissions' first tokens and the tokens of the decode step
+        dispatched by the previous iteration."""
         events = []
         with telemetry.span("serve_step",
                             running=self._sched.n_running(),
@@ -1300,14 +1306,20 @@ class ServingEngine:
                     self._wts, *self._pools,
                     row, np.int32(n_cached), suf, np.int32(len(suffix)))
             self._pools = tuple(pools)
-            # completion barrier, not a transfer: dispatching the
-            # next call while its DONATED pool buffers are still
-            # pending hits a pathological slow path (~7x) in the
-            # runtime's donation bookkeeping
+            # the launch may go onto pools a decode step in flight
+            # still holds: the device runs the prefill behind it.  The
+            # barrier times the prefill for the span, and once it
+            # returns no decode step is left on the device
             jax.block_until_ready(self._pools[0])
         req.prefill_s += sp_pre.elapsed
-        # the device computes through a prefill, not through its launch
-        self._gap_busy += sp_pre.elapsed - sp_launch.elapsed
+        if self._gap_t0 is None:
+            # the host learns here that the decode step before has
+            # ended: the gap before the next launch starts now
+            self._gap_t0 = time.perf_counter()
+        else:
+            # a prefill inside the gap: the device computes through
+            # it, not through its launch
+            self._gap_busy += sp_pre.elapsed - sp_launch.elapsed
         self._gap_admitted += 1
         tracing.trace_event(
             "serve_prefill", rid=req.id, engine=self.engine_id,
@@ -1461,27 +1473,42 @@ class ServingEngine:
         return (rows, (put(tables), put(npast), put(tokens),
                        put(fresh)), live, chained)
 
-    def _gap_fields(self):
-        """The fields of a decode launch's span: ``gap_s`` since the
-        last decode barrier returned, ``busy_s`` of it the device spent
-        on prefills, and the ``admitted`` prompts; none after an idle
-        engine, and none while no session records them.  Starts the
-        next gap's sums."""
+    def _gap_fields(self, flight):
+        """Whether the decode launch about to be made goes onto the
+        step before while that step still runs on the device
+        (``queued``; counted in ``serving_decode_queued_total``), and
+        the fields of the launch's span, built only while a session
+        records them: ``queued``; ``gap_s``, 0 on a queued launch (the
+        chip has work), else since the host learned that the step
+        before had ended (a prefill's barrier, a landing before the
+        launch, or this check where nothing told it earlier);
+        ``busy_s`` of it the device spent on prefills; the
+        ``admitted`` prompts.  Starts the next gap's sums."""
+        queued = flight is not None and not flight.nxt.is_ready()
+        if queued:
+            self._m_queued.inc()
         fields = {}
-        if self._gap_t0 is not None and telemetry.recording():
-            fields = {"gap_s": time.perf_counter() - self._gap_t0,
-                      "busy_s": self._gap_busy,
+        if telemetry.recording():
+            now = time.perf_counter()
+            t0 = self._gap_t0
+            if queued or t0 is None:
+                t0 = now
+            fields = {"queued": queued, "gap_s": now - t0,
+                      "busy_s": 0.0 if queued else self._gap_busy,
                       "admitted": self._gap_admitted}
-        self._gap_busy, self._gap_admitted = 0.0, 0
+        self._gap_t0, self._gap_busy, self._gap_admitted = None, 0.0, 0
         return fields
 
     def _decode_once(self, events):
         """The decode part of one iteration: dispatch the next decode
-        step, its tokens taken from the step in flight on the device;
-        land that step (the per-iteration token read, emit, retire)
-        while the new one computes; grow and stage for the step after;
-        wait for the new step's pools.  With no row to run it only
-        lands the step in flight.
+        step onto the pools the step in flight returns, its tokens
+        taken from that step on the device, while that step may still
+        run; land that step (the per-iteration token read, emit,
+        retire), the one wait of the loop; grow and stage for the step
+        after while the new one computes; return with it running.  So
+        at most two decode programs are outstanding, one running and
+        one queued.  With no row to run it only lands the step in
+        flight.
 
         Where a runner lacks its next block and the pool has none
         left, the step in flight lands BEFORE the dispatch, so that a
@@ -1496,16 +1523,19 @@ class ServingEngine:
         wedged device call cannot be cancelled portably — converting
         the overrun into a post-mortem is this layer's job, killing
         the process is the heartbeat monitor's."""
-        import jax
         B = self.max_batch
 
         # nested, so that the iteration's one read stays inside this
         # function, where ci/lint.py's host-sync rule looks
-        def land(flight):
+        def land(flight, emptied=False):
             """The step's tokens read, emitted and retired; returns
-            the experts it touched (a routed model)."""
+            the experts it touched (a routed model).  ``emptied``: no
+            decode step is launched behind it, so the read's return is
+            when the host learns that the device has none left."""
             with telemetry.span("serve_token_fetch"):
                 toks = np.asarray(flight.nxt)  # sync-ok: the per-iteration token read
+            if emptied and self._gap_t0 is None:
+                self._gap_t0 = time.perf_counter()
             # a routed model's statistics ride behind the tokens
             touched = self._count_moe(toks[B:], decode=True)
             with telemetry.span("serve_emit") as sp_emit:
@@ -1526,7 +1556,7 @@ class ServingEngine:
                 short = not self._grow(preempt=False)
             if short and self._flight is not None:
                 flight, self._flight = self._flight, None
-                land(flight)
+                land(flight, emptied=True)
                 self._staged = None
             if short:
                 with telemetry.span("serve_grow"):
@@ -1543,7 +1573,9 @@ class ServingEngine:
             flight, self._flight = self._flight, None
             if flight is not None:
                 land(flight)
-            self._gap_t0 = None
+            # an idle engine: the next launch's gap starts afresh
+            self._gap_t0, self._gap_busy, self._gap_admitted = \
+                None, 0.0, 0
             return
         # the watchdog below counts an injected hang as the step's
         resilience.inject("serve", "step")
@@ -1561,7 +1593,7 @@ class ServingEngine:
         with telemetry.span("serve_decode", running=running,
                             live_blocks=live_blocks) as sp_dec:
             with telemetry.span("serve_decode_dispatch",
-                                **self._gap_fields()):
+                                **self._gap_fields(flight)):
                 *pools, nxt, logits = fn(self._wts, *self._pools,
                                          *args, prev)
             self._pools = tuple(pools)
@@ -1576,11 +1608,6 @@ class ServingEngine:
                 self._grow_due = not self._grow(preempt=False)
             with telemetry.span("serve_decode_prep"):
                 self._staged = self._stage()
-            # completion barrier (see _admit_one): the next dispatch
-            # goes onto pools that are ready, off the slow path
-            with telemetry.span("serve_decode_wait"):
-                jax.block_until_ready(self._pools[0])
-            self._gap_t0 = time.perf_counter()  # nothing queued now
         dt_step = time.monotonic() - t0
         if self.step_timeout > 0 and dt_step > self.step_timeout:
             tracing.trace_event(

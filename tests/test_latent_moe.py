@@ -34,6 +34,9 @@ from incubator_mxnet_tpu.serving import ServingEngine  # noqa: E402
 from incubator_mxnet_tpu.serving.engine import \
     PAGED_PROTOCOL  # noqa: E402
 
+sys.path.insert(0, os.path.join(REPO, "tests"))
+from _serving import serve, slow_decode  # noqa: E402
+
 # hidden 64, 4 heads, latent 16 + rope 8, 8 experts top 2, one dense
 # and two expert layers
 CFG = {"family": "latent_moe_lm", "hidden_size": 64,
@@ -245,6 +248,73 @@ def test_the_chained_loop_serves_the_forwards_tokens(f32):
     device, admissions while a step is in flight, budgets that end in
     flight: float32, every served token is the eager forward's."""
     _chained_against_the_forward(f32[0])
+
+
+def _counter(name):
+    return telemetry.counter(name).value
+
+
+def test_the_latent_loop_launches_onto_the_step_still_running(
+        f32, monkeypatch, profiler_session, newest_spans):
+    """A decode step of the latent family is launched while the step
+    before still runs (``serving_decode_queued_total``, the launch's
+    ``queued``); the greedy tokens are those of a loop that lands
+    every step before the next launch, and the eager forward's."""
+    block = slow_decode(monkeypatch, f32[0])
+    prompts = [t[:n] for t, n in zip(_tokens(3, 13, 11), (13, 11, 9))]
+    budgets = (5, 7, 9)
+    served = {}
+    for land_each in (True, False):
+        eng = ServingEngine(block, max_batch=2, block_size=4,
+                            num_blocks=80, max_len=64)
+        serve(eng, prompts[:1], [2], land_each)    # compiled
+        queued0 = _counter("serving_decode_queued_total")
+        with profiler_session():
+            reqs = serve(eng, prompts, budgets, land_each)
+        queued = _counter("serving_decode_queued_total") - queued0
+        launches = [e for e in newest_spans()
+                    if e["name"] == "serve_decode_dispatch"]
+        assert [e["queued"] for e in launches].count(True) == queued
+        assert (queued == 0) if land_each else (queued > 0)
+        served[land_each] = [r.generated for r in reqs]
+    assert served[True] == served[False]
+    seqs = [np.concatenate([p, g]) for p, g in zip(prompts, served[True])]
+    for p, seq in zip(prompts, seqs):
+        full = block.forward(mx.nd.array(seq[None], dtype="int32"))
+        assert list(np.argmax(full.asnumpy()[0, len(p) - 1:-1], -1)) \
+            == list(seq[len(p):])
+
+
+def test_a_preemption_under_a_running_step_keeps_the_victims_token(
+        f32, monkeypatch):
+    """Launches going onto steps still running, and a pool too small
+    for both requests: the growth that preempts lands the step in
+    flight first, so the victim keeps the token that step computed;
+    what each request serves is what it serves alone."""
+    block = slow_decode(monkeypatch, f32[0])
+    prompts = [t[:n] for t, n in zip(_tokens(2, 30, 7), (30, 26))]
+    want = []
+    for p in prompts:
+        eng = ServingEngine(block, max_batch=2, block_size=4,
+                            num_blocks=40, max_len=64)
+        req = eng.submit(p, 12)
+        eng.run()
+        want.append(req.generated)
+    eng = ServingEngine(block, max_batch=2, block_size=4,
+                        num_blocks=19, max_len=64, prefix_cache=False)
+    seen, preempt = [], eng._preempt
+
+    def watched(req):
+        seen.append((eng._flight, req.n_past
+                     == len(req.prompt) + len(req.generated) - 1))
+        preempt(req)
+    eng._preempt = watched
+    queued0 = _counter("serving_decode_queued_total")
+    reqs = [eng.submit(p, 12) for p in prompts]
+    eng.run()
+    assert seen and all(f is None and kept for f, kept in seen)
+    assert _counter("serving_decode_queued_total") > queued0
+    assert [r.generated for r in reqs] == want
 
 
 def test_a_long_context_is_read_in_passes_of_keys(f32):

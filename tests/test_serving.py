@@ -19,6 +19,8 @@ from incubator_mxnet_tpu.serving import (
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
+from _serving import serve, slow_decode  # noqa: E402
+
 VOCAB = 37
 
 
@@ -452,10 +454,13 @@ def test_the_chained_program_compiles_in_a_warm_up_that_never_chains(
 def test_each_decode_launch_accounts_for_the_gap_before_it(
         profiler_session, newest_spans):
     """In a traced window every decode step's launch is a
-    ``serve_decode_dispatch`` span inside its ``serve_decode``.  All
-    but the first after an idle engine carry the gap since the last
-    decode barrier: the admissions in it, and the device seconds of
-    their prefills (``serve_prefill`` less its launch)."""
+    ``serve_decode_dispatch`` span inside its ``serve_decode``, and
+    every one carries the gap before it: whether it went onto the step
+    before while that still ran (``queued``, no gap), else the seconds
+    since the host learned that step had ended (the first admission's
+    barrier, where prompts were taken in), the admissions since the
+    launch before, and the device seconds of the prefills inside the
+    gap (``serve_prefill`` less its launch): all but the first's."""
     from incubator_mxnet_tpu import tracing
     net = _tiny()
     eng = ServingEngine(net, max_batch=2, block_size=4, num_blocks=64,
@@ -482,27 +487,146 @@ def test_each_decode_launch_accounts_for_the_gap_before_it(
         if e["name"] == "serve_prefill":
             assert [c["name"] for c in evs if c["parent"] == e["id"]] \
                 == ["serve_prefill_dispatch"]
-    assert ["gap_s" in e for e in launches].count(False) == 2
+    assert all({"queued", "gap_s", "busy_s", "admitted"} <= set(e)
+               for e in launches)
     admits = [e for e in evs if e["name"] == "serve_admit"]
     launch_s = {c["parent"]: c["t1"] - c["t0"] for c in evs
                 if c["name"] == "serve_prefill_dispatch"}
     held = 0
-    for before, e in zip(launches, launches[1:]):
-        if "gap_s" not in e:
-            assert set(e) & {"busy_s", "admitted"} == set()
-            continue
+    for before, e in zip([{"t0": 0.0}] + launches, launches):
         assert 0 <= e["busy_s"] <= e["gap_s"]
         mine = [a for a in admits if before["t0"] < a["t0"] < e["t0"]]
         assert e["admitted"] == len(mine)
         prefills = [p for p in evs if p["name"] == "serve_prefill"
                     and p["parent"] in {a["id"] for a in mine}]
+        if e["queued"]:
+            assert e["gap_s"] == e["busy_s"] == 0.0 and not mine
+            continue
+        if prefills:
+            # the gap starts where the first prefill's barrier returned
+            assert prefills[0]["t1"] <= e["t0"] - e["gap_s"]
+            assert all(e["t0"] - e["gap_s"] < p["t1"]
+                       for p in prefills[1:])
         assert abs(e["busy_s"] - sum(p["t1"] - p["t0"] - launch_s[p["id"]]
-                                     for p in prefills)) < 1e-9
+                                     for p in prefills[1:])) < 1e-9
         held += e["admitted"]
-    assert held == 2                        # the third of each wave
+    # the first two of each wave, admitted in one iteration, and the
+    # third when a slot frees
+    assert held == 6
     session = launches[0]["session"]
     assert [c["session"] for c in tracing.events("span_clock")] \
         == [session]
+
+
+# ------------------------------------------------- the launch ahead
+def test_a_decode_launch_goes_onto_the_step_still_running(
+        monkeypatch, profiler_session, newest_spans):
+    """Nothing waits for a decode step's pools: the next step is
+    launched while the one before still runs, counted in
+    ``serving_decode_queued_total`` and said by the launch's
+    ``queued``, whose gap is 0.  The greedy tokens are a loop's that
+    lands every step before the next launch, and generate()'s."""
+    net = slow_decode(monkeypatch, _tiny())
+    rs = np.random.RandomState(83)
+    prompts = [list(rs.randint(0, VOCAB, n)) for n in (6, 9, 4)]
+    budgets = [9, 5, 7]
+    served = {}
+    for land_each in (True, False):
+        eng = ServingEngine(net, max_batch=2, block_size=4,
+                            num_blocks=64, prefix_cache=False)
+        serve(eng, [[1, 2, 3]], [3], land_each)    # compiled
+        queued0 = _counter("serving_decode_queued_total")
+        steps0 = _counter("serving_decode_steps_total")
+        with profiler_session():
+            reqs = serve(eng, prompts, budgets, land_each)
+        queued = _counter("serving_decode_queued_total") - queued0
+        steps = _counter("serving_decode_steps_total") - steps0
+        served[land_each] = [r.generated for r in reqs]
+        launches = [e for e in newest_spans()
+                    if e["name"] == "serve_decode_dispatch"]
+        assert len(launches) == steps
+        assert [e["queued"] for e in launches].count(True) == queued
+        for e in launches:
+            if e["queued"]:
+                assert e["gap_s"] == e["busy_s"] == 0.0
+                assert e["admitted"] == 0
+            else:
+                assert 0 <= e["busy_s"] <= e["gap_s"]
+        # landed before every launch, no launch finds its step
+        # running; launched ahead, all but those after an admission
+        # do
+        assert (queued == 0) if land_each else (queued > steps // 2)
+    assert served[True] == served[False] == [
+        _gen_ref(net, p, n)[len(p):] for p, n in zip(prompts, budgets)]
+
+
+def test_a_preemption_lands_the_running_step_before_the_launch(
+        monkeypatch):
+    """With every launch going onto a step still running, a growth
+    that needs a preemption still lands the step in flight before any
+    runner is freed: the victim keeps the token that step computed for
+    it, and the launch after it finds nothing running."""
+    net = slow_decode(monkeypatch, _tiny())
+    rs = np.random.RandomState(19)
+    prompts = [list(rs.randint(0, VOCAB, n)) for n in (9, 10)]
+    refs = [_gen_ref(net, p, 14) for p in prompts]
+    eng = ServingEngine(net, max_batch=2, block_size=4, num_blocks=12,
+                        prefix_cache=False)
+    seen, preempt = [], eng._preempt
+
+    def watched(req):
+        # the victim's newest token has landed: every position it
+        # holds but its last token's is written
+        seen.append((eng._flight, req.n_past
+                     == len(req.prompt) + len(req.generated) - 1))
+        preempt(req)
+    eng._preempt = watched
+    queued0 = _counter("serving_decode_queued_total")
+    reqs = [eng.submit(p, 14) for p in prompts]
+    while eng.has_work():
+        n, queued = len(seen), _counter("serving_decode_queued_total")
+        eng.step()
+        if len(seen) > n:
+            assert _counter("serving_decode_queued_total") == queued
+    assert seen and all(f is None and kept for f, kept in seen)
+    assert _counter("serving_decode_queued_total") > queued0
+    for req, ref in zip(reqs, refs):
+        assert req.state == FINISHED
+        assert [int(t) for t in req.tokens] == ref
+    assert eng.pool.num_allocated == 0
+
+
+def test_the_watchdog_catches_a_hang_with_launches_ahead(
+        monkeypatch, tmp_path):
+    """``serve:step:N:hang`` with the launches going onto steps still
+    running: the iteration that hangs is logged as an overrun and
+    dumps the flight recorder, and the requests finish exactly."""
+    from incubator_mxnet_tpu import tracing
+    import json
+    path = str(tmp_path / "flight.jsonl")
+    monkeypatch.setenv("MXTPU_TRACE_DUMP", path)
+    monkeypatch.setenv("MXTPU_FAULT_SPEC", "serve:step:4:hang")
+    monkeypatch.setenv("MXTPU_FAULT_HANG_S", "0.5")
+    resilience.reset_faults()
+    net = slow_decode(monkeypatch, _tiny())
+    eng = ServingEngine(net, max_batch=2, block_size=4, num_blocks=64,
+                        prefix_cache=False, step_timeout=0.4)
+    try:
+        queued0 = _counter("serving_decode_queued_total")
+        reqs = [eng.submit(p, 8) for p in ([1, 2, 3], [4, 5, 6, 7])]
+        eng.run()
+    finally:
+        monkeypatch.setenv("MXTPU_FAULT_SPEC", "")
+        resilience.reset_faults()
+    assert _counter("serving_decode_queued_total") > queued0
+    evs = tracing.events("serve_step_overrun", engine=eng.engine_id)
+    assert any(e["seconds"] >= 0.5 for e in evs)
+    lines = [json.loads(line)
+             for line in open(path).read().splitlines()]
+    assert lines[0]["reason"] == "serve_step_overrun"
+    for req, p in zip(reqs, ([1, 2, 3], [4, 5, 6, 7])):
+        assert req.state == FINISHED
+        assert [int(t) for t in req.tokens] == _gen_ref(net, p, 8)
 
 
 # --------------------------------------------------- trace-count guards

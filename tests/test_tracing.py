@@ -605,11 +605,15 @@ def _tiny_latent_moe():
 
 @pytest.mark.parametrize("model", ["transformer_lm", "latent_moe_lm"])
 def test_engine_step_spans_form_the_tree_with_request_ids(
-        profiler_session, newest_spans, model):
+        monkeypatch, profiler_session, newest_spans, model):
+    from _serving import slow_decode
     from incubator_mxnet_tpu.serving import ServingEngine
     routed = model == "latent_moe_lm"
-    eng = ServingEngine(_tiny_latent_moe() if routed else _tiny_lm(),
-                        max_batch=2, block_size=4,
+    # a decode step longer than the host's loop: the second launch
+    # goes onto the first step while it still runs
+    net = slow_decode(monkeypatch,
+                      _tiny_latent_moe() if routed else _tiny_lm())
+    eng = ServingEngine(net, max_batch=2, block_size=4,
                         num_blocks=64, prefix_cache=False)
     eng.submit([1, 2, 3], 2)            # warm prefill_4 and decode
     eng.run()
@@ -635,21 +639,26 @@ def test_engine_step_spans_form_the_tree_with_request_ids(
         "serve_reap", "serve_decode"]
     # the launch, then the host's part inside the decode span, while
     # the device computes: the step before lands (none before the
-    # first), the growth and the inputs for the step after, the wait
+    # first), the growth and the inputs for the step after; nothing
+    # waits for the step launched
     first_dec, second_dec = (kids[s["id"]][-1] for s in (first, second))
     assert [c["name"] for c in kids[first_dec["id"]]] == [
-        "serve_decode_dispatch", "serve_grow", "serve_decode_prep",
-        "serve_decode_wait"]
+        "serve_decode_dispatch", "serve_grow", "serve_decode_prep"]
     assert [c["name"] for c in kids[second_dec["id"]]] == [
         "serve_decode_dispatch", "serve_token_fetch", "serve_emit",
-        "serve_grow", "serve_decode_prep", "serve_decode_wait"]
-    # the first launch after an idle engine has no gap before it; the
-    # next one's gap took no prompt in
+        "serve_grow", "serve_decode_prep"]
+    # the first launch after an idle engine finds no step running; its
+    # gap runs from the admission's barrier, and the one prefill lies
+    # before it.  The next launch goes onto the first step while that
+    # still runs: no gap
     first_launch, second_launch = (kids[d["id"]][0]
                                    for d in (first_dec, second_dec))
-    assert "gap_s" not in first_launch
+    assert first_launch["queued"] is False
+    assert first_launch["admitted"] == 1
+    assert first_launch["busy_s"] == 0.0 < first_launch["gap_s"]
+    assert second_launch["queued"] is True
     assert second_launch["admitted"] == 0
-    assert second_launch["busy_s"] == 0.0 < second_launch["gap_s"]
+    assert second_launch["busy_s"] == second_launch["gap_s"] == 0.0
     # a routed model's decode says how many experts the step that
     # landed in it read: one slot, 2 routed layers, 2 experts a token
     # and layer
@@ -664,6 +673,7 @@ def test_engine_step_spans_form_the_tree_with_request_ids(
         "serve_prefill", "serve_token_fetch")
     assert (prefill["rid"], prefill["tokens"], prefill["bucket"]) \
         == (req.id, 5, 8)
+    assert first_launch["t0"] - first_launch["gap_s"] >= prefill["t1"]
     launch, = (c for c in kids[prefill["id"]] if c["name"] != "compile")
     assert launch["name"] == "serve_prefill_dispatch"
     # the new bucket compiled inside the traced step: the compile is
