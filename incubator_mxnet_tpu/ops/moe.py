@@ -46,7 +46,7 @@ import jax.numpy as jnp
 from .registry import defop
 
 __all__ = ["moe_ffn_fn", "top2_gating", "routed_ffn_fn", "route_top_k",
-           "gated_ffn"]
+           "gated_ffn", "relu2_ffn"]
 
 
 def top2_gating(logits, capacity, renorm=True):
@@ -181,6 +181,25 @@ def gated_ffn(x, gate, up, down):
                    preferred_element_type=jnp.float32)
 
 
+def relu2_ffn(x, up, down):
+    """``relu(x up^T)^2 down^T``, an expert with no gate: products in
+    x's dtype, accumulated in float32; float32 result."""
+    hidden = jnp.square(jax.nn.relu(jnp.dot(
+        x, up.T, preferred_element_type=jnp.float32)))
+    return jnp.dot(hidden.astype(x.dtype), down.T,
+                   preferred_element_type=jnp.float32)
+
+
+# an expert's activation -> (the expert on rows, given its gate or
+# None, up and down; the activation of its grouped product's hidden
+# rows, given the gate's and the up's products)
+ACTIVATIONS = {
+    "silu": (gated_ffn, lambda gate, up: jax.nn.silu(gate) * up),
+    "relu2": (lambda x, gate, up, down: relu2_ffn(x, up, down),
+              lambda gate, up: jnp.square(jax.nn.relu(up))),
+}
+
+
 def _tile_rows(tokens, top_k, n_experts):
     """Rows of one tile of the sorted dispatch: the pairs an expert
     expects from ``tokens`` tokens, rounded up to a power of two,
@@ -197,10 +216,13 @@ WEIGHT_TILE_BYTES = 4 << 20
 
 
 def _experts_tiled(x, order, pairs, flat_weight, gate_weight,
-                   up_weight, down_weight, top_k, base, rows_tile):
+                   up_weight, down_weight, top_k, base, rows_tile,
+                   activation="silu"):
     """The sorted pairs expert by expert, in tiles of ``rows_tile``
     rows: (sum (T, D) float32, rows multiplied)."""
-    t, d = x.shape
+    t = x.shape[0]
+    d = down_weight.shape[1]
+    ffn = ACTIVATIONS[activation][0]
     first_row = jnp.cumsum(pairs) - pairs
     tiles = -(-pairs // rows_tile)
     last_tile = jnp.cumsum(tiles)
@@ -214,9 +236,10 @@ def _experts_tiled(x, order, pairs, flat_weight, gate_weight,
         pair = order[jnp.minimum(first_row[e] + within,
                                  t * top_k - 1)]
         token = pair // top_k
-        g, u, dn = (jax.lax.dynamic_index_in_dim(w, base + e, 0, False)
+        g, u, dn = (None if w is None else
+                    jax.lax.dynamic_index_in_dim(w, base + e, 0, False)
                     for w in (gate_weight, up_weight, down_weight))
-        y = gated_ffn(x[token], g, u, dn)
+        y = ffn(x[token], g, u, dn)
         y = y * jnp.where(real, flat_weight[pair], 0.0)[:, None]
         return out.at[token].add(y)
 
@@ -227,16 +250,17 @@ def _experts_tiled(x, order, pairs, flat_weight, gate_weight,
 
 def _experts_grouped(x, order, pairs, flat_weight, gate_weight,
                      up_weight, down_weight, top_k, base,
-                     interpret=False):
+                     interpret=False, activation="silu"):
     """The sorted pairs through a grouped matrix product: the rows of
     one expert lie together, and the kernel goes through them in tiles
     of ``GROUP_ROWS``, a tile that holds rows of several experts once
     for each of them: (sum (T, D) float32, rows multiplied)."""
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
-    t, d = x.shape
+    t = x.shape[0]
+    d = down_weight.shape[1]
     count = pairs.shape[0]
     gate_weight, up_weight, down_weight = (
-        w if w.shape[0] == count
+        w if w is None or w.shape[0] == count
         else jax.lax.slice_in_dim(w, base, base + count)
         for w in (gate_weight, up_weight, down_weight))
 
@@ -252,8 +276,9 @@ def _experts_grouped(x, order, pairs, flat_weight, gate_weight,
                    interpret=interpret)
 
     rows = x[order // top_k]
-    hidden = jax.nn.silu(product(rows, gate_weight)) \
-        * product(rows, up_weight)
+    hidden = ACTIVATIONS[activation][1](
+        None if gate_weight is None else product(rows, gate_weight),
+        product(rows, up_weight))
     y = product(hidden.astype(x.dtype), down_weight)
     # rows behind the last held pair were never written
     live = jnp.arange(t * top_k) < jnp.sum(pairs)
@@ -272,17 +297,27 @@ def _experts_grouped(x, order, pairs, flat_weight, gate_weight,
 def routed_ffn_fn(x, router_weight, gate_weight, up_weight,
                   down_weight, top_k, scoring="sigmoid",
                   select_bias=None, normalize=True, scale=1.0,
-                  shared=None, held=None, valid=None):
-    """A routed layer of gated experts, dropless.
+                  shared=None, held=None, valid=None,
+                  activation="silu", latent=None):
+    """A routed layer of experts, dropless.
 
     x             : (T, D) tokens
     router_weight : (E, D), select_bias (E,) or None: the whole
                     router (:func:`route_top_k` has the rest)
     gate_weight, up_weight : (E or count, H, D); down_weight
                     (E or count, D, H): stacked experts, (out, in)
-                    as FullyConnected has them
-    shared        : None, or (gate (S, D), up (S, D), down (D, S)):
-                    an expert every token goes through, added here
+                    as FullyConnected has them; ``gate_weight`` None
+                    where the activation has no gate
+    activation    : ``silu`` (gated: ``silu(x gate^T) * x up^T``) or
+                    ``relu2`` (``relu(x up^T)^2``, no gate)
+    latent        : None, or (down (L, D), up (D, L)): the experts
+                    take ``x down^T`` (L wide, their stacked weights
+                    L wide too) while the router reads ``x``; their sum
+                    goes back through ``up`` before the shared expert
+                    is added
+    shared        : None, or (gate (S, D) or None, up (S, D), down (D,
+                    S)): an expert of the same activation every token
+                    goes through, on ``x``, added here
     held          : None (all), or (first, count): route over all
                     ``E``, compute what experts ``first .. first +
                     count - 1`` give.  The stacked weights then hold
@@ -299,23 +334,28 @@ def routed_ffn_fn(x, router_weight, gate_weight, up_weight,
 
     The sum of ``y`` over shares that partition the experts, with
     ``shared`` given to one of them, is the whole layer."""
-    t, d = x.shape
+    t = x.shape[0]
     n_experts = router_weight.shape[0]
     first, count = (0, n_experts) if held is None else held
     if not 0 <= first <= first + count <= n_experts:
         raise ValueError(f"held={held!r} is not among {n_experts} "
                          "experts")
-    if gate_weight.shape[0] == n_experts:
+    if (gate_weight is None) != (activation == "relu2"):
+        raise ValueError(f"activation={activation!r} with gate "
+                         f"{'none' if gate_weight is None else 'given'}")
+    stacked = up_weight.shape[0]
+    if stacked == n_experts:
         base = first          # the stack holds every expert
-    elif gate_weight.shape[0] == count:
+    elif stacked == count:
         base = 0              # the stack holds the held ones
     else:
         raise ValueError(
-            f"{gate_weight.shape[0]} stacked experts: neither all "
+            f"{stacked} stacked experts: neither all "
             f"{n_experts} nor the {count} held")
     rows_tile = _tile_rows(t, top_k, n_experts)
+    d = up_weight.shape[2]    # the experts' rows: x's, or the latent's
     # the grouped product wants whole lanes and whole tiles of rows
-    grouped = d % 128 == 0 and gate_weight.shape[1] % 128 == 0 \
+    grouped = d % 128 == 0 and up_weight.shape[1] % 128 == 0 \
         and (t * top_k) % GROUP_ROWS == 0 and count > 0
 
     with jax.named_scope("moe_route"):
@@ -332,21 +372,38 @@ def routed_ffn_fn(x, router_weight, gate_weight, up_weight,
                         dtype=jnp.int32)
         flat_weight = weight.reshape(-1)
 
+    rows = x
+    if latent is not None:
+        with jax.named_scope("moe_latent"):
+            rows = jnp.dot(x, latent[0].T,
+                           preferred_element_type=jnp.float32) \
+                .astype(x.dtype)
     with jax.named_scope("moe_experts"):
-        args = (x, order, pairs, flat_weight, gate_weight, up_weight,
-                down_weight)
+        # no operand for a gate there is not: None is no array of a
+        # platform's branch
+        gate = () if gate_weight is None else (gate_weight,)
+        args = (rows, order, pairs, flat_weight) + gate \
+            + (up_weight, down_weight)
+
+        def taken(a):
+            return a[:4] + (None,) + a[4:] if gate_weight is None else a
         if grouped:
             out, routed_rows = jax.lax.platform_dependent(
                 *args,
-                tpu=lambda *a: _experts_grouped(*a, top_k, base),
-                default=lambda *a: _experts_tiled(*a, top_k, base,
-                                                  rows_tile))
+                tpu=lambda *a: _experts_grouped(
+                    *taken(a), top_k, base, activation=activation),
+                default=lambda *a: _experts_tiled(
+                    *taken(a), top_k, base, rows_tile, activation))
         else:
-            out, routed_rows = _experts_tiled(*args, top_k, base,
-                                              rows_tile)
+            out, routed_rows = _experts_tiled(*taken(args), top_k, base,
+                                              rows_tile, activation)
+    if latent is not None:
+        with jax.named_scope("moe_latent"):
+            out = jnp.dot(out.astype(x.dtype), latent[1].T,
+                          preferred_element_type=jnp.float32)
     if shared is not None:
         with jax.named_scope("moe_shared"):
-            out = out + gated_ffn(x, *shared)
+            out = out + ACTIVATIONS[activation][0](x, *shared)
     stats = {"routed_rows": routed_rows,
              "padded_rows": routed_rows - jnp.sum(pairs),
              "experts_touched": jnp.sum(pairs > 0, dtype=jnp.int32)}

@@ -268,26 +268,41 @@ class ServingEngine:
         import jax.numpy as jnp
         # the model's cache: what one token leaves in one layer, a
         # pool each (TransformerLM: a row of keys and one of values,
-        # float32; LatentMoELM: one latent row in the weights' dtype)
+        # float32; LatentMoELM: one latent row in the weights' dtype),
+        # over every layer or over the ``layers`` it names; a pool
+        # marked ``per: slot`` holds a row of fixed size a slot in
+        # place of one a token (NemotronHLM: a Mamba layer's state)
         self.cache_spec = tuple(
             {**c, "shape": tuple(c["shape"]),
              "dtype": str(jnp.dtype(c["dtype"]))}
             for c in model._paged_cache())
-        self.token_bytes = model.n_layers * sum(
-            int(np.prod(c["shape"])) * jnp.dtype(c["dtype"]).itemsize
-            for c in self.cache_spec)
+
+        def row_bytes(c):
+            return len(self._pool_layers(c)) * int(np.prod(c["shape"])) \
+                * jnp.dtype(c["dtype"]).itemsize
+        self.token_bytes = sum(row_bytes(c) for c in self.cache_spec
+                               if not self._per_slot(c))
+        # what the pools a slot hold, all slots: fixed bytes
+        self.state_bytes = self.max_batch * sum(
+            row_bytes(c) for c in self.cache_spec if self._per_slot(c))
         if self.auto_blocks:
             self.num_blocks = self._auto_num_blocks()
         if self.num_blocks < 1:
             raise ValueError(
                 f"bad serving config: num_blocks={self.num_blocks}")
         self.pool = BlockPool(self.num_blocks, self.block_size)
-        self.cache = PrefixCache(self.pool, enabled=prefix_cache)
+        # a state a slot is no row of blocks: adopting cached blocks
+        # would skip the state of their positions, so such a model
+        # prefills every prompt whole (counted as bypassed)
+        self._bypass_prefix = bool(prefix_cache) and bool(self.state_bytes)
+        self.cache = PrefixCache(
+            self.pool, enabled=prefix_cache and not self._bypass_prefix)
         # one list of per-layer arrays a pool; the programs take and
         # return them in this order, right after the weights
         self._pools = tuple(
-            [jnp.zeros((self.num_blocks, self.block_size) + c["shape"],
-                       c["dtype"]) for _ in range(model.n_layers)]
+            [jnp.zeros(((self.max_batch,) if self._per_slot(c) else
+                        (self.num_blocks, self.block_size)) + c["shape"],
+                       c["dtype"]) for _ in self._pool_layers(c)]
             for c in self.cache_spec)
 
         self._step_fn = None
@@ -384,6 +399,10 @@ class ServingEngine:
             "serving_prefix_cache_hits_total")
         self._m_misses = telemetry.counter(
             "serving_prefix_cache_misses_total")
+        self._m_bypassed = telemetry.counter(
+            "serving_prefix_cache_bypassed_total")
+        self._m_resets = telemetry.counter(
+            "serving_state_slot_resets_total")
         self._m_preempt = telemetry.counter(
             "serving_preemptions_total")
         self._m_evict = telemetry.counter("serving_evictions_total")
@@ -429,11 +448,21 @@ class ServingEngine:
         self._m_hc = None
 
     # ---------------------------------------------------------- setup
+    def _pool_layers(self, c):
+        """The layers a pool lies over: every layer unless it says."""
+        return tuple(c.get("layers", range(self.model.n_layers)))
+
+    @staticmethod
+    def _per_slot(c):
+        """Whether a pool holds a row a slot (else a row a token)."""
+        return c.get("per", "token") == "slot"
+
     def _auto_num_blocks(self):
         """Size the KV pool from planner headroom (docs/memory.md):
         usable device capacity (MXTPU_HBM_BYTES override honored,
-        MXTPU_MEM_GATE_MARGIN reserved) minus the settled weights and
-        a per-step decode workspace (hidden states + logits), divided
+        MXTPU_MEM_GATE_MARGIN reserved) minus the settled weights, the
+        pools a slot holds and a per-step decode workspace (hidden
+        states + logits), divided
         by per-block bytes of the model's own row — capped at a full
         context row for every slot plus the scratch block, so tiny
         models never hoard the chip.  Refuses with a typed
@@ -446,7 +475,7 @@ class ServingEngine:
         # slot (fp32), the transient XLA scratch next to the pools
         workspace = self.model._decode_workspace_bytes(self.max_batch)
         per_block = float(self.token_bytes * self.block_size)
-        avail = headroom(wts_bytes + workspace)
+        avail = headroom(wts_bytes + workspace + self.state_bytes)
         floor = self.max_batch + 1   # one block per slot + scratch
         if avail < per_block * floor:
             from ..resilience import MemoryPlanError
@@ -543,7 +572,7 @@ class ServingEngine:
             # first step that chains compiles the decode program
             # again, and a committed ``next`` would commit every
             # program's outputs after it and compile each once more
-            pool = self._pools[0][0]
+            pool = jax.tree_util.tree_leaves(self._pools)[0]
             self._nxt0 = jax.device_put(
                 np.zeros(nxt.shape, nxt.dtype),
                 pool.sharding if pool.committed else None)
@@ -1252,6 +1281,8 @@ class ServingEngine:
             return True
         toks = req.tokens
         matched, n_cached = self.cache.match(toks)
+        if self._bypass_prefix:
+            self._m_bypassed.inc()
         need = -(-len(toks) // self.block_size) - len(matched)
         try:
             fresh = self._alloc(need)
@@ -1298,13 +1329,19 @@ class ServingEngine:
         suf = np.zeros(bucket, np.int32)
         suf[:len(suffix)] = suffix
         row, suf = jnp.asarray(row.copy()), jnp.asarray(suf)
+        # a model with pools a slot: the prefill takes its slot, whose
+        # state it starts from zero where nothing is cached
+        at = (np.int32(slot),) if self.state_bytes else ()
+        if at and not n_cached:
+            self._m_resets.inc()
         with telemetry.span("serve_prefill", rid=req.id,
                             tokens=len(suffix),
                             bucket=bucket) as sp_pre:
             with telemetry.span("serve_prefill_dispatch") as sp_launch:
                 *pools, nxt, logits = fn(
                     self._wts, *self._pools,
-                    row, np.int32(n_cached), suf, np.int32(len(suffix)))
+                    row, np.int32(n_cached), suf, np.int32(len(suffix)),
+                    *at)
             self._pools = tuple(pools)
             # the launch may go onto pools a decode step in flight
             # still holds: the device runs the prefill behind it.  The
@@ -1590,8 +1627,12 @@ class ServingEngine:
             self._m_chained.inc()
         fn = self._get_step_fn()
         prev = flight.nxt if flight is not None else self._nxt0
+        # the states every slot's row reads and writes, where the model
+        # keeps any
+        state = {"state_bytes": 2 * self.state_bytes} \
+            if self.state_bytes else {}
         with telemetry.span("serve_decode", running=running,
-                            live_blocks=live_blocks) as sp_dec:
+                            live_blocks=live_blocks, **state) as sp_dec:
             with telemetry.span("serve_decode_dispatch",
                                 **self._gap_fields(flight)):
                 *pools, nxt, logits = fn(self._wts, *self._pools,

@@ -510,3 +510,91 @@ def test_planner_never_under_the_v5e_compiler(one_chip, graph, accum):
     assert plan.total() >= tpu, (
         f"{graph} accum={accum}: planner {plan.total():.0f} under the "
         f"v5e compiler's {tpu:.0f} — {plan.describe()}")
+
+
+@pytest.fixture(scope="module")
+def nemotron_cell(one_chip):
+    """``NemotronHLM`` at ``benchmark/configs/nemotron-3-super-120b-a12b
+    .json``'s widths and 11 layers (128 of 512 experts held), and the
+    engine's pools at the cell's shapes (128 slots, 512 blocks of 16 a
+    row, 39,041 blocks; the attention layer's keys and values, each
+    Mamba layer's state and conv tail a slot), all abstract, bfloat16
+    where the weights are."""
+    import json
+
+    from incubator_mxnet_tpu.gluon.model_zoo.nemotron_h import \
+        NemotronHLM
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "nemotron-3-super-120b-a12b.json")) as f:
+        cfg = json.load(f)
+    lm = NemotronHLM(dict(cfg, n_routed_experts=512),
+                     held=(0, cfg["n_routed_experts"]))
+    lm.cast("bfloat16")
+
+    def leaf(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    wts = {"embed": leaf(*lm.embed_weight.shape),
+           "norm": leaf(*lm.norm.shape),
+           "head": leaf(*lm.head_weight.shape),
+           "layers": [{k: leaf(*p.shape) for k, p in lw.items()}
+                      for lw in lm.layers]}
+    slots, row_blocks, blocks = 128, 512, 39041
+    pools = tuple(
+        [leaf(*(((slots,) if c.get("per") == "slot" else (blocks, 16))
+                + tuple(c["shape"])), dtype=jnp.dtype(c["dtype"]))
+         for _ in c["layers"]] for c in lm._paged_cache())
+    ints = functools.partial(leaf, dtype=jnp.int32)
+    return dict(lm=lm, wts=wts, pools=pools, slots=slots,
+                decode=lm._build_paged_step(slots, row_blocks, 16),
+                args=(ints(slots, row_blocks), ints(slots), ints(slots)),
+                prefill=lm._build_paged_prefill(4096, row_blocks, 16),
+                rest=(ints(row_blocks), ints(), ints(4096), ints(),
+                      ints()))
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_the_hybrid_programs_compile_for_v5e(nemotron_cell, program):
+    """The Nemotron cell's decode program (as the engine jits it) and
+    its 4096-row prefill, at the published widths, compiled for the
+    chip: the decode step holds one ``ssm_state_step`` kernel a Mamba
+    layer (5), one paged read (the attention layer) and the grouped
+    product's two kernels a routed layer (10); no instruction copies a
+    state pool; every pool comes out in the buffer it went in (13.35 GB
+    of arguments: 9.30 of weights, 4.04 of pools); the decode program's
+    temporaries stay under 0.1 GB, the prefill's under 2.5 GB.  Counts
+    of a compile, no times."""
+    c = nemotron_cell
+    pools = c["pools"]
+    if program == "decode":
+        lowered = _engine_decode(c["decode"], c["slots"], c["wts"], pools,
+                                 c["args"])
+    else:
+        lowered = serving.program("prefill_4096", c["prefill"],
+                                  len(pools)).lower(c["wts"], *pools,
+                                                    *c["rest"])
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line]
+
+    def named(name):
+        return sum(1 for line in kernels if name in line)
+    if program == "decode":
+        assert (named("ssm_state_step"), named("paged_decode_attention"),
+                len(kernels)) == (5, 1, 16)
+    else:
+        assert named("ssm_state_step") == 0 and len(kernels) == 10
+    state = ",".join(str(n) for n in pools[2][0].shape)
+    copied = [line.strip()[:120] for line in text.splitlines()
+              if re.search(rf"\[{state}\]\S* copy\(", line)]
+    assert not copied, copied
+    aliased = re.search(r"input_output_alias=\{(.*?) \}", text).group(1)
+    assert len(re.findall(r"\{\d+\}: \(\d+, \{\}", aliased)) \
+        == sum(len(p) for p in pools) == 12
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= sum(
+        int(np.prod(a.shape)) * a.dtype.itemsize
+        for p in pools for a in p)
+    assert memory.temp_size_in_bytes < (
+        0.1e9 if program == "decode" else 2.5e9), \
+        memory.temp_size_in_bytes
